@@ -10,7 +10,10 @@ on first use if missing.
 
 from __future__ import annotations
 
+import contextlib
 import ctypes
+import glob
+import hashlib
 import os
 import subprocess
 import threading
@@ -21,29 +24,43 @@ import numpy as np
 from ml_trainer_tpu.data.datasets import ArrayDataset
 from ml_trainer_tpu.data.sampler import ShardedSampler
 
-_CSRC = os.path.join(os.path.dirname(__file__), "..", "..", "csrc")
-_LIB_PATH = os.path.abspath(os.path.join(_CSRC, "libbatch_worker.so"))
+_CSRC = os.path.abspath(
+    os.path.join(os.path.dirname(__file__), "..", "..", "csrc")
+)
+_SOURCES = ("batch_worker.cpp", "jpeg_decoder.cpp")
+_CXX = ("g++", "-O3", "-std=c++17", "-fPIC", "-pthread", "-Wall", "-shared")
 _lib = None
 _lib_lock = threading.Lock()
 
 
-_SOURCES = ("batch_worker.cpp", "jpeg_decoder.cpp")
+def _library_path() -> str:
+    """The built library's path, keyed by the CONTENT of its sources and
+    the compile line: a binary built from other sources has another name
+    and is never loaded.  (File times say nothing — a copied tree does
+    not keep them in order.)"""
+    h = hashlib.sha256(" ".join(_CXX).encode())
+    for name in _SOURCES:
+        with open(os.path.join(_CSRC, name), "rb") as f:
+            h.update(f.read())
+    return os.path.join(_CSRC, f"libbatch_worker.{h.hexdigest()[:16]}.so")
 
 
-def _build_library() -> str:
-    srcs = [os.path.join(_CSRC, s) for s in _SOURCES]
+def _build_library(lib_path: str) -> None:
     # Compile to a private temp path, then atomically publish: concurrent
-    # processes (parallel pytest, multi-process workers) may rebuild at
+    # processes (parallel pytest, multi-process workers) may build at
     # the same time, and one must never dlopen a half-written .so.
-    tmp = f"{_LIB_PATH}.{os.getpid()}.tmp"
+    tmp = f"{lib_path}.{os.getpid()}.tmp"
     subprocess.run(
-        ["g++", "-O3", "-std=c++17", "-fPIC", "-pthread", "-Wall", "-shared",
-         "-o", tmp, *srcs],
+        [*_CXX, "-o", tmp, *(os.path.join(_CSRC, s) for s in _SOURCES)],
         check=True,
         capture_output=True,
     )
-    os.replace(tmp, _LIB_PATH)
-    return _LIB_PATH
+    os.replace(tmp, lib_path)
+    # Binaries of earlier source versions are dead weight now.
+    for stale in glob.glob(os.path.join(_CSRC, "libbatch_worker.*.so")):
+        if stale != lib_path:
+            with contextlib.suppress(OSError):
+                os.remove(stale)
 
 
 def load_library() -> ctypes.CDLL:
@@ -51,14 +68,10 @@ def load_library() -> ctypes.CDLL:
     with _lib_lock:
         if _lib is not None:
             return _lib
-        srcs = [os.path.join(_CSRC, s) for s in _SOURCES]
-        if not os.path.exists(_LIB_PATH) or any(
-            os.path.exists(s)
-            and os.path.getmtime(s) > os.path.getmtime(_LIB_PATH)
-            for s in srcs
-        ):
-            _build_library()
-        lib = ctypes.CDLL(_LIB_PATH)
+        lib_path = _library_path()
+        if not os.path.exists(lib_path):
+            _build_library(lib_path)
+        lib = ctypes.CDLL(lib_path)
         lib.batch_worker_create_sharded.restype = ctypes.c_void_p
         lib.batch_worker_create_sharded.argtypes = [
             ctypes.POINTER(ctypes.c_void_p), ctypes.POINTER(ctypes.c_int64),

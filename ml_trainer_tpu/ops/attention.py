@@ -21,12 +21,16 @@ Shapes follow the TPU-native convention [batch, heads, seq, head_dim].
 
 from __future__ import annotations
 
+import contextlib
+import contextvars
 import functools
+import math
 from typing import Optional
 
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.sharding import PartitionSpec as P
 
 
 def _mask_bias(mask, dtype):
@@ -487,16 +491,90 @@ def _flash_bwd(causal, scale, block_q, block_k, interpret, res, g):
 flash_attention.defvjp(_flash_fwd, _flash_bwd)
 
 
-def _flash_supported(q, k, block_q, block_k) -> bool:
-    s_q, d = q.shape[-2], q.shape[-1]
-    s_k = k.shape[-2]
-    return (
-        jax.default_backend() == "tpu"
-        and s_q == s_k  # kernel's causal mask is diagonal-aligned (see below)
-        and s_q % block_q == 0
-        and s_k % block_k == 0
-        and d % 64 == 0  # sublane-friendly head dim (Mosaic pads 64 -> 128)
+# The mesh the program being traced will run on, as its owner declares it
+# (the Trainer wraps every model.apply in ``kernel_mesh``).  GSPMD cannot
+# partition a Mosaic kernel — under a jit over more than one device the
+# lowering refuses it ("Mosaic kernels cannot be automatically
+# partitioned") — so there the flash call runs inside a shard_map, each
+# device on its own block of the batch (and of the heads, under tensor
+# parallelism).
+_KERNEL_MESH: contextvars.ContextVar = contextvars.ContextVar(
+    "kernel_mesh", default=None
+)
+
+
+@contextlib.contextmanager
+def kernel_mesh(mesh):
+    """Declare ``mesh`` as the device mesh of whatever is traced inside."""
+    token = _KERNEL_MESH.set(mesh)
+    try:
+        yield
+    finally:
+        _KERNEL_MESH.reset(token)
+
+
+def _kernel_specs(q):
+    """(q/k/v spec, kv_lens spec, ways the batch splits) to shard_map the
+    flash call with, or None when it runs as it is: no mesh declared, one
+    device, or already inside a shard_map (the sharded train step,
+    ring/ulysses, a pipeline stage)."""
+    mesh = _KERNEL_MESH.get()
+    if (mesh is None or mesh.size == 1
+            or jax.sharding.get_abstract_mesh().manual_axes):
+        return None
+    batch = tuple(
+        a for a in ("data", "fsdp")
+        if a in mesh.axis_names and mesh.shape[a] > 1
     )
+    heads = (
+        "tensor" if mesh.shape.get("tensor", 1) > 1
+        and q.shape[1] % mesh.shape["tensor"] == 0 else None
+    )
+    return (
+        P(batch or None, heads, None, None), P(batch or None),
+        math.prod(mesh.shape[a] for a in batch),
+    )
+
+
+def _off_tile(q, k, block_q, block_k) -> bool:
+    return bool(
+        q.shape[-2] % block_q or k.shape[-2] % block_k
+        or q.shape[-1] % 64  # sublane-friendly head dim (Mosaic pads 64->128)
+    )
+
+
+def _flash_on_mesh(q, k, v, kv_lens, causal, scale, block_q, block_k,
+                   interpret=False):
+    """The flash kernel (padded to tile shapes where it must be) on the
+    declared mesh."""
+    fn = _flash_padded if _off_tile(q, k, block_q, block_k) else (
+        flash_attention
+    )
+    specs = _kernel_specs(q)
+    if specs is None:
+        return fn(q, k, v, kv_lens, causal, scale, block_q, block_k,
+                  interpret)
+    spec, lens_spec, _ = specs
+    if scale is None:
+        scale = q.shape[-1] ** -0.5
+    lens = () if kv_lens is None else (kv_lens,)
+    return jax.shard_map(
+        lambda q, k, v, *lens: fn(q, k, v, *(lens or (None,)), causal, scale,
+                                  block_q, block_k, interpret),
+        mesh=_KERNEL_MESH.get(),
+        in_specs=(spec, spec, spec) + (lens_spec,) * len(lens),
+        out_specs=spec, check_vma=False,
+    )(q, k, v, *lens)
+
+
+def _flash_supported(q, k) -> bool:
+    """What 'auto' needs before it picks the kernel: the TPU, equal
+    lengths (the kernel's causal mask is diagonal-aligned), and a batch
+    the declared mesh's data axes divide."""
+    specs = _kernel_specs(q)
+    if specs is not None and q.shape[0] % specs[2]:
+        return False
+    return jax.default_backend() == "tpu" and q.shape[-2] == k.shape[-2]
 
 
 # In 'auto' mode the padded-flash path only engages from this sequence
@@ -527,8 +605,6 @@ def _flash_padded(q, k, v, kv_lens, causal, scale, block_q, block_k,
     Requires s_q == s_k (the kernel's causal mask is diagonal-aligned);
     ``scale`` is resolved against the ORIGINAL head_dim before padding.
     """
-    import math
-
     b, h, s, d = q.shape
     if scale is None:
         scale = d ** -0.5
@@ -634,32 +710,19 @@ def attention(
                 "flash attention requires equal query/key lengths "
                 f"(got {q.shape[-2]} vs {k.shape[-2]}); use the XLA path"
             )
-        if (
-            q.shape[-2] % block_q
-            or k.shape[-2] % block_k
-            or q.shape[-1] % 64
-        ):
-            # Off-tile shapes run through the padding wrapper — exact
-            # math (see _flash_padded), slightly more FLOPs.
-            return _flash_padded(
-                q, k, v, kv_lens, causal, scale, block_q, block_k
-            )
-        return flash_attention(
-            q, k, v, kv_lens, causal, scale, block_q, block_k, False
+        # Off-tile shapes run through the padding wrapper — exact math
+        # (see _flash_padded), slightly more FLOPs.
+        return _flash_on_mesh(
+            q, k, v, kv_lens, causal, scale, block_q, block_k
         )
     if implementation == "auto" and (mask is None or kv_lens is not None):
-        if _flash_supported(q, k, block_q, block_k):
-            return flash_attention(
-                q, k, v, kv_lens, causal, scale, block_q, block_k, False
-            )
-        if (
-            jax.default_backend() == "tpu"
-            and q.shape[-2] == k.shape[-2]
-            and q.shape[-2] >= _AUTO_PAD_MIN_SEQ
+        # Long off-tile sequences pad: the O(S) memory win beats the
+        # padding overhead (see _AUTO_PAD_MIN_SEQ rationale).
+        if _flash_supported(q, k) and (
+            q.shape[-2] >= _AUTO_PAD_MIN_SEQ
+            or not _off_tile(q, k, block_q, block_k)
         ):
-            # Long off-tile sequences: the O(S) memory win beats the
-            # padding overhead (see _AUTO_PAD_MIN_SEQ rationale).
-            return _flash_padded(
+            return _flash_on_mesh(
                 q, k, v, kv_lens, causal, scale, block_q, block_k
             )
     if mask is None and kv_lens is not None:

@@ -26,9 +26,10 @@ bitwise the optax path's, and the rebuilt ``opt_state``
 (``EmptyState``, (``ScaleByAdamState``, ``ScaleByScheduleState``))
 keeps checkpoints and the NaN-guard's where-select structure unchanged.
 
-The Pallas kernels are elementwise over lane-padded 2-D views (no
-cross-element reductions except ``unscale_sqsum``'s whole-leaf sum,
-which runs single-block to preserve the reference reduction order —
+The Pallas kernels are elementwise over lane-padded 2-D views, the Adam
+kernel row-blocked over a grid so a leaf of any size streams through
+VMEM (no cross-element reductions except ``unscale_sqsum``'s whole-leaf
+sum, which runs single-block to preserve the reference reduction order —
 leaves past the VMEM budget fall back to the reference).  Output
 shapes/dtypes come from ``jax.eval_shape`` of the reference, so the
 kernels inherit its promotion semantics exactly.
@@ -49,9 +50,15 @@ B1, B2, EPS, EPS_ROOT = 0.9, 0.999, 1e-8, 0.0
 
 # unscale_sqsum runs the whole leaf as one Pallas block (reduction-order
 # preservation); leaves above this many elements use the reference.
-_SQSUM_VMEM_ELEMS = 2 * 1024 * 1024
+# 1M f32 elements is 4 MB in + 4 MB out, half the 16 MB a TPU core's
+# default scoped-VMEM limit allows one kernel.
+_SQSUM_VMEM_ELEMS = 1024 * 1024
 
 _LANES = 128
+# Rows of the (-1, 128) view one grid step of the Adam kernel takes: four
+# inputs + four outputs, double-buffered, at 512 x 128 f32 = 256 KB each
+# is 4 MB of VMEM whatever the leaf's size.
+_ADAM_BLOCK_ROWS = 512
 
 
 def adam_scalars(count, sched_count, lr_schedule):
@@ -238,11 +245,20 @@ def _adam_pallas(g, p, mu, nu, bc1, bc2, step_size, lr_scale, factor,
         jnp.asarray(factor if has_factor else 1.0, jnp.float32),
     ]).reshape(1, 5)
     flats = [_flat2(t) for t in (g, p, mu, nu)]
+    # Elementwise over the row-blocked view: a leaf of any size streams
+    # through VMEM one block at a time (GPT-2's 50257 x 768 embedding is
+    # 154 MB per operand — whole-leaf operands cannot fit).  A short leaf
+    # is one block of all its rows; a ragged last block reads padding it
+    # never writes back.
+    rows = flats[0].shape[0]
+    block_rows = min(_ADAM_BLOCK_ROWS, rows)
+    block = pl.BlockSpec((block_rows, _LANES), lambda i: (i, 0),
+                         memory_space=pltpu.VMEM)
     outs = pl.pallas_call(
         functools.partial(_adam_kernel, has_factor=has_factor),
-        in_specs=[pl.BlockSpec(memory_space=pltpu.SMEM)]
-        + [pl.BlockSpec(memory_space=pltpu.VMEM)] * 4,
-        out_specs=[pl.BlockSpec(memory_space=pltpu.VMEM)] * 4,
+        grid=(pl.cdiv(rows, block_rows),),
+        in_specs=[pl.BlockSpec(memory_space=pltpu.SMEM)] + [block] * 4,
+        out_specs=[block] * 4,
         out_shape=[
             jax.ShapeDtypeStruct(flats[1].shape, ref_out[0].dtype),
             jax.ShapeDtypeStruct(flats[2].shape, ref_out[1].dtype),

@@ -72,9 +72,13 @@ def _int8_pallas(x, w_q, scale, block_n, interpret):
     x2 = x.reshape(-1, x.shape[-1])
     m, k = x2.shape
     n = w_q.shape[-1]
-    bn = min(block_n, n)
-    if n % bn:
-        bn = n  # ragged N: one block (decode N is 128-aligned in practice)
+    # Largest lane-aligned block <= block_n that tiles N exactly (GPT-2's
+    # qkv N=2304 takes 384); an N no 128-multiple divides rides whole.
+    bn = next(
+        (c for c in range(min(block_n, n) // 128 * 128, 0, -128)
+         if n % c == 0),
+        n,
+    )
     y = pl.pallas_call(
         _int8_kernel,
         grid=(n // bn,),

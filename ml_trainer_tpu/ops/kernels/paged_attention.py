@@ -86,15 +86,19 @@ def _paged_kernel(table_ref, lens_ref, q_ref, k_ref, v_ref, o_ref,
     b_i = pl.program_id(0)
     p_i = pl.program_id(2)
     L = pages * page_size
-    k_scr[pl.ds(p_i * page_size, page_size), :] = k_ref[0, 0]
-    v_scr[pl.ds(p_i * page_size, page_size), :] = v_ref[0, 0]
+    # A dynamic-start store on the sublane axis: Mosaic needs to know the
+    # start is tile-aligned (page_size must be a multiple of the cache
+    # dtype's sublane tile — 8 rows of f32, 16 of bf16).
+    start = pl.multiple_of(p_i * page_size, page_size)
+    k_scr[pl.ds(start, page_size), :] = k_ref[0, 0]
+    v_scr[pl.ds(start, page_size), :] = v_ref[0, 0]
 
     @pl.when(p_i == pages - 1)
     def _finish():
         # The exact dot_product_attention op chain on the [1, L] row:
         # f32 score dot, python-float scale, ADDED mask bias, softmax,
         # weights cast to v.dtype then f32 for the output dot.
-        qv = q_ref[0].astype(jnp.float32)                      # [1, D]
+        qv = q_ref[0, 0].astype(jnp.float32)                   # [1, D]
         scores = jax.lax.dot_general(
             qv, k_scr[...].astype(jnp.float32),
             (((1,), (1,)), ((), ())),
@@ -109,7 +113,7 @@ def _paged_kernel(table_ref, lens_ref, q_ref, k_ref, v_ref, o_ref,
             (((1,), (0,)), ((), ())),
             preferred_element_type=jnp.float32,
         )                                                      # [1, D]
-        o_ref[0] = out.astype(o_ref.dtype)
+        o_ref[0, 0] = out.astype(o_ref.dtype)
 
 
 def _paged_attention_pallas(q, k_pool, v_pool, table, lengths, scale,
@@ -122,11 +126,17 @@ def _paged_attention_pallas(q, k_pool, v_pool, table, lengths, scale,
     P = table.shape[-1]
     L = P * ps
 
+    # q/out ride as [B, H, 1, D] with (1, 1, 1, D) blocks: a block's last
+    # two dims must equal the array's (or be (8, 128)-divisible), and a
+    # (1, D) block on [.., H, D] puts a 1 on the H axis — which the TPU
+    # lowering rejects and interpret mode never checks.
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2,
         grid=(b, h, P),
         in_specs=[
-            pl.BlockSpec((1, 1, d), lambda bi, hi, pi, tbl, lens: (bi, hi, 0)),
+            pl.BlockSpec(
+                (1, 1, 1, d), lambda bi, hi, pi, tbl, lens: (bi, hi, 0, 0)
+            ),
             # The fused gather: page p of row b streams in from whatever
             # pool page the prefetched table names for it.
             pl.BlockSpec(
@@ -139,7 +149,7 @@ def _paged_attention_pallas(q, k_pool, v_pool, table, lengths, scale,
             ),
         ],
         out_specs=pl.BlockSpec(
-            (1, 1, d), lambda bi, hi, pi, tbl, lens: (bi, hi, 0)
+            (1, 1, 1, d), lambda bi, hi, pi, tbl, lens: (bi, hi, 0, 0)
         ),
         scratch_shapes=[
             pltpu.VMEM((L, d), k_pool.dtype),
@@ -152,9 +162,9 @@ def _paged_attention_pallas(q, k_pool, v_pool, table, lengths, scale,
     return pl.pallas_call(
         kernel,
         grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((b, h, d), q.dtype),
+        out_shape=jax.ShapeDtypeStruct((b, h, 1, d), q.dtype),
         interpret=interpret,
-    )(table, lengths, q, k_pool, v_pool)
+    )(table, lengths, q[:, :, None, :], k_pool, v_pool)[:, :, 0, :]
 
 
 def paged_attention(

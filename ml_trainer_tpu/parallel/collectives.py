@@ -22,7 +22,6 @@ from typing import Union, Sequence
 from jax import lax
 
 from ml_trainer_tpu.parallel.comm_stats import account as _account
-from ml_trainer_tpu.parallel.compat import axis_size as _axis_size
 
 AxisName = Union[str, Sequence[str]]
 
@@ -62,7 +61,7 @@ def reduce_scatter(x, axis: AxisName, *, scatter_axis: int = 0,
 def ppermute_ring(x, axis: AxisName, shift: int = 1):
     """Send each shard to its ring neighbour over ICI — the building block
     of ring attention (parallel/ring.py rotates K/V through it)."""
-    n = _axis_size(axis)
+    n = lax.axis_size(axis)
     perm = [(i, (i + shift) % n) for i in range(n)]
     _account("ppermute", x, axis)
     return lax.ppermute(x, axis, perm)
@@ -82,4 +81,4 @@ def axis_index(axis: AxisName):
 
 
 def axis_size(axis: AxisName):
-    return _axis_size(axis)
+    return lax.axis_size(axis)

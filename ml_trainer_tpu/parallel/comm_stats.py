@@ -45,6 +45,7 @@ import threading
 from typing import Dict, Tuple
 
 import numpy as np
+from jax import lax
 
 _lock = threading.Lock()
 _bytes: Dict[str, float] = {}
@@ -192,14 +193,12 @@ def account(op: str, x, axis, times: int = 1, bucket: str = None,
     leaves) is swallowed so the wrapped collective always executes
     unchanged."""
     try:
-        from ml_trainer_tpu.parallel.compat import axis_size as _axis_size
-
         if isinstance(axis, (tuple, list)):
             n = 1
             for a in axis:
-                n *= int(_axis_size(a))
+                n *= int(lax.axis_size(a))
         else:
-            n = int(_axis_size(axis))
+            n = int(lax.axis_size(axis))
         n_bytes = collective_bytes(op, _tree_bytes(x), n) * int(times)
         record_collective(op, n_bytes, calls=int(times), bucket=bucket)
         if hop is not None:

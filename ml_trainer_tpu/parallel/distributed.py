@@ -38,10 +38,16 @@ def initialize_distributed(
         _INITIALIZED = True
         return
     explicit = coordinator_address is not None
-    auto = any(
-        v in os.environ
-        for v in ("COORDINATOR_ADDRESS", "CLOUD_TPU_TASK_ID", "TPU_WORKER_ID")
-    )
+    # Rendezvous only when the environment describes MORE THAN ONE host.
+    # A single TPU host also carries TPU_WORKER_ID / CLOUD_TPU_TASK_ID,
+    # and there jax.distributed.initialize() has nobody to meet: its
+    # cluster auto-detection asks a metadata service a sealed machine
+    # cannot reach.  One process drives all the chips of one host.
+    hosts = [
+        h for h in os.environ.get("TPU_WORKER_HOSTNAMES", "").split(",")
+        if h.strip()
+    ]
+    auto = "COORDINATOR_ADDRESS" in os.environ or len(hosts) > 1
     if explicit or auto:
         jax.distributed.initialize(
             coordinator_address=coordinator_address,

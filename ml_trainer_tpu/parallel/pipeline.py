@@ -60,7 +60,7 @@ from typing import Any, Callable, Dict, Optional
 import jax
 import jax.numpy as jnp
 import numpy as np
-from jax import lax
+from jax import lax, shard_map
 from jax.sharding import Mesh, PartitionSpec as P
 
 from ml_trainer_tpu.parallel.comm_stats import (
@@ -69,7 +69,6 @@ from ml_trainer_tpu.parallel.comm_stats import (
     record_collective as _record_collective,
     record_hop as _record_hop,
 )
-from ml_trainer_tpu.parallel.compat import axis_size, shard_map
 
 SCHEDULES = ("gpipe", "1f1b", "interleaved", "zb")
 PIPELINE_SCHEDULES = SCHEDULES  # public alias (parallel/__init__.py)
@@ -363,7 +362,7 @@ def _ring_broadcast(val, root: int, axis_name: str, *, schedule: str,
     old output ``psum`` paid (and no reduction compute).  Each call's
     analytic bytes are recorded per participant (size · active pairs /
     S) against the schedule's hop ledger."""
-    n = axis_size(axis_name)
+    n = lax.axis_size(axis_name)
     if n <= 1:
         return val
     stage = lax.axis_index(axis_name)
@@ -392,7 +391,7 @@ def _pipeline_local(params, x, *, stage_fn, axis_name, n_micro, remat):
     params: this device's stage params (leading stage dim of size 1).
     x: the full [n_micro, mb, ...] microbatched input (replicated).
     """
-    n_stages = axis_size(axis_name)
+    n_stages = lax.axis_size(axis_name)
     stage = lax.axis_index(axis_name)
     params = jax.tree.map(lambda p: p[0], params)  # drop the stage dim
     mb_shape = x.shape[1:]
@@ -465,7 +464,7 @@ def _engine_fwd_local(params, x, *, stage_fn, axis_name, tables, n_f_slots,
     and ring-broadcast at the end.  With ``want_stash`` every stage
     input is also written into a [V, M] boundary-activation stash — the
     ``remat=False`` backward's residuals."""
-    S = axis_size(axis_name)
+    S = lax.axis_size(axis_name)
     stage = lax.axis_index(axis_name)
     n_micro, mb_shape = x.shape[0], x.shape[1:]
     fwd_perm = [(s, (s + 1) % S) for s in range(S)]
@@ -534,7 +533,7 @@ def _engine_bwd_local(params, x, stash, dy, *, stage_fn, axis_name, tables,
     fused backward (``jax.vjp`` of the stage), or the zb split halves.
     Param grads accumulate per local virtual stage; the input cotangent
     is captured on device 0 and ring-broadcast out."""
-    S = axis_size(axis_name)
+    S = lax.axis_size(axis_name)
     stage = lax.axis_index(axis_name)
     n_micro, mb_shape = x.shape[0], x.shape[1:]
     fwd_perm = [(s, (s + 1) % S) for s in range(S)]

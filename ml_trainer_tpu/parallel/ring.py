@@ -20,12 +20,11 @@ from typing import Optional
 
 import jax
 import jax.numpy as jnp
-from jax import lax
+from jax import lax, shard_map
 
 from ml_trainer_tpu.parallel.collectives import ppermute_ring
 from ml_trainer_tpu.parallel.comm_stats import account as _comm_account
 from jax.sharding import Mesh, PartitionSpec as P
-from ml_trainer_tpu.parallel.compat import axis_size, shard_map
 
 
 def _block_attend(q, k, v, m_prev, l_prev, o_prev, q_offset, k_offset,
@@ -54,7 +53,7 @@ def _block_attend(q, k, v, m_prev, l_prev, o_prev, q_offset, k_offset,
 
 def _ring_attention_local(q, k, v, *, axis_name, causal, scale):
     """Runs per-shard inside shard_map.  q/k/v: [B, H, S_local, D]."""
-    n = axis_size(axis_name)
+    n = lax.axis_size(axis_name)
     my = lax.axis_index(axis_name)
     s_local = q.shape[-2]
     q32 = q.astype(jnp.float32)

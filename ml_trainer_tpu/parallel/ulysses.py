@@ -25,10 +25,10 @@ import functools
 from typing import Optional
 
 import jax
+from jax import shard_map
+from jax.sharding import Mesh, PartitionSpec as P
 
 from ml_trainer_tpu.parallel.collectives import all_to_all
-from jax.sharding import Mesh, PartitionSpec as P
-from ml_trainer_tpu.parallel.compat import shard_map
 
 
 def _ulysses_local(q, k, v, *, axis_name, causal, scale, attend):

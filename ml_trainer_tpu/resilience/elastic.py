@@ -261,11 +261,13 @@ def precheck_topology(model, batch_shape: Sequence[int],
                       optimizer: str = "adamw",
                       capacity_bytes: Optional[float] = None,
                       margin: float = 0.95,
+                      generation: Optional[str] = None,
                       **plan_kwargs) -> dict:
     """Price a target topology through the analytic memory ledger BEFORE
     any device allocates (``plan_train_memory`` is ``jax.eval_shape``
     only) and raise :class:`TopologyError` when the predicted per-device
-    peak exceeds ``margin`` × chip capacity.  Returns the planner's
+    peak exceeds ``margin`` × chip capacity (``capacity_bytes``, else
+    ``generation``'s HBM, else the local chip's).  Returns the planner's
     verdict dict on success — the elastic controller calls this with the
     post-reshape mesh shape, so a reshape that cannot fit fails with the
     planner's numbers instead of a device OOM mid-recovery."""
@@ -276,7 +278,8 @@ def precheck_topology(model, batch_shape: Sequence[int],
         mesh_shape=mesh_shape, **plan_kwargs,
     )
     verdict = fit_verdict(
-        ledger.peak_bytes(), capacity_bytes=capacity_bytes, margin=margin
+        ledger.peak_bytes(), capacity_bytes=capacity_bytes, margin=margin,
+        generation=generation,
     )
     verdict["mesh_shape"] = dict(mesh_shape or {})
     if verdict["verdict"] == "oom" or (

@@ -48,6 +48,7 @@ import urllib.error
 import urllib.request
 from typing import Dict, List, Optional, Sequence
 
+from ml_trainer_tpu.trainer import COMPILE_CACHE_DIR
 from ml_trainer_tpu.utils.logging import get_logger
 from ml_trainer_tpu.serving.overload import OverloadShed
 from ml_trainer_tpu.serving.scheduler import (
@@ -469,10 +470,17 @@ class Fleet:
         ...
         router.close(); fleet.stop()
 
-    Worker processes share one on-disk XLA compile cache directory
-    (``compile_cache_dir``), are pinned to CPU with a single device,
-    and never inherit an active chaos plan — faults are the DRIVER's
-    job, a worker must only ever die by real signal."""
+    Worker processes share one on-disk XLA compile cache — wherever
+    ``JAX_COMPILATION_CACHE_DIR`` places it, else the checkout's fixed
+    ``trainer.COMPILE_CACHE_DIR`` — and never inherit an active chaos
+    plan: faults are the DRIVER's job, a worker must only ever die by
+    real signal.
+
+    Every worker is PINNED TO THE CPU (``JAX_PLATFORMS=cpu``, one host
+    device): a chip belongs to one process, and putting replicas on
+    chips (one process driving several devices, or one chip per worker
+    with the parent off JAX) is ROADMAP R5a's work, not done here.  A
+    fleet therefore measures nothing about a TPU."""
 
     def __init__(self, roles: Sequence[str], *,
                  model_name: str = "gpt2_tiny", max_len: int = 256,
@@ -481,7 +489,6 @@ class Fleet:
                  seed: int = 0, prefill_chunk: int = 0,
                  prefix_cache: bool = True,
                  host: str = "127.0.0.1",
-                 compile_cache_dir: Optional[str] = None,
                  log_dir: Optional[str] = None,
                  spawn_timeout: float = 180.0,
                  stream_timeout: float = 600.0):
@@ -498,9 +505,6 @@ class Fleet:
         self.host = host
         self.spawn_timeout = float(spawn_timeout)
         self.stream_timeout = float(stream_timeout)
-        self.compile_cache_dir = compile_cache_dir or tempfile.mkdtemp(
-            prefix="fleet-xla-cache-"
-        )
         self.log_dir = log_dir or tempfile.mkdtemp(prefix="fleet-logs-")
         self.replicas: Dict[str, RemoteServer] = {}
         self._role_seq: Dict[str, int] = {}
@@ -520,7 +524,8 @@ class Fleet:
         env.pop("ML_TRAINER_TPU_FAULTS", None)
         env["JAX_PLATFORMS"] = "cpu"
         env["XLA_FLAGS"] = "--xla_force_host_platform_device_count=1"
-        env["JAX_COMPILATION_CACHE_DIR"] = self.compile_cache_dir
+        # Placed from outside if the variable is set; JAX reads it itself.
+        env.setdefault("JAX_COMPILATION_CACHE_DIR", COMPILE_CACHE_DIR)
         return env
 
     def spawn(self, name: str, role: str,
@@ -674,15 +679,10 @@ def _worker_main(argv: Optional[List[str]] = None) -> int:
     import jax
     import numpy as np
 
-    cache_dir = os.environ.get("JAX_COMPILATION_CACHE_DIR")
-    if cache_dir:
-        try:  # shared on-disk compile cache (best effort on CPU)
-            jax.config.update("jax_compilation_cache_dir", cache_dir)
-            jax.config.update(
-                "jax_persistent_cache_min_compile_time_secs", 0.0
-            )
-        except Exception:
-            pass
+    # The fleet's shared on-disk compile cache is JAX_COMPILATION_CACHE_DIR
+    # (Fleet._worker_env); gpt2_tiny's CPU compiles are under the default
+    # one-second threshold, so cache every program.
+    jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)
 
     from ml_trainer_tpu.models import get_model
     from ml_trainer_tpu.serving.api import Server

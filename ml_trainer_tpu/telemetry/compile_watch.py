@@ -23,14 +23,15 @@ real instrument on JAX's own compilation path:
 * compile seconds feed the goodput ledger's ``compile`` bucket
   (``telemetry/goodput.py``) — wall-clock attribution, not just counts.
 
-Mechanism: :func:`install` wraps ``jax._src.dispatch.log_elapsed_time``
-(the one funnel both the pjit and pmap lowering paths time their
-backend compiles through — looked up as a module attribute at call
-time, so the wrap takes effect everywhere) and registers a capture
-handler on the ``jax._src.pjit`` logger for the cache-miss
-explanations.  If a future jax moves the funnel, ``install`` degrades
-to the public ``jax.monitoring`` duration listener — counts and
-elapsed survive, function names become ``"unknown"``.  The observed
+Mechanism (jax 0.9): :func:`install` registers a public
+``jax.monitoring`` duration listener — JAX records every backend compile
+under ``BACKEND_COMPILE_EVENT`` with the function's name as ``fun_name``
+— and an event listener for the persistent cache's hit/miss events, and
+puts a capturing filter on the loggers ``jax_explain_cache_misses``
+writes to (``jax._src.interpreters.partial_eval`` for its ``TRACING
+CACHE MISS`` explanations).  A compile served from the persistent cache
+still counts as a compile event (its elapsed time is the retrieval);
+:func:`persistent_cache_counts` tells the two apart.  The observed
 programs are untouched: this is pure host-side bookkeeping, so the
 compiled-step trajectory stays bit-identical with the watch installed
 (test-pinned).
@@ -49,9 +50,18 @@ from ml_trainer_tpu.utils.logging import get_logger
 
 logger = get_logger("ml_trainer_tpu.telemetry")
 
-# The jax.monitoring key the backend-compile timer records under —
-# public, stable across 0.4.x (jax._src.dispatch.BACKEND_COMPILE_EVENT).
+# The jax.monitoring keys JAX records a backend compile and the
+# persistent compilation cache's verdict on it under.
 BACKEND_COMPILE_EVENT = "/jax/core/compile/backend_compile_duration"
+CACHE_HIT_EVENT = "/jax/compilation_cache/cache_hits"
+CACHE_MISS_EVENT = "/jax/compilation_cache/cache_misses"
+# Where jax_explain_cache_misses logs: its TRACING CACHE MISS text, and —
+# once a persistent cache directory is set — a WARNING for every
+# persistent-cache miss and every program too quick to be written back.
+_EXPLAIN_LOGGERS = (
+    "jax._src.interpreters.partial_eval", "jax._src.compiler",
+    "jax._src.compilation_cache",
+)
 
 _MAX_EVENTS = 512  # bounded ring; a compile storm must not grow the host
 _MAX_EXPLANATION = 2000  # chars kept of a cache-miss explanation
@@ -78,7 +88,6 @@ class _State:
     def __init__(self):
         self.lock = threading.Lock()
         self.installed = False
-        self.mode = "off"  # "patched" | "monitoring" | "off"
         self.events: List[CompileEvent] = []
         self.seq = 0
         self.total = 0
@@ -86,29 +95,38 @@ class _State:
         self.warm = False
         self.by_fn: Dict[str, int] = {}
         self.pending_explanation: Optional[str] = None
-        self.orig_log_elapsed = None
-        self.explain_handler: Optional[logging.Handler] = None
-        self.explain_prev_propagate: Optional[bool] = None
+        self.cache_hits = 0
+        self.cache_misses = 0
+        self.explain_filter: Optional[logging.Filter] = None
         self.explain_prev_config: Optional[bool] = None
 
 
 _state = _State()
 
 
-class _ExplainHandler(logging.Handler):
-    """Captures ``TRACING CACHE MISS`` explanations (jax._src.pjit logs
-    them at WARNING when ``jax_explain_cache_misses`` is on) so the next
-    compile event can name the offending argument/shape."""
+class _ExplainFilter(logging.Filter):
+    """Captures — and swallows, so every first-seen-function trace does
+    not spam the user's log — the ``TRACING CACHE MISS`` explanations JAX
+    logs at WARNING when ``jax_explain_cache_misses`` is on, so the next
+    compile event can name the offending argument/shape.  The flag's
+    persistent-cache chatter is swallowed too (the monitoring events
+    already count hits and misses).  Other records pass untouched."""
 
-    def emit(self, record: logging.LogRecord) -> None:
+    def filter(self, record: logging.LogRecord) -> bool:
         try:
             msg = record.getMessage()
         except Exception:
-            return
+            return True
+        if record.levelno == logging.WARNING and msg.startswith((
+            "PERSISTENT COMPILATION CACHE MISS",
+            "Not writing persistent cache entry", "Writing ",
+        )):
+            return False
         if "TRACING CACHE MISS" not in msg:
-            return
+            return True
         with _state.lock:
             _state.pending_explanation = msg[:_MAX_EXPLANATION]
+        return False
 
 
 def _on_compile(fn: str, elapsed_s: float) -> None:
@@ -171,82 +189,47 @@ def _on_compile(fn: str, elapsed_s: float) -> None:
         )
 
 
-def _patched_log_elapsed_time(orig):
-    @contextlib.contextmanager
-    def wrapped(fmt, fun_name, event=None):
-        t0 = time.perf_counter()
-        with orig(fmt, fun_name, event=event):
-            yield
-        if event == BACKEND_COMPILE_EVENT:
-            _on_compile(str(fun_name), time.perf_counter() - t0)
-
-    return wrapped
+def _duration_listener(event: str, duration: float, **kwargs) -> None:
+    if event == BACKEND_COMPILE_EVENT:
+        _on_compile(str(kwargs.get("fun_name", "unknown")), float(duration))
 
 
-def install() -> str:
-    """Install the compile watch (idempotent).  Returns the active mode:
-    ``"patched"`` (full forensics) or ``"monitoring"`` (counts + elapsed
-    only — the jax internals moved)."""
+def _event_listener(event: str, **kwargs) -> None:
+    if event == CACHE_HIT_EVENT:
+        with _state.lock:
+            _state.cache_hits += 1
+    elif event == CACHE_MISS_EVENT:
+        with _state.lock:
+            _state.cache_misses += 1
+
+
+def install() -> None:
+    """Install the compile watch (idempotent)."""
     with _state.lock:
         if _state.installed:
-            return _state.mode
+            return
         _state.installed = True
     # Register the post-warmup counter eagerly (at 0): the fleet's
     # metrics federation (serving/router.py) pins every worker's
     # ``compile_events_post_warmup_total`` in the merged exposition —
     # absence must mean "watch not installed", never "no recompile yet".
-    try:
-        from ml_trainer_tpu.telemetry.registry import default_registry
+    from ml_trainer_tpu.telemetry.registry import default_registry
 
-        default_registry().counter(
-            "compile_events_post_warmup_total",
-            "compiles AFTER the owning loop declared warmup done — "
-            "each one is a steady-state recompile to investigate",
-        )
-    except Exception:
-        pass
+    default_registry().counter(
+        "compile_events_post_warmup_total",
+        "compiles AFTER the owning loop declared warmup done — "
+        "each one is a steady-state recompile to investigate",
+    )
     import jax
 
-    mode = "monitoring"
-    try:
-        from jax._src import dispatch as _dispatch
-
-        orig = _dispatch.log_elapsed_time
-        _dispatch.log_elapsed_time = _patched_log_elapsed_time(orig)
-        _state.orig_log_elapsed = orig
-        mode = "patched"
-    except Exception as e:
-        logger.warning(
-            f"compile watch: jax internals moved ({e}); falling back to "
-            "the monitoring listener (no function names)"
-        )
-        import jax.monitoring as _mon
-
-        def _listener(key, dur, **kw):
-            if key == BACKEND_COMPILE_EVENT:
-                _on_compile("unknown", float(dur))
-
-        _mon.register_event_duration_secs_listener(_listener)
-    # Cache-miss explanations: jax logs them (WARNING, jax._src.pjit)
-    # when the flag is on; our handler captures, propagation is silenced
-    # while installed so every first-seen-function trace does not spam
-    # the user's log (uninstall restores both).
-    try:
-        plog = logging.getLogger("jax._src.pjit")
-        handler = _ExplainHandler()
-        plog.addHandler(handler)
-        _state.explain_handler = handler
-        _state.explain_prev_propagate = plog.propagate
-        plog.propagate = False
-        _state.explain_prev_config = bool(
-            jax.config.jax_explain_cache_misses
-        )
-        jax.config.update("jax_explain_cache_misses", True)
-    except Exception:
-        _state.explain_handler = None
-    _state.mode = mode
-    logger.info(f"compile watch installed (mode={mode})")
-    return mode
+    jax.monitoring.register_event_duration_secs_listener(_duration_listener)
+    jax.monitoring.register_event_listener(_event_listener)
+    _state.explain_filter = _ExplainFilter()
+    for name in _EXPLAIN_LOGGERS:
+        logging.getLogger(name).addFilter(_state.explain_filter)
+    _state.explain_prev_config = bool(jax.config.jax_explain_cache_misses)
+    jax.config.update("jax_explain_cache_misses", True)
+    logger.info("compile watch installed")
 
 
 def uninstall() -> None:
@@ -255,30 +238,14 @@ def uninstall() -> None:
         if not _state.installed:
             return
         _state.installed = False
-        _state.mode = "off"
-    if _state.orig_log_elapsed is not None:
-        try:
-            from jax._src import dispatch as _dispatch
+    import jax
 
-            _dispatch.log_elapsed_time = _state.orig_log_elapsed
-        except Exception:
-            pass
-        _state.orig_log_elapsed = None
-    if _state.explain_handler is not None:
-        try:
-            import jax
-
-            plog = logging.getLogger("jax._src.pjit")
-            plog.removeHandler(_state.explain_handler)
-            if _state.explain_prev_propagate is not None:
-                plog.propagate = _state.explain_prev_propagate
-            if _state.explain_prev_config is not None:
-                jax.config.update(
-                    "jax_explain_cache_misses", _state.explain_prev_config
-                )
-        except Exception:
-            pass
-        _state.explain_handler = None
+    jax.monitoring.unregister_event_duration_listener(_duration_listener)
+    jax.monitoring.unregister_event_listener(_event_listener)
+    for name in _EXPLAIN_LOGGERS:
+        logging.getLogger(name).removeFilter(_state.explain_filter)
+    _state.explain_filter = None
+    jax.config.update("jax_explain_cache_misses", _state.explain_prev_config)
 
 
 def installed() -> bool:
@@ -318,6 +285,16 @@ def post_warmup_count() -> int:
         return _state.post_warmup
 
 
+def persistent_cache_counts() -> Dict[str, int]:
+    """Persistent-compilation-cache verdicts since install/reset: ``hits``
+    are compile events served from ``jax_compilation_cache_dir``,
+    ``misses`` went to the compiler and were written back.  Both 0 when
+    no cache directory is configured (or a program compiled under the
+    cache's minimum compile time)."""
+    with _state.lock:
+        return {"hits": _state.cache_hits, "misses": _state.cache_misses}
+
+
 def counts_by_fn() -> Dict[str, int]:
     with _state.lock:
         return dict(_state.by_fn)
@@ -346,6 +323,8 @@ def reset() -> None:
         _state.warm = False
         _state.by_fn.clear()
         _state.pending_explanation = None
+        _state.cache_hits = 0
+        _state.cache_misses = 0
 
 
 @contextlib.contextmanager

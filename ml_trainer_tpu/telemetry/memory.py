@@ -780,24 +780,32 @@ def serving_kv_ledger(engine) -> MemoryLedger:
 
 # ---------------------------------------------------------------- planner
 def fit_verdict(peak_bytes: float, capacity_bytes: Optional[float] = None,
-                margin: float = 0.9) -> dict:
+                margin: float = 0.9,
+                generation: Optional[str] = None) -> dict:
     """fit-or-OOM verdict: predicted peak vs chip HBM capacity.  "fits"
-    under ``margin`` × capacity, "tight" under capacity, else "oom"."""
+    under ``margin`` × capacity, "tight" under capacity, else "oom".
+    The capacity is ``capacity_bytes``, else the HBM of ``generation``
+    (a ``telemetry/flops.py`` table key, e.g. ``"v5e"`` — how a host
+    without the chip prices one), else the local chip's; a device the
+    table does not know is an error."""
     from ml_trainer_tpu.telemetry.flops import (
-        chip_generation_label,
+        chip_generation,
         chip_hbm_capacity_bytes,
     )
 
     cap = (
         float(capacity_bytes) if capacity_bytes is not None
-        else chip_hbm_capacity_bytes()
+        else chip_hbm_capacity_bytes(generation)
     )
     frac = peak_bytes / cap if cap > 0 else float("inf")
     verdict = "fits" if frac <= margin else ("tight" if frac <= 1.0 else "oom")
     return {
         "peak_bytes": int(peak_bytes),
         "capacity_bytes": int(cap),
-        "chip": chip_generation_label(),
+        "chip": (
+            "explicit-capacity" if capacity_bytes is not None
+            else chip_generation(generation)
+        ),
         "utilization": round(frac, 4),
         "margin": margin,
         "verdict": verdict,

@@ -217,7 +217,6 @@ def main() -> int:
         "total": compile_watch.compile_count(),
         "post_warmup": compile_watch.post_warmup_count(),
         "train_step": train_compiles,
-        "mode": compile_watch.install(),
     }
     # Live-vs-analytic exposition both landed in the registry.
     for key in ("mem_analytic_resident_bytes", "mem_live_bytes{device=0}"):

@@ -153,7 +153,7 @@ def main() -> int:
 
     from ml_trainer_tpu.parallel import create_mesh
     from ml_trainer_tpu.parallel.collectives import psum
-    from ml_trainer_tpu.parallel.compat import shard_map
+    from jax import shard_map
 
     if jax.device_count() < 2:
         return fail(f"expected 2 virtual devices, got {jax.device_count()}")

@@ -19,7 +19,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
-from jax import lax
+from jax import lax, shard_map
 from jax.sharding import Mesh, PartitionSpec as P
 
 from ml_trainer_tpu.analysis import (
@@ -36,7 +36,6 @@ from ml_trainer_tpu.analysis import (
 )
 from ml_trainer_tpu.analysis import ast_checks, jaxpr_checks
 from ml_trainer_tpu.analysis.findings import Finding
-from ml_trainer_tpu.parallel.compat import shard_map
 
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 

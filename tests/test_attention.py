@@ -236,7 +236,7 @@ def test_flash_inside_shard_map_matches_dense():
     from jax.sharding import PartitionSpec as P
 
     from ml_trainer_tpu.parallel import create_mesh
-    from ml_trainer_tpu.parallel.compat import shard_map
+    from jax import shard_map
 
     mesh = create_mesh({"sequence": 4}, devices=jax.devices()[:4])
     q, k, v = qkv(b=2, h=4, s=256, d=64, seed=7)
@@ -262,6 +262,78 @@ def test_flash_inside_shard_map_matches_dense():
     out = jax.jit(fn)(q, k, v)
     ref = dot_product_attention(q, k, v, causal=True)
     np.testing.assert_allclose(out, ref, atol=2e-3, rtol=2e-3)
+
+
+@pytest.mark.parametrize("shape", [{"data": 4}, {"data": 2, "tensor": 2}])
+def test_flash_on_a_declared_mesh_runs_in_shard_map(shape, monkeypatch):
+    """GSPMD cannot partition a Mosaic kernel: under a jit over several
+    devices the flash call must sit in a shard_map, each device on its
+    block of the batch (and heads).  Values and grads still equal dense,
+    kv_lens included, and nothing is wrapped twice inside a shard_map."""
+    from jax.sharding import NamedSharding, PartitionSpec as P
+
+    from ml_trainer_tpu.ops import attention as A
+    from ml_trainer_tpu.parallel import create_mesh
+
+    mesh = create_mesh(shape, devices=jax.devices()[:4])
+    q, k, v = qkv(b=4, h=4, s=128, d=64, seed=11)
+    kv_lens = jnp.asarray([128, 100, 7, 64], jnp.int32)
+    with A.kernel_mesh(mesh):
+        spec, lens_spec, ways = A._kernel_specs(q)
+        assert ways == shape["data"] and spec == P(
+            "data", "tensor" if "tensor" in shape else None, None, None)
+        # 'auto' leaves the kernel alone when the batch does not divide.
+        monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+        assert A._flash_supported(q, k) is True
+        assert A._flash_supported(q[:3], k[:3]) is False
+        monkeypatch.undo()
+
+    def loss(q, k, v, lens):
+        with A.kernel_mesh(mesh):
+            return A._flash_on_mesh(
+                q, k, v, lens, lens is None, None, 64, 64, interpret=True
+            ).sum()
+
+    def dense(q, k, v, lens):
+        mask = None if lens is None else (
+            jnp.arange(128)[None, None, None, :] < lens[:, None, None, None]
+        )
+        return dot_product_attention(
+            q, k, v, causal=lens is None, mask=mask
+        ).sum()
+
+    put = lambda t, s: jax.device_put(t, NamedSharding(mesh, s))
+    args = tuple(put(t, spec) for t in (q, k, v))
+
+    # What the chip run hit: lowered for the TPU, the compiled kernel under
+    # a multi-device jit is refused outright unless the mesh is declared.
+    # (The declaration is read while tracing, as in Trainer._apply.)
+    def lower_for_tpu(declared):
+        def compiled_kernel(q, k, v):
+            with A.kernel_mesh(declared):
+                return A._flash_on_mesh(q, k, v, None, True, None, 64, 64)
+
+        return jax.jit(compiled_kernel).trace(*args).lower(
+            lowering_platforms=("tpu",))
+
+    with pytest.raises(NotImplementedError, match="shard_map"):
+        lower_for_tpu(None)
+    assert "tpu_custom_call" in lower_for_tpu(mesh).as_text()
+    for lens in (None, put(kv_lens, lens_spec)):
+        got = jax.jit(jax.value_and_grad(loss, argnums=(0, 1, 2)))(
+            *args, lens)
+        want = jax.value_and_grad(dense, argnums=(0, 1, 2))(q, k, v, kv_lens
+                                                            if lens is not None
+                                                            else None)
+        for g, w in zip(jax.tree.leaves(got), jax.tree.leaves(want)):
+            np.testing.assert_allclose(g, w, atol=2e-3, rtol=2e-3)
+    # Already manual (the sharded train step's body): run as it is.
+    inner = jax.shard_map(
+        lambda q: jnp.asarray(A._kernel_specs(q) is None), mesh=mesh,
+        in_specs=spec, out_specs=P(), check_vma=False,
+    )
+    with A.kernel_mesh(mesh):
+        assert bool(jax.jit(inner)(args[0]))
 
 
 @pytest.mark.parametrize("causal", [False, True])

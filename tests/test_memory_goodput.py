@@ -168,12 +168,18 @@ def test_ledger_publish_and_live_snapshot():
 def test_fit_verdict_and_capacity_table():
     from ml_trainer_tpu.telemetry.flops import chip_hbm_capacity_bytes
 
-    cap = chip_hbm_capacity_bytes()
+    cap = chip_hbm_capacity_bytes("v5e")
     assert cap > 2 ** 30
-    assert M.fit_verdict(0.5 * cap)["verdict"] == "fits"
-    assert M.fit_verdict(0.95 * cap)["verdict"] == "tight"
-    oom = M.fit_verdict(1.5 * cap)
+    assert M.fit_verdict(0.5 * cap, generation="v5e")["verdict"] == "fits"
+    assert M.fit_verdict(0.95 * cap, generation="v5e")["verdict"] == "tight"
+    oom = M.fit_verdict(1.5 * cap, generation="v5e")
     assert oom["verdict"] == "oom" and oom["utilization"] > 1.0
+    # No default generation: the CPU mesh is not in the table, and an
+    # unknown device is an error, not a v5e guess.
+    with pytest.raises(ValueError, match="not in the chip peak tables"):
+        M.fit_verdict(0.5 * cap)
+    with pytest.raises(ValueError, match="unknown TPU generation"):
+        chip_hbm_capacity_bytes("v99")
 
 
 # ------------------------------------------------------- goodput buckets

@@ -678,7 +678,8 @@ def test_precheck_topology_structured_oom():
     assert v["peak_bytes"] > v["capacity_bytes"]
     # A sane capacity passes and returns the planner's verdict.
     ok = elastic.precheck_topology(
-        MLModel(), (16, 32, 32, 3), mesh_shape={"data": 4}
+        MLModel(), (16, 32, 32, 3), mesh_shape={"data": 4},
+        generation="v5e",
     )
     assert ok["verdict"] in ("fits", "tight")
 

@@ -426,7 +426,7 @@ def test_comm_accounting_formulas_and_traced_bytes():
         comm_calls,
         reset_comm_stats,
     )
-    from ml_trainer_tpu.parallel.compat import shard_map
+    from jax import shard_map
 
     # Formula pins (size=1024 bytes, n=4).
     assert collective_bytes("psum", 1024, 4) == 2 * 1024 * 3 / 4
