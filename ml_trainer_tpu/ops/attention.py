@@ -198,6 +198,7 @@ def _flash_forward(q, k, v, kv_lens, *, causal, scale, block_q, block_k,
             pltpu.VMEM((block_q, 1), jnp.float32),
         ],
         interpret=interpret,
+        name="flash_fwd",
     )(*operands)
     return out.reshape(b, h, s_q, d), lse.reshape(b, h, s_q)
 
@@ -396,6 +397,7 @@ def _flash_backward(q, k, v, kv_lens, out, lse, g, *, causal, scale, block_q,
         out_shape=jax.ShapeDtypeStruct((b * h, s_q, d), q.dtype),
         scratch_shapes=[pltpu.VMEM((block_q, d), jnp.float32)],
         interpret=interpret,
+        name="flash_bwd_dq",
     )(*operands)
 
     kvspec = pl.BlockSpec((1, block_k, d), lambda i, j, x: (i, j, 0),
@@ -420,6 +422,7 @@ def _flash_backward(q, k, v, kv_lens, out, lse, g, *, causal, scale, block_q,
             pltpu.VMEM((block_k, d), jnp.float32),
         ],
         interpret=interpret,
+        name="flash_bwd_dkv",
     )(*operands)
     return (
         dq.reshape(b, h, s_q, d),

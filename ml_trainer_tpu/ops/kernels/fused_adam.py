@@ -148,6 +148,7 @@ def _unscale_pallas(g, denom, compute_sq, interpret):
             jax.ShapeDtypeStruct((1, 1), jnp.float32),
         ],
         interpret=interpret,
+        name="fused_adam_norm",
     )(*args)
     out = out.reshape(g.shape)
     return (out, sq[0, 0]) if compute_sq else (out, None)
@@ -266,6 +267,7 @@ def _adam_pallas(g, p, mu, nu, bc1, bc2, step_size, lr_scale, factor,
             jax.ShapeDtypeStruct(flats[1].shape, ref_out[3].dtype),
         ],
         interpret=interpret,
+        name="fused_adam_update",
     )(scalars, *flats)
     return tuple(
         _unflat(o, r.shape) for o, r in zip(outs, ref_out)

@@ -96,6 +96,7 @@ def _int8_pallas(x, w_q, scale, block_n, interpret):
                                memory_space=pltpu.VMEM),
         out_shape=jax.ShapeDtypeStruct((m, n), x.dtype),
         interpret=interpret,
+        name="int8_matmul",
     )(x2, w_q, scale.reshape(1, -1))
     return y.reshape(*x.shape[:-1], n)
 

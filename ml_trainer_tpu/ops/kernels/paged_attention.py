@@ -164,6 +164,7 @@ def _paged_attention_pallas(q, k_pool, v_pool, table, lengths, scale,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((b, h, 1, d), q.dtype),
         interpret=interpret,
+        name="paged_attention_decode",
     )(table, lengths, q[:, :, None, :], k_pool, v_pool)[:, :, 0, :]
 
 

@@ -1145,7 +1145,10 @@ class Server:
                         sched.requeue(req)
                     progressed = True
                 if not progressed:
-                    self._wake.wait(timeout=self._idle_poll)
+                    # Idle with nothing to do, told from idle behind the
+                    # host: one span a poll (50 a second at the default).
+                    with spans.span("serve_wait"):
+                        self._wake.wait(timeout=self._idle_poll)
                     self._wake.clear()
             except Exception as e:  # noqa: BLE001 — the loop must survive
                 # Fail every in-flight request loudly rather than hang

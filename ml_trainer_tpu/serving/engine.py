@@ -1048,6 +1048,13 @@ class SlotDecodeEngine:
         return len(self._chunked)
 
     def admit(self, req: Request, slot: int) -> str:
+        """``_admit`` (which see for the statuses returned) under its
+        span: bookkeeping, prefill dispatch and the fence on the first
+        token are one stall of every decoding slot."""
+        with span("serve_admit", request=req.id, prompt_len=len(req.prompt)):
+            return self._admit(req, slot)
+
+    def _admit(self, req: Request, slot: int) -> str:
         """Prefill ``req`` into ``slot`` and emit its first token.
         Returns ``"active"`` (decoding), ``"finished"`` (EOS on token 0
         or a one-token budget — the caller recycles the slot),
@@ -1192,7 +1199,8 @@ class SlotDecodeEngine:
             if self._draft is not None:
                 self._admit_draft(prompt, slot, key, req.temperature)
         self._pos[slot] = p
-        tok0 = np.asarray(tok0)  # blocks until prefill + insert land
+        with span("serve_prefill.fence"):
+            tok0 = np.asarray(tok0)  # blocks until prefill + insert land
         prefill_dt = time.perf_counter() - t0
         req.prefill_secs += prefill_dt
         req.mark("prefill_done", ms=round(prefill_dt * 1e3, 3))
@@ -1527,39 +1535,43 @@ class SlotDecodeEngine:
         each slot advances 1..spec_k+1 tokens."""
         if not self._active:
             return []
-        cancel_freed = self._sweep_cancelled()
-        if not self._active:
-            return cancel_freed
-        self._step_seq += 1
-        # Flight record BEFORE the dispatch: when this step wedges, the
-        # ring's newest decode_step record names the step — and the
-        # REQUESTS riding it — that the watchdog dump blames.
-        step_requests = [req.id for _, req in sorted(self._active.items())]
-        self._flight.record(
-            "decode_step", engine_step=self._step_seq,
-            active=len(self._active), spec=bool(self.spec_k),
-            requests=step_requests,
-        )
-        self._profiler.on_step(self._step_seq)
-        # decode_wedge injection hook (resilience/faults.py): block like a
-        # wedged device program would — the serving watchdog's job is to
-        # fail the waiting clients while this thread is stuck here.
-        from ml_trainer_tpu.resilience.faults import active_plan
-
-        plan = active_plan()
-        if plan is not None:
-            fault = plan.fire("decode_wedge", step=self._step_seq)
-            if fault is not None:
-                plan.hold_wedge(fault)
-        spec_now = bool(self.spec_k and self.spec_enabled)
-        preempt_freed: List[int] = cancel_freed
-        if self.paged:
-            preempt_freed = preempt_freed + self._ensure_pages(
-                self.spec_k + 1 if spec_now else 1
-            )
-            self._sync_table()
+        with span("serve_prepare", engine_step=self._step_seq + 1):
+            cancel_freed = self._sweep_cancelled()
             if not self._active:
-                return preempt_freed
+                return cancel_freed
+            self._step_seq += 1
+            # Flight record BEFORE the dispatch: when this step wedges,
+            # the ring's newest decode_step record names the step — and
+            # the REQUESTS riding it — that the watchdog dump blames.
+            step_requests = [
+                req.id for _, req in sorted(self._active.items())
+            ]
+            self._flight.record(
+                "decode_step", engine_step=self._step_seq,
+                active=len(self._active), spec=bool(self.spec_k),
+                requests=step_requests,
+            )
+            self._profiler.on_step(self._step_seq)
+            # decode_wedge injection hook (resilience/faults.py): block
+            # like a wedged device program would — the serving watchdog's
+            # job is to fail the waiting clients while this thread is
+            # stuck here.
+            from ml_trainer_tpu.resilience.faults import active_plan
+
+            plan = active_plan()
+            if plan is not None:
+                fault = plan.fire("decode_wedge", step=self._step_seq)
+                if fault is not None:
+                    plan.hold_wedge(fault)
+            spec_now = bool(self.spec_k and self.spec_enabled)
+            preempt_freed: List[int] = cancel_freed
+            if self.paged:
+                preempt_freed = preempt_freed + self._ensure_pages(
+                    self.spec_k + 1 if spec_now else 1
+                )
+                self._sync_table()
+                if not self._active:
+                    return preempt_freed
         if spec_now:
             return preempt_freed + self._step_spec()
         active_before = len(self._active)
@@ -1571,39 +1583,47 @@ class SlotDecodeEngine:
         )
         with span("serve_decode", engine_step=self._step_seq,
                   active=active_before, requests=step_requests):
-            self.cache, self.tok = self._decode(
-                self.params, self.cache, self.tok,
-                self._temps, self._rngs, self._steps, *extra,
-            )
-            # The step's ONE fence: every later read this iteration is
-            # host data.  # graft-lint: sync-ok
-            toks = np.asarray(self.tok[:, 0])  # blocks: the step landed
-        dt = time.perf_counter() - t0
-        # Host mirror of the device's idx += 1 (every row advances).
-        self._pos = np.minimum(self._pos + 1, self.max_len).astype(np.int32)
-        freed: List[int] = []
-        emitted = 0
-        now = time.monotonic()
-        for slot in sorted(self._active):
-            req = self._active[slot]
-            if req.expired(now):
-                req.finish(
-                    "expired",
-                    f"deadline ({req.deadline}s) passed mid-decode "
-                    f"after {len(req.tokens)} token(s)",
+            with span("serve_decode.dispatch"):
+                self.cache, self.tok = self._decode(
+                    self.params, self.cache, self.tok,
+                    self._temps, self._rngs, self._steps, *extra,
                 )
-                self.metrics.record_expiry()
-                self._release_slot_pages(slot, req, donate=True)
-                del self._active[slot]
-                freed.append(slot)
-                continue
-            self._steps[slot] += 1
-            token = int(toks[slot])
-            req.push_token(token)
-            emitted += 1
-            if self._finished(req, token):
-                freed.append(slot)
-        self.metrics.record_step(dt, active_before, self.max_batch, emitted)
+            with span("serve_decode.fence"):
+                # The step's ONE fence: every later read this iteration
+                # is host data.  # graft-lint: sync-ok
+                toks = np.asarray(self.tok[:, 0])  # blocks: the step landed
+        dt = time.perf_counter() - t0
+        with span("serve_deliver", emitted=0, freed=0) as delivered:
+            # Host mirror of the device's idx += 1 (every row advances).
+            self._pos = np.minimum(
+                self._pos + 1, self.max_len
+            ).astype(np.int32)
+            freed: List[int] = []
+            emitted = 0
+            now = time.monotonic()
+            for slot in sorted(self._active):
+                req = self._active[slot]
+                if req.expired(now):
+                    req.finish(
+                        "expired",
+                        f"deadline ({req.deadline}s) passed mid-decode "
+                        f"after {len(req.tokens)} token(s)",
+                    )
+                    self.metrics.record_expiry()
+                    self._release_slot_pages(slot, req, donate=True)
+                    del self._active[slot]
+                    freed.append(slot)
+                    continue
+                self._steps[slot] += 1
+                token = int(toks[slot])
+                req.push_token(token)
+                emitted += 1
+                if self._finished(req, token):
+                    freed.append(slot)
+            self.metrics.record_step(
+                dt, active_before, self.max_batch, emitted
+            )
+            delivered.update(emitted=emitted, freed=len(freed))
         return preempt_freed + freed
 
     def _step_spec(self) -> List[int]:
@@ -1652,40 +1672,44 @@ class SlotDecodeEngine:
             # graft-lint: sync-ok (the verify step's one fence)
             toks = np.asarray(self.tok[:, 0])  # blocks: the step landed
         dt = time.perf_counter() - t0
-        freed: List[int] = []
-        emitted = 0
-        acc_active: List[int] = []
-        now = time.monotonic()
-        for slot in sorted(self._active):
-            req = self._active[slot]
-            if req.expired(now):
-                req.finish(
-                    "expired",
-                    f"deadline ({req.deadline}s) passed mid-decode "
-                    f"after {len(req.tokens)} token(s)",
-                )
-                self.metrics.record_expiry()
-                self._release_slot_pages(slot, req, donate=True)
-                del self._active[slot]
-                freed.append(slot)
-                continue
-            n_acc = int(acc[slot])
-            acc_active.append(n_acc)
-            req.spec_steps += 1
-            req.spec_accepted_tokens += n_acc
-            committed = [int(t) for t in drafts[slot][:n_acc]]
-            committed.append(int(toks[slot]))
-            for token in committed:
-                self._steps[slot] += 1
-                req.push_token(token)
-                emitted += 1
-                if self._finished(req, token):
+        with span("serve_deliver", emitted=0, freed=0) as delivered:
+            freed: List[int] = []
+            emitted = 0
+            acc_active: List[int] = []
+            now = time.monotonic()
+            for slot in sorted(self._active):
+                req = self._active[slot]
+                if req.expired(now):
+                    req.finish(
+                        "expired",
+                        f"deadline ({req.deadline}s) passed mid-decode "
+                        f"after {len(req.tokens)} token(s)",
+                    )
+                    self.metrics.record_expiry()
+                    self._release_slot_pages(slot, req, donate=True)
+                    del self._active[slot]
                     freed.append(slot)
-                    break
-        # Host mirrors the device's new_pos formula exactly.
-        self._pos = np.minimum(
-            self._pos + acc.astype(np.int32) + 1, self._caps
-        ).astype(np.int32)
-        self.metrics.record_step(dt, active_before, self.max_batch, emitted)
-        self.metrics.record_spec(acc_active, k)
+                    continue
+                n_acc = int(acc[slot])
+                acc_active.append(n_acc)
+                req.spec_steps += 1
+                req.spec_accepted_tokens += n_acc
+                committed = [int(t) for t in drafts[slot][:n_acc]]
+                committed.append(int(toks[slot]))
+                for token in committed:
+                    self._steps[slot] += 1
+                    req.push_token(token)
+                    emitted += 1
+                    if self._finished(req, token):
+                        freed.append(slot)
+                        break
+            # Host mirrors the device's new_pos formula exactly.
+            self._pos = np.minimum(
+                self._pos + acc.astype(np.int32) + 1, self._caps
+            ).astype(np.int32)
+            self.metrics.record_step(
+                dt, active_before, self.max_batch, emitted
+            )
+            self.metrics.record_spec(acc_active, k)
+            delivered.update(emitted=emitted, freed=len(freed))
         return freed

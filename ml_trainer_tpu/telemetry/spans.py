@@ -60,11 +60,12 @@ def _now_us() -> float:
 def span(name: str, category: str = "host", **args):
     """Time a host region: one Chrome complete event + an XLA trace
     annotation.  ``args`` (JSON-safe values) land in the event's
-    ``args`` payload — visible in the Perfetto detail pane."""
+    ``args`` payload — visible in the Perfetto detail pane; the block
+    gets the dict (``as out``) to set what only its end knows."""
     t0 = _now_us()
     with annotate(name):
         try:
-            yield
+            yield args
         finally:
             t1 = _now_us()
             ev = {
@@ -122,6 +123,29 @@ def trace_events() -> list:
     """Point-in-time copy of the buffered events (oldest first)."""
     with _events_lock:
         return list(_events)
+
+
+def events_between(mono_t0: float, mono_t1: float, names=None) -> tuple:
+    """``(events, wrapped)``: the complete events whose START lies in
+    ``[mono_t0, mono_t1]`` (``time.monotonic()`` seconds, put on the
+    trace clock as ``complete_event`` does), oldest start first, only
+    those named in ``names`` when given.  ``wrapped`` says the ring was
+    full and its oldest event ended after ``mono_t0``: events of the
+    window may have been dropped, the answer is partial, and a reader
+    that wants a statistic of the window must report nothing."""
+    lo = (mono_t0 - _MONO_EPOCH) * 1e6
+    hi = (mono_t1 - _MONO_EPOCH) * 1e6
+    with _events_lock:  # copy, then filter: span writers wait on this lock
+        events = list(_events)
+        full = len(events) == _events.maxlen
+    wrapped = full and events[0]["ts"] + events[0].get("dur", 0.0) > lo
+    found = [
+        ev for ev in events
+        if ev["ph"] == "X" and lo <= ev["ts"] <= hi
+        and (names is None or ev["name"] in names)
+    ]
+    found.sort(key=lambda ev: ev["ts"])
+    return found, wrapped
 
 
 def clock_payload() -> dict:

@@ -2109,6 +2109,7 @@ class Trainer:
             for _ in range(start_b):
                 next(it)
             from ml_trainer_tpu.resilience import faults
+            from ml_trainer_tpu.telemetry.spans import span
 
             plan = faults.active_plan()
             batches = prefetch_to_device(
@@ -2130,7 +2131,8 @@ class Trainer:
                         if plan.fire("nan_grad", step=gstep) is not None:
                             x = self._poison_batch(x)
                         self._poll_host_faults(plan, gstep)
-                    out = self._train_step(self.state, x, y, lr_scale)
+                    with span("train_step_dispatch", step=gstep):
+                        out = self._train_step(self.state, x, y, lr_scale)
                     self.state, loss, metric_val = out[0], out[1], out[2]
                     if self.telemetry:
                         stats = out[3]
@@ -2143,16 +2145,17 @@ class Trainer:
                         # pays one per batch, ref: src/trainer.py:186).
                         # Display matches the reference's running-average-
                         # over-full-epoch quirk (ref: src/trainer.py:193-194).
-                        if self.metric:
-                            tepoch.set_postfix(
-                                loss=float(loss_sum) / n,  # graft-lint: sync-ok
-                                metric=self._postfix_metric(
-                                    metric_sum, done, n
-                                ),
-                            )
-                        else:
-                            # graft-lint: sync-ok (the log_every fence)
-                            tepoch.set_postfix(loss=float(loss))
+                        with span("train_log_sync", step=gstep):
+                            if self.metric:
+                                tepoch.set_postfix(
+                                    loss=float(loss_sum) / n,  # graft-lint: sync-ok
+                                    metric=self._postfix_metric(
+                                        metric_sum, done, n
+                                    ),
+                                )
+                            else:
+                                # graft-lint: sync-ok (the log_every fence)
+                                tepoch.set_postfix(loss=float(loss))
                         if self._telemetry is not None and stats is not None:
                             self._telemetry.on_sync(
                                 gstep, stats, epoch=epoch,
@@ -2248,6 +2251,8 @@ class Trainer:
         ``steps_per_execution`` batches go through the scanned program, the
         ragged tail through the per-batch step — same trajectory either
         way."""
+        from ml_trainer_tpu.telemetry.spans import span
+
         k = self.steps_per_execution
         loss_sum = jnp.zeros(())
         metric_sum = jnp.zeros(())
@@ -2262,16 +2267,20 @@ class Trainer:
 
             def log(step_n, loss, stats):
                 if done % max(self.log_every, k) < step_n or done == n:
-                    if self.metric:
-                        tepoch.set_postfix(
-                            loss=float(loss_sum) / n,  # graft-lint: sync-ok
-                            metric=self._postfix_metric(metric_sum, done, n),
-                        )
-                    else:
-                        # Mean loss of the last dispatch — the multi-step
-                        # analog of the single-step path's last-batch loss.
-                        # graft-lint: sync-ok (per-dispatch fence)
-                        tepoch.set_postfix(loss=float(loss) / step_n)
+                    with span("train_log_sync", step=(epoch - 1) * n + done):
+                        if self.metric:
+                            tepoch.set_postfix(
+                                loss=float(loss_sum) / n,  # graft-lint: sync-ok
+                                metric=self._postfix_metric(
+                                    metric_sum, done, n
+                                ),
+                            )
+                        else:
+                            # Mean loss of the last dispatch — the
+                            # multi-step analog of the single-step path's
+                            # last-batch loss.
+                            # graft-lint: sync-ok (per-dispatch fence)
+                            tepoch.set_postfix(loss=float(loss) / step_n)
                     if self._telemetry is not None and stats is not None:
                         self._telemetry.on_sync(
                             (epoch - 1) * n + done, stats, epoch=epoch,
@@ -2297,7 +2306,9 @@ class Trainer:
             for x, y in prefetch_to_device(
                 iter(tail), size=2, sharding=self._batch_sharding
             ):
-                out = self._train_step(self.state, x, y, lr_scale)
+                with span("train_step_dispatch",
+                          step=(epoch - 1) * n + done + 1):
+                    out = self._train_step(self.state, x, y, lr_scale)
                 self.state, loss, metric_val = out[0], out[1], out[2]
                 stats = out[3] if self.telemetry else None
                 loss_sum = loss_sum + loss

@@ -109,6 +109,25 @@ def test_flash_backward_is_pallas_not_xla_recompute():
     assert "_flash_backward" in src
 
 
+def test_each_flash_kernel_carries_its_own_name():
+    """What the profiler shows of a Pallas call is its ``name``: forward,
+    dq and dkv must be three names, not one flax scope."""
+    import re
+
+    q, k, v = qkv(b=1, h=2, s=128)
+
+    def names(fn):
+        return re.findall(r"name=(flash_(?:fwd|bwd)\w*)",
+                          str(jax.make_jaxpr(fn)(q, k, v)))
+
+    def fwd(q, k, v):
+        return flash_attention(q, k, v, None, True, interpret=True)
+
+    assert names(fwd) == ["flash_fwd"]
+    grad = jax.grad(lambda *a: fwd(*a).sum(), argnums=(0, 1, 2))
+    assert names(grad) == ["flash_fwd", "flash_bwd_dq", "flash_bwd_dkv"]
+
+
 @pytest.mark.parametrize("causal", [False, True])
 def test_flash_gradients_match_reference_uneven_blocks(causal):
     """Backward kernels with block_q != block_k and multiple blocks on both

@@ -208,6 +208,33 @@ def test_int8_matmul_parity_and_quantize():
         int8_matmul(x, w.astype(jnp.float32), scale)
 
 
+def _kernel_calls():
+    rng = np.random.default_rng(9)
+    g = jnp.asarray(rng.normal(size=(64, 16)), jnp.float32)
+    x = jnp.asarray(rng.normal(size=(4, 32)), jnp.float32)
+    w_q, scale = quantize_per_channel(
+        jnp.asarray(rng.normal(size=(32, 48)), jnp.float32))
+    kw = dict(implementation="pallas", interpret=True)
+    return {
+        "paged_attention_decode": lambda: paged_attention(
+            *_paged_case(rng, 2, 2, 8, 8, 2, jnp.float32, [5, 16]), **kw),
+        "int8_matmul": lambda: int8_matmul(x, w_q, scale, **kw),
+        "fused_adam_norm": lambda: unscale_sqsum(g, 2.0, **kw),
+        "fused_adam_update": lambda: fused_adam_update(
+            g, g, g, jnp.abs(g), bc1=0.1, bc2=0.001, step_size=-1e-3,
+            lr_scale=1.0, **kw),
+    }
+
+
+@pytest.mark.parametrize("name", ["paged_attention_decode", "int8_matmul",
+                                  "fused_adam_norm", "fused_adam_update"])
+def test_each_kernel_carries_the_name_the_profiler_shows(name):
+    """docs/kernels.md: a Pallas call's ``name`` is the instruction's name
+    on the device trace, the handle a per-kernel metric finds it by."""
+    text = str(jax.make_jaxpr(_kernel_calls()[name])())
+    assert f"name={name}\n" in text or f"name={name} " in text, text[:400]
+
+
 def test_quantize_tree_structure():
     model = get_model("gpt2_tiny", max_len=32)
     variables = model.init(

@@ -359,3 +359,52 @@ def test_metrics_snapshot_hammer_under_concurrent_recording():
     final = m.snapshot()
     assert final["requests_completed"] > 0
     assert final["spec_acceptance_rate"] <= 1.0
+
+
+def test_decode_step_and_admission_are_cut_into_phase_spans(model_and_vars):
+    """Per decode step, on the engine's one thread and in this order:
+    ``serve_prepare``, ``serve_decode`` holding ``.dispatch`` then
+    ``.fence``, ``serve_deliver``, no sibling overlapping the next; per
+    admission ``serve_admit`` holding the prefill and its fence."""
+    from ml_trainer_tpu.telemetry.spans import clear_trace, trace_events
+
+    model, variables = model_and_vars
+    clear_trace()
+    with Server(model, variables, max_batch=2) as server:
+        first = server.submit(_prompt(3, 5), 6)
+        second = server.submit(_prompt(4, 9), 4)
+        first.result(timeout=120)
+        second.result(timeout=120)
+    events = sorted(
+        (e for e in trace_events()
+         if e["ph"] == "X" and e["name"].startswith("serve_")
+         and e["name"] != "serve_wait"),
+        key=lambda e: (e["ts"], -e["dur"]))
+    assert len({e["tid"] for e in events}) == 1
+
+    def end(e):
+        return e["ts"] + e["dur"]
+
+    def inside(parent):
+        return [e["name"] for e in events if e is not parent
+                and parent["ts"] <= e["ts"] and end(e) <= end(parent)]
+
+    tops = [e for e in events if "." not in e["name"]
+            and not e["name"].startswith("serve_prefill")]
+    for a, b in zip(tops, tops[1:]):
+        assert end(a) <= b["ts"]                   # siblings never overlap
+    steps = [e for e in tops if e["name"] != "serve_admit"]
+    assert len(steps) % 3 == 0 and len(steps) >= 3 * 5
+    for i in range(0, len(steps), 3):
+        prepare, decode, deliver = steps[i:i + 3]
+        assert [e["name"] for e in (prepare, decode, deliver)] == [
+            "serve_prepare", "serve_decode", "serve_deliver"]
+        assert prepare["args"]["engine_step"] == decode["args"]["engine_step"]
+        assert inside(decode) == ["serve_decode.dispatch",
+                                  "serve_decode.fence"]
+        assert 1 <= deliver["args"]["emitted"] <= 2
+    assert sum(e["args"]["freed"] for e in steps[2::3]) == 2
+    admits = [e for e in tops if e["name"] == "serve_admit"]
+    assert [a["args"]["prompt_len"] for a in admits] == [5, 9]
+    for a in admits:
+        assert inside(a) == ["serve_prefill", "serve_prefill.fence"]

@@ -153,6 +153,60 @@ def test_perfetto_trace_loads_and_nests(tmp_path):
     assert outer["args"] == {"step": 1}
 
 
+def test_events_between_takes_a_monotonic_window_and_tells_a_wrapped_ring(
+        monkeypatch):
+    import collections
+    import time
+
+    from ml_trainer_tpu.telemetry import spans
+
+    monkeypatch.setattr(spans, "_events", collections.deque(maxlen=4))
+    base = time.monotonic()
+    for i, name in enumerate(["a", "b", "a"]):
+        spans.complete_event(name, base + i, base + i + 0.25)
+    spans.instant("marker")                        # not a complete event
+    events, wrapped = spans.events_between(base + 0.5, base + 2.0)
+    assert [e["name"] for e in events] == ["b", "a"] and not wrapped
+    # On the trace clock, as complete_event puts them there.
+    assert events[0]["ts"] == pytest.approx(
+        (base + 1 - spans._MONO_EPOCH) * 1e6)
+    assert events[0]["dur"] == pytest.approx(0.25e6)
+    only_a, _ = spans.events_between(base, base + 3.0, names=("a",))
+    assert [e["ts"] for e in only_a] == sorted(e["ts"] for e in only_a)
+    assert [e["name"] for e in only_a] == ["a", "a"]
+    # The ring is full; its oldest event ended before a late window's
+    # start, so nothing of that window can have been dropped ...
+    assert spans.events_between(base + 1.0, base + 3.0)[1] is False
+    # ... and one more event drops "a"@0: a window from 0.5 may have lost
+    # events (the oldest kept ends at 1.25), one from 1.5 has not.
+    spans.complete_event("c", base + 3, base + 3.5)
+    assert spans.events_between(base + 0.5, base + 4.0)[1] is True
+    events, wrapped = spans.events_between(base + 1.5, base + 4.0)
+    assert [e["name"] for e in events] == ["a", "c"] and not wrapped
+    # A live span lands on the same clock.
+    t0 = time.monotonic()
+    with span("live"):
+        pass
+    live, _ = spans.events_between(t0, time.monotonic(), names=("live",))
+    assert len(live) == 1
+
+
+def test_fit_gives_one_dispatch_span_a_step_and_a_log_sync(tmp_path):
+    from ml_trainer_tpu.telemetry.spans import clear_trace, trace_events
+
+    clear_trace()
+    t = make_trainer(tmp_path / "m", size=32, log_every_steps=2)
+    t.fit()                                        # 32 rows of 16: two steps
+    events = [e for e in trace_events() if e["ph"] == "X"]
+    steps = [e for e in events if e["name"] == "train_step_dispatch"]
+    assert [e["args"]["step"] for e in steps] == [1, 2]
+    assert steps[0]["tid"] == steps[1]["tid"]
+    assert steps[0]["ts"] + steps[0]["dur"] <= steps[1]["ts"]
+    syncs = [e for e in events if e["name"] == "train_log_sync"]
+    assert [e["args"]["step"] for e in syncs] == [2]
+    assert syncs[0]["ts"] >= steps[1]["ts"] + steps[1]["dur"]
+
+
 # --------------------------------------------------------- flight recorder
 def test_flight_ring_bounded_and_dump(tmp_path):
     fr = FlightRecorder(capacity=4)
