@@ -213,6 +213,7 @@ def kernel_cases(cfg: SmokeConfig):
         int8_matmul,
         paged_attention,
         quantize_per_channel,
+        slot_cache_write,
         unscale_sqsum,
     )
     from ml_trainer_tpu.ops.kernels.fused_adam import _SQSUM_VMEM_ELEMS
@@ -315,6 +316,24 @@ def kernel_cases(cfg: SmokeConfig):
         yield (f"paged_attention [{dtype.__name__}] B={B} L={L} page={ps}",
                paged("pallas"), paged("reference"), args,
                BF16_FLOOR if dtype == jnp.bfloat16 else F32_MXU_FLOOR)
+
+    # -- slot-cache write: every decode step of the slot engine ------------
+    for dtype in (jnp.float32, jnp.bfloat16):
+        pos = rng.integers(0, L, size=B)
+        pos[:4] = (0, L - 1, L, L + 500)     # both ends, and the clamp
+        args = (
+            normal((B, H, L, D), dtype, 0.5), normal((B, H, L, D), dtype, 0.5),
+            normal((B, H, 1, D), dtype, 0.5), normal((B, H, 1, D), dtype, 0.5),
+            jnp.asarray(pos, jnp.int32),
+        )
+
+        def write(impl):
+            return lambda kc, vc, kn, vn, at: slot_cache_write(
+                kc, vc, kn, vn, at, implementation=impl, interpret=interp)
+
+        # It moves bytes and rounds nothing: no floor.
+        yield (f"slot_cache_write [{dtype.__name__}] {(B, H, L, D)}",
+               write("pallas"), write("reference"), args, 0.0)
 
     # -- int8 decode matmul (opt-in: Server(quant_int8=True)) --------------
     for tag, kk, nn in (("qkv", E, 3 * E), ("proj", E, E),

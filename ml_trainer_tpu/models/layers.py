@@ -23,6 +23,10 @@ import jax
 import jax.numpy as jnp
 
 from ml_trainer_tpu.ops.attention import attention
+from ml_trainer_tpu.ops.kernels.slot_cache_write import (
+    slot_cache_write,
+    slot_cache_write_reference,
+)
 
 # Dense targets a LoRA adapter may attach to (docs/serving.md "Batched
 # LoRA adapters"): the attention and MLP projections.  Embeddings and
@@ -238,26 +242,24 @@ class MultiHeadAttention(nn.Module):
             # is a PER-ROW [B] vector — each batch row (slot) sits at its
             # own sequence position, so rows write K/V at their own index
             # and attend their own valid prefix.  ``s == 1`` is the
-            # ordinary decode step; ``s > 1`` is the speculative VERIFY
-            # window (speculative.py): a length-``s`` token window lands
-            # at each row's own dynamic offset — one dynamic_update_slice
-            # per row, shapes static at fixed ``s``, so a fixed draft
-            # length K never recompiles — and query position j attends
-            # cached positions <= idx + j (the in-window causal rule).
+            # ordinary decode step: one in-place write a layer with every
+            # row in flight (ops/kernels/slot_cache_write.py; XLA runs the
+            # scatter it replaces as a sequential loop over the rows).
+            # ``s > 1`` is the speculative VERIFY window (speculative.py): a
+            # length-``s`` token window lands at each row's own dynamic
+            # offset — one dynamic_update_slice per row, shapes static at
+            # fixed ``s``, so a fixed draft length K never recompiles — and
+            # query position j attends cached positions <= idx + j (the
+            # in-window causal rule).  The window keeps the scatter (the
+            # kernel's reference): it may cross the edge of the kernel's
+            # block, which would take a second kernel, and no measured
+            # traffic runs it.
             # Prefill still runs per request at batch 1 with the ordinary
             # scalar index and is inserted into the slot cache afterwards.
-
-            def write_row(cache_row, kv_row, i):
-                # [H, L, D] <- [H, s, D] at position i of THIS row only.
-                return jax.lax.dynamic_update_slice(
-                    cache_row, kv_row, (0, i, 0)
-                )
-
-            cached_k.value = jax.vmap(write_row)(
-                cached_k.value, k.astype(self.dtype), idx
-            )
-            cached_v.value = jax.vmap(write_row)(
-                cached_v.value, v.astype(self.dtype), idx
+            write = slot_cache_write if s == 1 else slot_cache_write_reference
+            cached_k.value, cached_v.value = write(
+                cached_k.value, cached_v.value,
+                k.astype(self.dtype), v.astype(self.dtype), idx,
             )
             idx_var.value = idx + s
             valid = (

@@ -28,11 +28,18 @@ Catalog (see docs/kernels.md for block layouts and measured numbers):
 * ``int8_matmul`` / ``quantize_per_channel`` — int8 weight-quantized
   matmul with per-output-channel scales, backing the opt-in quantized
   decode path (``Server(quant_int8=True)``).
+* ``slot_cache_write`` — the slot engine's per-row append of this step's
+  K and V, every decode step and behind no knob: one in-place grid over
+  the rows where XLA runs the scatter as a sequential loop over them.
 """
 
 from ml_trainer_tpu.ops.kernels.paged_attention import (  # noqa: F401
     paged_attention,
     paged_attention_reference,
+)
+from ml_trainer_tpu.ops.kernels.slot_cache_write import (  # noqa: F401
+    slot_cache_write,
+    slot_cache_write_reference,
 )
 from ml_trainer_tpu.ops.kernels.fused_adam import (  # noqa: F401
     adam_scalars,
@@ -54,4 +61,6 @@ __all__ = [
     "int8_matmul",
     "quantize_per_channel",
     "quantize_tree",
+    "slot_cache_write",
+    "slot_cache_write_reference",
 ]

@@ -1,0 +1,242 @@
+"""Slot-cache write: this step's K and V into each row's own position, in
+place and with every row in flight.
+
+The slot engine's decode step (``models/layers.py::_decode_step``, the
+per-row ``cache_index`` branch) appends one position a row to a
+``[B, H, L, D]`` cache, each row at its own index.  Stated in XLA that is a
+scatter of ``B`` windows ``[H, 1, D]`` (``jax.vmap`` of a
+``dynamic_update_slice``), and the TPU runs a scatter as a sequential
+``while`` over its indices: 72 loops of 32 iterations a step of a 36-layer
+model, half of the decode step (PERF.md, PR 25).  This kernel is the same
+write as one grid over the rows whose DMAs the pipeline overlaps.
+
+Why it moves a block and not a position.  The chip addresses memory in
+tiles of 8 sublanes of 32 bits by 128 lanes, and a DMA moves whole tiles.
+Which two dimensions of the cache lie on the tile is XLA's choice, made
+from the shape alone so that every program agrees on a buffer: the pair
+that pads least.  With heads of 64 the head dimension would fill half of
+the 128 lanes, so XLA puts the POSITION on the lanes and the head dimension
+on the sublanes (``bf16[32,20,1024,64]{2,3,1,0:T(8,128)(2,1)}``); with heads
+of a multiple of 128 it keeps the order as written, head dimension on the
+lanes and position on the sublanes, where two bfloat16 positions share one
+32-bit sublane.  Either way a single position has no address of its own.
+Each grid step therefore reads the aligned block that holds the position,
+replaces the position under an ``iota`` compare and writes the block back
+through ``input_output_aliases``: the cache never leaves its buffer and
+nothing outside the block is touched.  The kernel follows XLA's rule
+(``_position_on_lanes``) and hands Mosaic the view whose order as written
+IS the order in memory, so that no copy of the cache is made on the way in
+or out (a wrong guess costs two such copies, never a wrong byte):
+
+* position on the lanes: the view ``[N, H, D, L]`` (a transpose that XLA
+  turns into a bitcast), block ``[1, H, D, 128]``: 320 KB at 20 heads of 64
+  in bfloat16.  The new row arrives as ``[B, D, H padded to 128]`` so that a
+  head's column can be spread along the lanes without a transpose in the
+  kernel;
+* position on the sublanes: the cache as written, block
+  ``[1, H, 32 // itemsize, D]`` (16 positions of bfloat16).
+
+Contract, pinned bit for bit by ``tests/test_kernels.py``:
+
+* ``slot_cache_write_reference`` IS the write the engine made before the
+  kernel (``jax.vmap`` of ``dynamic_update_slice``), so a position outside
+  ``[0, L - 1]`` is CLAMPED into it as ``dynamic_update_slice`` clamps
+  (a negative one counts from the end first, as JAX reads it).
+  The engine advances every row's index on every step, a free row's too,
+  so a free row's index runs past ``L - 1`` and lands on its own last
+  position.  The kernel clamps before the block index is computed: an
+  unclamped DMA would write over another row, or outside the array.
+* ``rows`` (default ``arange(B)``) names the cache row each new row goes
+  to, so a paged pool ``[N, H, page, D]`` can use the same call with
+  ``rows`` the page and ``pos`` the offset in it.  The rows of one call
+  must differ: two grid steps on one block race (the second reads the
+  block before the first has written it back).
+"""
+
+from __future__ import annotations
+
+import functools
+from typing import Optional
+
+import jax
+import jax.numpy as jnp
+
+LANES = 128
+
+
+def slot_cache_write_reference(k_cache, v_cache, k_new, v_new, pos,
+                               rows=None):
+    """The scatter, as the engine stated it before the kernel; a window
+    ``[B, H, s, D]`` of any length ``s`` (the speculative verify window
+    still takes this path)."""
+    def write_row(cache_row, new_row, i):
+        # [H, L, D] <- [H, s, D] at position i of THIS row only.
+        return jax.lax.dynamic_update_slice(cache_row, new_row, (0, i, 0))
+
+    def write(cache, new):
+        if rows is None:
+            return jax.vmap(write_row)(cache, new, pos)
+        return cache.at[rows].set(jax.vmap(write_row)(cache[rows], new, pos))
+
+    return write(k_cache, k_new), write(v_cache, v_new)
+
+
+def _position_on_lanes(L: int, d: int) -> bool:
+    """XLA's layout of a ``[.., L, d]`` array on the TPU: the dimension
+    that pads least to a multiple of 128 goes on the lanes, the order as
+    written winning a tie."""
+    def padding(n):
+        return -(-n // LANES) * LANES / n
+
+    return padding(L) < padding(d)
+
+
+def _wide(dtype):
+    # The select runs on 32-bit lanes on every generation; widening a
+    # bfloat16 and rounding it back returns the same bits.
+    return jnp.float32 if jnp.dtype(dtype).itemsize < 4 else dtype
+
+
+def _sublane_kernel(rows_ref, pos_ref, k_new, v_new, k_in, v_in, k_out,
+                    v_out, *, tile):
+    """Blocks ``[1, H, tile, D]``; the new rows ``[1, H, 1, D]``."""
+    from jax.experimental import pallas as pl
+
+    offset = pos_ref[pl.program_id(0)] % tile
+    shape = k_in.shape[1:]
+    hit = jax.lax.broadcasted_iota(jnp.int32, shape, 1) == offset
+    wide = _wide(k_in.dtype)
+    for new, old, out in ((k_new, k_in, k_out), (v_new, v_in, v_out)):
+        row = jnp.broadcast_to(new[0].astype(wide), shape)
+        out[0] = jnp.where(hit, row, old[0].astype(wide)).astype(out.dtype)
+
+
+def _lane_kernel(rows_ref, pos_ref, k_new, v_new, k_in, v_in, k_out, v_out,
+                 *, tile):
+    """Blocks ``[1, H, D, tile]``; the new rows ``[1, D, H padded]``, a
+    head's values down one column."""
+    from jax.experimental import pallas as pl
+
+    offset = pos_ref[pl.program_id(0)] % tile
+    _, heads, d, _ = k_in.shape
+    hit = jax.lax.broadcasted_iota(jnp.int32, (d, tile), 1) == offset
+    wide = _wide(k_in.dtype)
+    for new, old, out in ((k_new, k_in, k_out), (v_new, v_in, v_out)):
+        columns = new[0].astype(wide)                           # [D, Hp]
+        for h in range(heads):
+            row = jnp.broadcast_to(columns[:, h:h + 1], (d, tile))
+            out[0, h] = jnp.where(
+                hit, row, old[0, h].astype(wide)).astype(out.dtype)
+
+
+# Jitted so that a model's layers share ONE trace and ONE lowering of the
+# kernel: every layer calls it at the same shapes, and lowering a Pallas
+# call to Mosaic takes about 0.15 s, which 36 layers would pay on every
+# start of a server, compile cache or not.
+@functools.partial(jax.jit, static_argnames=("interpret",))
+def _slot_cache_write_pallas(k_cache, v_cache, k_new, v_new, rows, pos,
+                             interpret):
+    from jax.experimental import pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
+
+    n, h, L, d = k_cache.shape
+    b = k_new.shape[0]
+    # As jax.lax.dynamic_update_slice reads a start: a negative one counts
+    # from the end, and the result is clamped into the array.
+    pos = jnp.clip(jnp.where(pos < 0, pos + L, pos), 0, L - 1)
+    on_lanes = _position_on_lanes(L, d)
+    if on_lanes:
+        tile = min(LANES, L)
+        padded = -(-h // LANES) * LANES
+
+        def arrange(new):                      # [B, H, 1, D] -> [B, D, Hp]
+            new = new[:, :, 0, :].transpose(0, 2, 1)
+            return jnp.pad(new, ((0, 0), (0, 0), (0, padded - h)))
+
+        caches = [c.transpose(0, 1, 3, 2) for c in (k_cache, v_cache)]
+        news = [arrange(k_new), arrange(v_new)]
+        new_spec = pl.BlockSpec((1, d, padded), lambda bi, r, p: (bi, 0, 0))
+        cache_spec = pl.BlockSpec(
+            (1, h, d, tile), lambda bi, r, p: (r[bi], 0, 0, p[bi] // tile))
+        kernel = _lane_kernel
+    else:
+        tile = min(max(8, 32 // k_cache.dtype.itemsize), L)
+        caches, news = [k_cache, v_cache], [k_new, v_new]
+        new_spec = pl.BlockSpec((1, h, 1, d), lambda bi, r, p: (bi, 0, 0, 0))
+        cache_spec = pl.BlockSpec(
+            (1, h, tile, d), lambda bi, r, p: (r[bi], 0, p[bi] // tile, 0))
+        kernel = _sublane_kernel
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=2,
+        grid=(b,),
+        in_specs=[new_spec, new_spec, cache_spec, cache_spec],
+        out_specs=[cache_spec, cache_spec],
+    )
+    shape = jax.ShapeDtypeStruct(caches[0].shape, caches[0].dtype)
+    out = pl.pallas_call(
+        functools.partial(kernel, tile=tile),
+        grid_spec=grid_spec,
+        out_shape=[shape, shape],
+        # Operands count from the scalars: 4 and 5 are the two caches.
+        input_output_aliases={4: 0, 5: 1},
+        interpret=interpret,
+        name="slot_cache_write",
+    )(rows, pos, *news, *caches)
+    if on_lanes:
+        out = [c.transpose(0, 1, 3, 2) for c in out]
+    return tuple(out)
+
+
+def slot_cache_write(
+    k_cache: jax.Array,
+    v_cache: jax.Array,
+    k_new: jax.Array,
+    v_new: jax.Array,
+    pos: jax.Array,
+    rows: Optional[jax.Array] = None,
+    *,
+    implementation: str = "auto",
+    interpret: bool = False,
+):
+    """Write ``k_new[b]``, ``v_new[b]`` (``[B, H, 1, D]``) at position
+    ``pos[b]`` of row ``rows[b]`` of the caches (``[N, H, L, D]``) and
+    return the two caches.  See the module docstring.
+
+    implementation: 'auto' (pallas on TPU, reference elsewhere),
+    'pallas', or 'reference'.  ``interpret=True`` runs the Pallas kernel
+    in interpret mode (the CPU parity harness).
+    """
+    if k_cache.shape != v_cache.shape or k_cache.dtype != v_cache.dtype:
+        raise ValueError(
+            f"k_cache/v_cache differ: {k_cache.shape} {k_cache.dtype} vs "
+            f"{v_cache.shape} {v_cache.dtype}"
+        )
+    n, h, L, d = k_cache.shape
+    b = pos.shape[0]
+    if k_new.shape != (b, h, 1, d) or v_new.shape != (b, h, 1, d):
+        raise ValueError(
+            f"k_new/v_new must be {(b, h, 1, d)} (one position a row), got "
+            f"{k_new.shape} and {v_new.shape}"
+        )
+    if rows is None and b != n:
+        raise ValueError(f"{b} positions for {n} cache rows and no `rows`")
+    k_new, v_new = k_new.astype(k_cache.dtype), v_new.astype(v_cache.dtype)
+    if implementation == "auto":
+        implementation = (
+            "pallas" if jax.default_backend() == "tpu" else "reference"
+        )
+    if implementation in ("reference", "xla"):
+        return slot_cache_write_reference(
+            k_cache, v_cache, k_new, v_new, pos, rows
+        )
+    if implementation != "pallas":
+        raise ValueError(
+            f"Unknown slot_cache_write implementation {implementation!r}; "
+            "expected 'auto', 'pallas', or 'reference'"
+        )
+    if rows is None:
+        rows = jnp.arange(b, dtype=jnp.int32)
+    return _slot_cache_write_pallas(
+        k_cache, v_cache, k_new, v_new, jnp.asarray(rows, jnp.int32),
+        jnp.asarray(pos, jnp.int32), interpret,
+    )

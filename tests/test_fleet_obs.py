@@ -218,6 +218,10 @@ def socket_fleet():
         train=False,
     )
     compile_watch.install()  # workers install it; here: shared process
+    # The watch's state belongs to the process: a Trainer with telemetry in
+    # a file this worker ran earlier leaves it "warm", and this fleet's own
+    # first compiles would then count as recompiles after warm-up.
+    compile_watch.reset()
     servers, remotes = {}, {}
     router = None
     try:

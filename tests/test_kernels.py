@@ -28,6 +28,11 @@ from ml_trainer_tpu.ops.kernels import (
     quantize_tree,
     unscale_sqsum,
 )
+from ml_trainer_tpu.ops.kernels.slot_cache_write import (
+    _position_on_lanes,
+    slot_cache_write,
+    slot_cache_write_reference,
+)
 
 
 def _jrun(fn, *args, **kw):
@@ -208,6 +213,86 @@ def test_int8_matmul_parity_and_quantize():
         int8_matmul(x, w.astype(jnp.float32), scale)
 
 
+# ---------------------------------------------------- slot_cache_write
+# [B, H, L, D] per layout the kernel can meet: XLA puts the position on the
+# lanes where the head dimension pads more than the length (GPT-2's heads of
+# 64; here 16 under 256 positions), and leaves it on the sublanes otherwise.
+_WRITE_SHAPES = {"lanes": (4, 3, 256, 16), "sublanes": (4, 2, 64, 128)}
+_WRITES = {}
+
+
+def _slot_write(layout, dtype):
+    """One cache, one pair of new rows and the two jitted writes a layout
+    and dtype: the positions are arguments, so every case shares them."""
+    key = (layout, jnp.dtype(dtype).name)
+    if key not in _WRITES:
+        b, h, L, d = _WRITE_SHAPES[layout]
+        assert _position_on_lanes(L, d) == (layout == "lanes")
+        rng = np.random.default_rng(len(_WRITES))
+        arrays = tuple(
+            jnp.asarray(rng.normal(size=shape), dtype)
+            for shape in [(b + 2, h, L, d)] * 2 + [(b, h, 1, d)] * 2)
+        _WRITES[key] = arrays, jax.jit(slot_cache_write_reference), jax.jit(
+            lambda *a: slot_cache_write(
+                *a, implementation="pallas", interpret=True))
+    return _WRITES[key]
+
+
+def _bits(x):
+    x = np.asarray(x)
+    return x.view({2: np.uint16, 4: np.uint32}[x.dtype.itemsize])
+
+
+@pytest.mark.parametrize("layout", sorted(_WRITE_SHAPES))
+@pytest.mark.parametrize("dtype,pos", [
+    *[(jnp.bfloat16, p) for p in (
+        0, 1, 15, 16, 17, 127, 128, "L-1", "L", "L+500", -3, -500,
+        (0, 17, "L-1", "L+500"), (16, 16, 15, 1))],
+    (jnp.float32, (7, 8, "L", 0)),
+], ids=str)
+def test_slot_cache_write_is_the_scatter_bit_for_bit(layout, dtype, pos):
+    """The kernel (interpret mode) against ``jax.vmap`` of
+    ``dynamic_update_slice``, K and V in one call: a position either side of
+    every tile edge, odd and even (two bfloat16 share a sublane), the clamp
+    at both ends, rows at different positions in one call; every other
+    position and every other row keep their bits.  ``rows`` sends the writes
+    to chosen rows of a pool with more rows than the call has."""
+    (k_cache, v_cache, k_new, v_new), reference, kernel = _slot_write(
+        layout, dtype)
+    n, h, L, d = k_cache.shape
+    b = k_new.shape[0]
+    at = {"L-1": L - 1, "L": L, "L+500": L + 500}
+    pos = pos if isinstance(pos, tuple) else (pos,) * b
+    pos = [at.get(p, p) for p in pos]
+    rows = jnp.asarray([5, 0, 3, 2], jnp.int32)
+    args = (k_cache, v_cache, k_new, v_new, jnp.asarray(pos, jnp.int32), rows)
+    want, got = reference(*args), kernel(*args)
+    for cache, new, w, g in zip((k_cache, v_cache), (k_new, v_new), want, got):
+        assert np.array_equal(_bits(g), _bits(w))
+        expect = _bits(cache).copy()
+        for row, p, r in zip(np.asarray(rows), pos, _bits(new)):
+            p = p + L if p < 0 else p          # as JAX reads a start
+            expect[row, :, min(max(p, 0), L - 1), :] = r[:, 0, :]
+        assert np.array_equal(_bits(g), expect)
+    # without ``rows`` the call writes row b of a cache of b rows
+    plain = tuple(a[:b] for a in args[:2]) + args[2:5]
+    assert _bits_equal(kernel(*plain), reference(*plain))
+
+
+def test_slot_cache_write_refusals():
+    (k_cache, v_cache, k_new, v_new), _, _ = _slot_write("lanes", jnp.float32)
+    pos = jnp.zeros((k_new.shape[0],), jnp.int32)
+    with pytest.raises(ValueError, match="no `rows`"):
+        slot_cache_write(k_cache, v_cache, k_new, v_new, pos)
+    with pytest.raises(ValueError, match="one position a row"):
+        slot_cache_write(k_cache, v_cache, k_new[:, :, 0], v_new, pos, pos)
+    with pytest.raises(ValueError, match="differ"):
+        slot_cache_write(k_cache, v_cache[:, :1], k_new, v_new, pos, pos)
+    with pytest.raises(ValueError, match="Unknown"):
+        slot_cache_write(k_cache, v_cache, k_new, v_new, pos, pos,
+                         implementation="scatter")
+
+
 def _kernel_calls():
     rng = np.random.default_rng(9)
     g = jnp.asarray(rng.normal(size=(64, 16)), jnp.float32)
@@ -219,6 +304,9 @@ def _kernel_calls():
         "paged_attention_decode": lambda: paged_attention(
             *_paged_case(rng, 2, 2, 8, 8, 2, jnp.float32, [5, 16]), **kw),
         "int8_matmul": lambda: int8_matmul(x, w_q, scale, **kw),
+        "slot_cache_write": lambda: slot_cache_write(
+            *_slot_write("lanes", jnp.float32)[0],
+            jnp.zeros((4,), jnp.int32), jnp.arange(4), **kw),
         "fused_adam_norm": lambda: unscale_sqsum(g, 2.0, **kw),
         "fused_adam_update": lambda: fused_adam_update(
             g, g, g, jnp.abs(g), bc1=0.1, bc2=0.001, step_size=-1e-3,
@@ -227,7 +315,8 @@ def _kernel_calls():
 
 
 @pytest.mark.parametrize("name", ["paged_attention_decode", "int8_matmul",
-                                  "fused_adam_norm", "fused_adam_update"])
+                                  "fused_adam_norm", "fused_adam_update",
+                                  "slot_cache_write"])
 def test_each_kernel_carries_the_name_the_profiler_shows(name):
     """docs/kernels.md: a Pallas call's ``name`` is the instruction's name
     on the device trace, the handle a per-kernel metric finds it by."""

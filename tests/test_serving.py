@@ -74,6 +74,57 @@ def test_join_mid_decode_matches_generate_token_for_token(model_and_vars):
     assert snap["max_active_slots"] >= 2
 
 
+@pytest.mark.parametrize("write,max_len", [
+    ("auto", 32), ("pallas", 32), ("pallas", 64)], ids=str)
+def test_freed_slot_whose_index_ran_past_the_cache_is_served_again(
+        write, max_len, monkeypatch):
+    """The decode program advances EVERY row's ``cache_index``, a free
+    row's too: while one long request decodes alone, the freed slot's index
+    runs past the cache's last position and its write must land, clamped,
+    in its own row.  The long request, and two admitted afterwards into
+    both slots, serve what ``generate()`` gives.  ``pallas`` steers the
+    write's call site to the kernel in interpret mode (at 32 positions the
+    position lies on the sublanes of its tile, at 64 on the lanes); ``auto``
+    is the call site as every other test on the CPU runs it."""
+    import functools
+
+    from ml_trainer_tpu.models import layers
+    from ml_trainer_tpu.ops.kernels.slot_cache_write import (
+        _position_on_lanes, slot_cache_write)
+
+    if write == "pallas":
+        monkeypatch.setattr(layers, "slot_cache_write", functools.partial(
+            slot_cache_write, implementation="pallas", interpret=True))
+    # A width of its own, so that no other test's compiled decode program
+    # is reused here, nor this one's there.
+    model = get_model("gpt2_tiny", max_len=max_len,
+                      embed_dim=64 if write == "pallas" else 96,
+                      num_heads=2)
+    assert _position_on_lanes(max_len, 32) == (max_len == 64)
+    variables = model.init(
+        {"params": jax.random.PRNGKey(1)}, np.zeros((1, 8), np.int32),
+        train=False,
+    )
+    long_new = max_len - 4
+    pA, pB, pC, pD = (_prompt(s, n) for s, n in
+                      ((10, 5), (11, 3), (12, 6), (13, 4)))
+    refs = [np.asarray(generate(model, variables, p[None], n))[0]
+            for p, n in ((pA, 3), (pB, long_new), (pC, 7), (pD, 9))]
+    with Server(model, variables, max_batch=2) as server:
+        sA = server.submit(pA, 3)
+        next(iter(sA))                      # A decodes before B joins
+        sB = server.submit(pB, long_new)
+        outs = [sA.result(timeout=120), sB.result(timeout=120)]
+        leaves = jax.tree_util.tree_leaves_with_path(server.engine.cache)
+        index = max(int(np.asarray(v).max()) for path, v in leaves
+                    if "cache_index" in jax.tree_util.keystr(path))
+        assert index >= max_len, index      # past the last position, L - 1
+        sC, sD = server.submit(pC, 7), server.submit(pD, 9)
+        outs += [sC.result(timeout=120), sD.result(timeout=120)]
+    for out, ref in zip(outs, refs):
+        np.testing.assert_array_equal(out, ref)
+
+
 def test_streaming_iterator_yields_generates_tokens(model_and_vars):
     model, variables = model_and_vars
     p = _prompt(3, 4)
