@@ -31,7 +31,7 @@ ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 if ROOT not in sys.path:
     sys.path.insert(0, ROOT)
 
-from benchmark import harness, loadgen, reference  # noqa: E402
+from benchmark import harness, loadgen  # noqa: E402
 
 MANIFEST = os.path.join(ROOT, "BENCHMARK.json")
 
@@ -135,9 +135,9 @@ def train_seeds(name, n_control, seeds, manifest=MANIFEST, allow_cpu=False):
                    "ref_losses": ref["losses"]}
             if i < n_control:
                 out["control_fp8"] = read(
-                    reference.train_steps(lower="fp8", **steps))
+                    cell.reference.train_steps(lower="fp8", **steps))
                 out["fault_half_batch"] = read(
-                    reference.train_steps(half_batch=True, **steps))
+                    cell.reference.train_steps(half_batch=True, **steps))
             out["took_s"] = time.monotonic() - t
             lines.append(say(**out))
             del d, ref, steps

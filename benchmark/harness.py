@@ -4,13 +4,19 @@ check, the compile cache, the compile counter, the result line.
 Driven by data.  ``BENCHMARK.json`` names cells, configurations and metrics;
 each configuration is ``configs/<name>.json``, each traffic mix or job is
 ``traffic/<name>.json``, each per-layer metric is
-``layer_metrics/<name>.json`` naming a reader ``readers/<reader>.py``.  A new
-cell, configuration, mix or metric is new files and a new entry.
+``layer_metrics/<name>.json`` naming a reader ``readers/<reader>.py``.  A
+configuration's file names its architecture's plain reference
+(``"reference"``: ``references/<name>.py``) and work counts (``"work"``:
+``work/<name>.py``); nothing here, in the drivers, in ``calibrate.py`` or in
+the readers knows an architecture but through those two names.  A new cell,
+configuration, mix, metric or architecture is new files and a new entry.
 """
 
 from __future__ import annotations
 
+import functools
 import importlib
+import importlib.util
 import json
 import os
 import sys
@@ -65,6 +71,39 @@ class Cell:
         self.bench_dir = os.path.dirname(os.path.dirname(config_file))
         self.traffic = load_json(os.path.join(
             self.bench_dir, "traffic", self.entry["traffic"] + ".json"))
+        # No default architecture: the file names both, and both are there.
+        self._module_files = {}
+        for key, kind in (("reference", "references"), ("work", "work")):
+            if not isinstance(self.config.get(key), str):
+                raise BenchError(
+                    f"{config_file} names no {key!r}: every configuration "
+                    f"says which {kind}/<name>.py is its architecture's", 2)
+            self._module_files[key] = self._find(
+                kind, self.config[key] + ".py")
+
+    def _find(self, kind: str, filename: str) -> str:
+        """A file of the cell's own by its name: beside the configurations'
+        directory first, then under ``benchmark/``."""
+        for base in (self.bench_dir, HERE):
+            path = os.path.join(base, kind, filename)
+            if os.path.exists(path):
+                return path
+        raise BenchError(f"no {kind}/{filename} for {self.name}", 2)
+
+    @functools.cached_property
+    def reference(self):
+        """The architecture's plain reference (PERF.md section 3 states the
+        contract it keeps), imported when first asked for."""
+        return load_module(self._module_files["reference"])
+
+    @functools.cached_property
+    def work(self):
+        """The architecture's work counts (same section, same contract)."""
+        return load_module(self._module_files["work"])
+
+    def sizes(self) -> dict:
+        """What the configuration's own reference reads from its keys."""
+        return self.reference.sizes_of(self.config)
 
     def metrics(self, section: str) -> list:
         """The metrics of ``end_to_end`` or ``per_layer`` that this cell
@@ -73,11 +112,29 @@ class Cell:
                 if "workloads" not in m or self.name in m["workloads"]]
 
     def layer_metric(self, name: str) -> dict:
-        for base in (self.bench_dir, HERE):
-            path = os.path.join(base, "layer_metrics", name + ".json")
-            if os.path.exists(path):
-                return load_json(path)
-        raise BenchError(f"no layer_metrics/{name}.json for {self.name}", 2)
+        return load_json(self._find("layer_metrics", name + ".json"))
+
+
+def load_module(path: str):
+    """A module by its file.  One under ``benchmark/`` is imported by its
+    dotted name; one elsewhere (a test's architecture) under a name made of
+    its path, once a process."""
+    path = os.path.abspath(path)
+    if path.startswith(HERE + os.sep):
+        return importlib.import_module("benchmark." + os.path.relpath(
+            path, HERE)[:-3].replace(os.sep, "."))
+    name = "benchmark_found_" + "".join(
+        c if c.isalnum() else "_" for c in path[:-3])
+    if name not in sys.modules:
+        spec = importlib.util.spec_from_file_location(name, path)
+        module = importlib.util.module_from_spec(spec)
+        sys.modules[name] = module
+        try:
+            spec.loader.exec_module(module)
+        except BaseException:
+            del sys.modules[name]
+            raise
+    return sys.modules[name]
 
 
 def read_layer_metrics(cell: Cell, ctx: dict) -> dict:
@@ -102,17 +159,19 @@ def build_model(config: dict):
     from ml_trainer_tpu.models import get_model
 
     opts = dict(config["program"].get("model_options", {}))
-    if "dtype" in opts:
-        opts["dtype"] = getattr(jnp, opts["dtype"])
+    for key, value in opts.items():
+        if key == "dtype" or key.endswith("_dtype"):
+            opts[key] = getattr(jnp, value)
     return get_model(config["program"]["model"], **opts)
 
 
-def make_weights(config: dict, seed: int):
-    """The benchmark's own weights from the seed (reference.py)."""
+def make_weights(cell: Cell, seed: int):
+    """The benchmark's own weights from the seed, by the configuration's own
+    reference, in the precision it states."""
     from benchmark import reference
 
-    return reference.make_weights(
-        reference.seed_key(seed), **reference.sizes_of(config))
+    return cell.reference.make_weights(
+        reference.seed_key(seed), **cell.sizes())
 
 
 def start_trace(log_dir: str) -> None:
@@ -227,7 +286,8 @@ def finish(cell: Cell, trace: bool, facts: dict, numbers: dict,
     device_extra = {"memory_peak_bytes": peak}
     layer_values, breakdown = {}, None
     if trace:
-        ctx.update(cell=cell.name, chips=cell.chips)
+        ctx.update(cell=cell.name, chips=cell.chips,
+                   reference=cell.reference, work=cell.work)
         if ctx.get("trace") and ctx["trace"]["devices"]:
             red = ctx["trace_reduced"] = trace_reduce.reduce(ctx["trace"])
             device_extra.update(busy_s=red["busy_s"],

@@ -22,7 +22,7 @@ import time
 
 import numpy as np
 
-from benchmark import harness, loadgen, reference, trace_reduce
+from benchmark import harness, loadgen, trace_reduce
 from benchmark.harness import BenchError
 
 def _stamped_metrics():
@@ -110,8 +110,8 @@ class Serving:
         from ml_trainer_tpu.serving.api import Server
 
         self.cell, self.seed = cell, seed
-        self.sizes = reference.sizes_of(cell.config)
-        self.weights = harness.make_weights(cell.config, seed)
+        self.sizes = cell.sizes()
+        self.weights = harness.make_weights(cell, seed)
         jax.block_until_ready(self.weights)
         self.metrics = _stamped_metrics()
         self.options = dict(cell.config["program"]["server_options"])
@@ -207,9 +207,8 @@ def check_outputs(cell, weights, sizes: dict, records: list, seed: int,
         if req["id"] >= max(wanted):
             break
     gaps = np.concatenate([
-        reference.served_token_gaps(
-            weights, sizes["heads"], prompts[r["id"]], r["tokens"],
-            pad_to=sizes["positions"], lower=lower)
+        cell.reference.served_token_gaps(
+            weights, sizes, prompts[r["id"]], r["tokens"], lower=lower)
         for r in sample])
     compared["served_token_gap_mean"]["value"] = float(gaps.mean())
     return {"compared": compared, "tokens_checked": int(gaps.size),

@@ -174,7 +174,7 @@ def compare(limits: dict, got: dict, ref: dict) -> dict:
     the parameters' change after the checked steps, the last three by the
     worst leaf.  Only the numbers the configuration's ``limits`` name are
     compared (PERF.md says which have two readings to set a limit from).
-    ``got`` and ``ref`` are as ``reference.train_steps`` returns them.
+    ``got`` and ``ref`` are as a reference's ``train_steps`` returns them.
     Elements whose reference gradient is nought to rounding are left out of
     the change (``reference.moved_threshold``)."""
     import jax
@@ -207,11 +207,11 @@ def build_trainer(cell, seed: int, seconds: float, weights, seen: list,
     """The job as the configuration and the traffic file state it."""
     from ml_trainer_tpu import Trainer
 
-    job, sizes = cell.traffic, reference.sizes_of(cell.config)
+    job, sizes = cell.traffic, cell.sizes()
     batch, seq = int(job["batch_size"]), int(job["seq_len"])
     # One epoch that cannot end before the window: as many steps as the
     # chip's peak could complete, and the steps before the window.
-    per_step = flops.train_flops_per_token(sizes, seq) * batch * seq
+    per_step = cell.work.train_flops_per_token(sizes, seq) * batch * seq
     peak = (flops.peaks_for(facts["kind"])["bf16_flops_per_s"]
             if facts["platform"] == "tpu" else per_step * 50.0)  # rehearsal
     steps = (int(job["warmup_steps"]) + int(job.get("traced_steps", 3)) + 8
@@ -236,10 +236,9 @@ def drive(cell, seed: int, seconds: float, trace: bool, facts: dict,
           t_process: float) -> dict:
     """Build the one Trainer, drive it from the seed through its first steps
     and the window, free its state.  Returns what the steps gave (as
-    ``reference.train_steps`` shapes it), the rows they were fed, the
+    the reference's ``train_steps`` shapes it), the rows they were fed, the
     reference's numbers for those rows, and the probe."""
-    weights = harness.make_weights(cell.config, seed)
-    sizes = reference.sizes_of(cell.config)
+    weights = harness.make_weights(cell, seed)
     seen = []
     model_dir = tempfile.mkdtemp(prefix="bench_train_")
     try:
@@ -271,11 +270,11 @@ def drive(cell, seed: int, seconds: float, trace: bool, facts: dict,
     options = cell.config["program"].get("trainer_options", {})
     steps = dict(
         params=weights, batches=[(data[i], targets[i]) for i in fed],
-        heads=sizes["heads"], lr=float(options["lr"]),
+        sizes=cell.sizes(), lr=float(options["lr"]),
         weight_decay=float(options.get("weight_decay", 0.0)),
         rows_per_block=int(cell.config["check"]["rows_per_block"]))
     return {"got": got, "probe": probe, "peak": peak, "steps": steps,
-            "ref": reference.train_steps(**steps)}
+            "ref": cell.reference.train_steps(**steps)}
 
 
 def run(cell, seed: int, seconds: float, trace: bool, t_process: float,
@@ -296,7 +295,7 @@ def run(cell, seed: int, seconds: float, trace: bool, t_process: float,
     print(f"window: {probe.window_steps} steps in {window_s:.3f}s",
           file=sys.stderr)
     numbers = {"train_tokens_per_s": rate, "setup_s": probe.t0 - t_process}
-    ctx = {"sizes": reference.sizes_of(cell.config),
+    ctx = {"sizes": cell.sizes(),
            "window": (probe.t0, probe.t1), "trace": probe.load_trace(),
            "bytes_per_value": 2,
            "train": {"tokens_per_s": rate, "seq_len": seq, "batch": batch,

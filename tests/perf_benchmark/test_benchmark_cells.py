@@ -3,15 +3,16 @@ generator, the trace reduction, the plain reference against the program, a
 rehearsal of every driver at tiny sizes, and the runs that must come out as
 NOT correct (the control, and the timed path broken underneath).
 
-The tiny cells are test presets (``tiny/``: two configurations and three
-mixes); their manifest is the root's own with those names swapped in.  No
-number a CPU run prints here is a device metric, and the harness prints none
-from a CPU.
+The tiny cells are test presets (``tiny/``), one file a rehearsal under
+``tiny/cells/``; ``conftest.py`` reads them, builds their manifest (the
+root's own with those names swapped in) and hands out ``tiny``,
+``run_tiny``, ``rehearse`` and the rehearsal's cases, so that a new cell or a
+new architecture brings a file and no edit here.  No number a CPU run prints
+here is a device metric, and the harness prints none from a CPU.
 """
 
 import asyncio
 import gzip
-import io
 import json
 import os
 import re
@@ -22,17 +23,12 @@ from http.server import BaseHTTPRequestHandler, ThreadingHTTPServer
 import numpy as np
 import pytest
 
-from benchmark import harness, loadgen, reference, trace_reduce
+from benchmark import harness, loadgen, trace_reduce
 
 HERE = os.path.dirname(os.path.abspath(__file__))
 ROOT = os.path.dirname(os.path.dirname(HERE))
 BENCH = os.path.join(ROOT, "benchmark")
 MANIFEST = os.path.join(ROOT, "BENCHMARK.json")
-TINY_CELLS = {  # root cell -> (tiny cell, tiny configuration, tiny mix)
-    "gpt2-large.batch-decode": ("tiny.closed", "gpt2-tiny-serve",
-                                "closed-tiny"),
-    "gpt2-124m.pretrain-1k": ("tiny.train", "gpt2-tiny-train", "train-tiny"),
-}
 NAME = re.compile(r"^[A-Za-z0-9_][A-Za-z0-9_.\-]{0,63}$")
 UNIT = re.compile(r"^[A-Za-z0-9_/%.\-]{1,16}$")
 
@@ -40,30 +36,6 @@ UNIT = re.compile(r"^[A-Za-z0-9_/%.\-]{1,16}$")
 def _load(path):
     with open(path) as fp:
         return json.load(fp)
-
-
-@pytest.fixture(scope="module")
-def tiny(tmp_path_factory):
-    """The root manifest with the tiny presets in the cells' places, and an
-    open-loop cell that reports what the closed one reports."""
-    b = _load(MANIFEST)
-    assert {w["name"] for w in b["workloads"]} == set(TINY_CELLS)
-    cells = list(TINY_CELLS.values()) + [
-        ("tiny.open", "gpt2-tiny-serve", "open-tiny")]
-    b["configs"] = [
-        {"name": c, "source": "test preset", "reduced": [], "why": "CPU",
-         "file": os.path.join(HERE, "tiny", "configs", c + ".json")}
-        for c in sorted({c for _, c, _ in cells})]
-    b["workloads"] = [{"name": n, "config": c, "traffic": t, "chips": 1,
-                       "why": "CPU rehearsal"} for n, c, t in cells]
-    for m in b["end_to_end"] + b["per_layer"]:
-        if "workloads" in m:
-            m["workloads"] = [TINY_CELLS[w][0] for w in m["workloads"]]
-            if "tiny.closed" in m["workloads"]:
-                m["workloads"].append("tiny.open")
-    path = tmp_path_factory.mktemp("tiny") / "BENCHMARK.json"
-    path.write_text(json.dumps(b))
-    return str(path)
 
 
 # ------------------------------------------------------------ the manifest
@@ -88,12 +60,13 @@ def test_manifest_keeps_to_the_contract_and_every_file_loads():
         assert NAME.match(c["name"]) and len(c["why"]) <= 200
         cfg = _load(os.path.join(ROOT, c["file"]))
         assert cfg["source"] == c["source"] and cfg["reduced"] == c["reduced"]
-        reference.sizes_of(cfg)
         assert cfg["program"]["entry"] in ("serve", "train")
     for w in b["workloads"]:
         assert NAME.match(w["name"]) and NAME.match(w["traffic"])
         assert w["chips"] in (1, 4) and 1 <= len(w["why"]) <= 200
         cell = harness.Cell(MANIFEST, w["name"])
+        # each configuration's OWN reference reads its keys
+        assert {"vocab", "positions"} <= set(cell.sizes())
         reported = {m["name"] for m in cell.metrics("end_to_end")}
         assert "setup_s" in reported and len(reported) >= 2
         layer = cell.metrics("per_layer")
@@ -345,9 +318,9 @@ def test_reference_agrees_with_the_program_through_prefill_and_cache(tiny):
 
     from ml_trainer_tpu.models import get_model
 
-    cfg = harness.Cell(tiny, "tiny.closed").config
-    sizes = reference.sizes_of(cfg)
-    weights = reference.make_weights(reference.seed_key(2**31 + 5), **sizes)
+    cell = harness.Cell(tiny, "tiny.closed")
+    reference, sizes = cell.reference, cell.sizes()
+    weights = harness.make_weights(cell, 2**31 + 5)
     model = get_model("gpt2_tiny")
     shapes = jax.eval_shape(
         lambda: model.init({"params": jax.random.PRNGKey(0)},
@@ -383,61 +356,20 @@ def test_reference_agrees_with_the_program_through_prefill_and_cache(tiny):
         row = reference.logits(weights, jnp.asarray([seq]), sizes["heads"])
         seq.append(int(np.asarray(row)[0, -1].argmax()))
     served = np.asarray(seq[16:])
-    gaps = reference.served_token_gaps(
-        weights, sizes["heads"], ids[0, :16], served, pad_to=64)
+    short = {**sizes, "positions": 64}
+    gaps = reference.served_token_gaps(weights, short, ids[0, :16], served)
     assert gaps.shape == (8,) and gaps.max() < 1e-4
     served[3] = (served[3] + 1) % sizes["vocab"]
-    bad = reference.served_token_gaps(
-        weights, sizes["heads"], ids[0, :16], served, pad_to=64)
+    bad = reference.served_token_gaps(weights, short, ids[0, :16], served)
     assert bad[3] > 0.01 and bad[:3].max() < 1e-4
 
 
-def _run_tiny(manifest, name, seed, trace=False, seconds=1.0):
-    cell = harness.Cell(manifest, name)
-    if cell.config["program"]["entry"] == "serve":
-        from benchmark import serve_driver as drv
-    else:
-        from benchmark import train_driver as drv
-    out, err = io.StringIO(), io.StringIO()
-    real = harness.emit
-
-    def quiet(*a, **kw):
-        return real(*a, out=out, err=err, **kw)
-
-    harness.emit, drv.harness.emit = quiet, quiet
-    try:
-        line = drv.run(cell, seed, seconds, trace, time.monotonic(),
-                       allow_cpu=True)
-    finally:
-        harness.emit = drv.harness.emit = real
-    assert json.loads(out.getvalue().splitlines()[-1]) == line
-    assert err.getvalue().splitlines()[-1] == f"correct {line['correct']}"
-    assert list(line)[-1] == "compared"
-    return line
-
-
-DEVICE_ONLY = {"serve_mfu_pct", "train_mfu_pct", "flash_train_roofline",
-               "device_idle_pct.serve", "device_idle_pct.train"}
-SERVE_LAYERS = {"ttft_p95_ms.closed", "prefill_share_pct", "decode_step_ms",
-                "slot_occupancy_pct", "cache_fill_pct"}
-
-
-@pytest.mark.parametrize("name,trace,expect", [
-    ("tiny.closed", False, {"serve_tokens_per_s", "itl_p95_ms", "setup_s"}),
-    ("tiny.closed", True, SERVE_LAYERS),
-    ("tiny.open", False, {"serve_tokens_per_s", "itl_p95_ms", "setup_s"}),
-    ("tiny.train", False, {"train_tokens_per_s", "setup_s"}),
-    ("tiny.train", True, {"loader_wait_pct"}),
-])
-def test_every_driver_rehearses_end_to_end_on_the_cpu(tiny, name, trace,
-                                                      expect):
-    line = _run_tiny(tiny, name, seed=2**31 + 17, trace=trace)
-    assert line["correct"] is True, line["compared"]
-    assert line["failed"] == 0 and line["attempted"] > 0
-    assert set(line["metrics"]) == expect
-    assert not set(line["metrics"]) & DEVICE_ONLY   # no device metric here
-    assert line["device"]["platform"] == "cpu"
-    assert all(v["value"] > 0 for v in line["metrics"].values())
+def test_every_driver_rehearses_end_to_end_on_the_cpu(rehearse, tiny_case):
+    """Every file under ``tiny/cells/``, untraced and traced: the end-to-end
+    metrics the manifest lists for the cell, or the per-layer ones its file
+    says a CPU can read, and never a device metric."""
+    name, trace = tiny_case
+    rehearse(name, trace, seed=2**31 + 17)
 
 
 def test_no_accelerator_no_result():
@@ -463,8 +395,8 @@ def test_cache_fill_counts_live_positions_over_the_reserved_pool():
     assert cache_fill.read({**ctx, "records": []}) is None
 
 
-def test_a_token_altered_where_it_is_produced_is_not_correct(monkeypatch,
-                                                             tiny):
+def test_a_token_altered_where_it_is_produced_is_not_correct(
+        monkeypatch, tiny, run_tiny):
     from ml_trainer_tpu.serving.scheduler import Request
 
     real = Request.push_token
@@ -475,7 +407,7 @@ def test_a_token_altered_where_it_is_produced_is_not_correct(monkeypatch,
         return real(self, (token + 1) % 1024 if n % 7 == 3 else token)
 
     monkeypatch.setattr(Request, "push_token", altered)
-    line = _run_tiny(tiny, "tiny.closed", seed=23)
+    line = run_tiny(tiny, "tiny.closed", seed=23)
     assert line["correct"] is False
     gap = line["compared"]["served_token_gap_mean"]
     assert gap["value"] > 10 * gap["limit"]
@@ -493,7 +425,8 @@ def _break_train_step(monkeypatch, breaker):
 
 
 @pytest.mark.parametrize("fault", ["state_unchanged", "half_batch"])
-def test_a_broken_train_step_is_not_correct(monkeypatch, tiny, fault):
+def test_a_broken_train_step_is_not_correct(monkeypatch, tiny, run_tiny,
+                                            fault):
     def state_unchanged(step):
         def f(state, x, y, lr_scale):
             return (state,) + tuple(step(state, x, y, lr_scale)[1:])
@@ -506,7 +439,7 @@ def test_a_broken_train_step_is_not_correct(monkeypatch, tiny, fault):
         return f
 
     _break_train_step(monkeypatch, locals()[fault])
-    line = _run_tiny(tiny, "tiny.train", seed=31)
+    line = run_tiny(tiny, "tiny.train", seed=31)
     assert line["correct"] is False
     over = {k for k, c in line["compared"].items() if c["value"] > c["limit"]}
     if fault == "state_unchanged":
@@ -522,13 +455,12 @@ def test_the_fp8_control_fails_a_training_number_at_test_size(tiny):
     from benchmark import train_driver
 
     cell = harness.Cell(tiny, "tiny.train")
-    sizes = reference.sizes_of(cell.config)
+    reference, sizes = cell.reference, cell.sizes()
     for seed in (1, 2, 3):
-        weights = reference.make_weights(reference.seed_key(seed), **sizes)
+        weights = harness.make_weights(cell, seed)
         data, targets = train_driver.token_rows(seed, 24, 128, sizes["vocab"])
         batches = [(data[i:i + 8], targets[i:i + 8]) for i in (0, 8, 16)]
-        kw = dict(heads=sizes["heads"], lr=1e-4, weight_decay=0.0,
-                  rows_per_block=4)
+        kw = dict(sizes=sizes, lr=1e-4, weight_decay=0.0, rows_per_block=4)
         ref = reference.train_steps(weights, batches, **kw)
         same = train_driver.compare(cell.config["limits"], ref, ref)
         assert harness.judge(same)
@@ -561,10 +493,10 @@ def test_the_fp8_control_in_the_programs_place_is_not_correct(tiny):
                     "output_len": fixed(o_len)}
     cell.config = {**cell.config, "check": {"requests": n},
                    "limits": {"served_token_gap_mean": 3e-6}}
-    sizes = reference.sizes_of(cell.config)
+    sizes = cell.sizes()
     model = harness.build_model(cell.config)
     for seed in (1, 2, 2**31 + 29):
-        weights = harness.make_weights(cell.config, seed)
+        weights = harness.make_weights(cell, seed)
         schedule = loadgen.iter_schedule(cell.traffic, sizes["vocab"], seed)
         reqs = [next(schedule) for _ in range(n)]
         out = np.asarray(generate(
