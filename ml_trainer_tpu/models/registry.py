@@ -26,7 +26,9 @@ DRAFT_PAIRS: Dict[str, str] = {
     # drafter covers those targets model-free.
 }
 
-_FAMILY_MODULES = ("mlmodel", "resnet", "vit", "bert", "gpt2", "llama")
+_FAMILY_MODULES = (
+    "mlmodel", "resnet", "vit", "bert", "gpt2", "llama", "exaone_moe",
+)
 
 
 def register_model(name: str):

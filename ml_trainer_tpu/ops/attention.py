@@ -45,8 +45,11 @@ def dot_product_attention(
     causal: bool = False,
     mask: Optional[jax.Array] = None,
     scale: Optional[float] = None,
+    window: Optional[int] = None,
 ) -> jax.Array:
-    """Reference XLA attention.  q,k,v: [B, H, S, D] (k/v may have S_kv)."""
+    """Reference XLA attention.  q,k,v: [B, H, S, D] (k/v may have S_kv).
+    ``window`` narrows the causal mask to a band: a query attends the
+    ``window`` newest keys up to its own position."""
     if scale is None:
         scale = q.shape[-1] ** -0.5
     scores = jnp.einsum(
@@ -57,6 +60,8 @@ def dot_product_attention(
         row = jax.lax.broadcasted_iota(jnp.int32, (s_q, s_k), 0)
         col = jax.lax.broadcasted_iota(jnp.int32, (s_q, s_k), 1)
         causal_mask = row + (s_k - s_q) >= col
+        if window is not None:
+            causal_mask &= row + (s_k - s_q) - col < window
         scores = scores + _mask_bias(causal_mask, scores.dtype)
     if mask is not None:
         scores = scores + _mask_bias(mask, scores.dtype)
@@ -642,9 +647,15 @@ def attention(
     block_k: int = 128,
     mesh=None,
     ring_axis: str = "sequence",
+    window: Optional[int] = None,
 ):
     """Dispatch between the Pallas flash kernel, ring sequence parallelism
     and the XLA path.
+
+    ``window`` (with ``causal``) makes the causal mask a band: position t
+    attends positions j with ``t - window < j <= t``.  Only the XLA path
+    states it, so 'auto' takes that path and the others refuse (the flash
+    kernel skipping the blocks outside the band is not written yet).
 
     ``implementation``: 'auto' | 'xla' | 'flash' | 'ring' | 'ulysses'.
     ARBITRARY masks always take the XLA path (requesting 'flash' with one
@@ -676,6 +687,16 @@ def attention(
     gathers sequence, attention runs dense locally, a second a2a restores
     the layout; requires ``mesh`` and heads divisible by the axis size.
     """
+    if window is not None:
+        if (not causal or kv_lens is not None
+                or implementation not in ("auto", "xla")):
+            raise ValueError(
+                "a window needs causal=True, no kv_lens and the XLA path "
+                f"(got causal={causal}, implementation={implementation!r})"
+            )
+        return dot_product_attention(
+            q, k, v, causal=True, mask=mask, scale=scale, window=window
+        )
     if implementation in ("ring", "ulysses"):
         # Shared preconditions for the sequence-parallel strategies.
         if mask is not None or kv_lens is not None:

@@ -79,6 +79,7 @@ bound covers every decode executable in the process.
 
 from __future__ import annotations
 
+import inspect
 import time
 from typing import Dict, List, Optional
 
@@ -329,6 +330,14 @@ class SlotDecodeEngine:
         # lora clone: the adapter shapes the cached K/V, so the prefill
         # program gathers the request's adapter too.)
         self._dm_prefill = self._prefill_model.clone(decode=True)
+        # What a model may offer beyond the GPT-2 family's call (the engine
+        # knows no architecture by name): per-row counters of the decode
+        # step turned into arguments of its fence span, and a prefill that
+        # is told the prompt's true length inside its padded bucket (a
+        # cache that keeps the last positions only must not keep padding).
+        self._counter_args = getattr(model, "step_counter_args", None)
+        self._prefill_takes_len = "true_len" in inspect.signature(
+            type(model).__call__).parameters
         self.params = (
             variables["params"] if "params" in variables else variables
         )
@@ -567,6 +576,25 @@ class SlotDecodeEngine:
 
             return jax.jit(step_lora, donate_argnums=(1, 2))
 
+        if self._counter_args is not None:
+            def step_counted(params, cache, tok, temps, rngs, steps,
+                             in_flight):
+                # A model with ``step_counter_args`` sows per-row counts
+                # (row axis first) in "step_counters"; the step returns
+                # them summed over the rows in flight, beside the tokens.
+                logits, mut = dm.apply(
+                    {"params": params, "cache": cache}, tok,
+                    train=False, mutable=["cache", "step_counters"],
+                )
+                nxt = _sample_rows(logits[:, -1], temps, rngs, steps)
+                counted = jax.tree.map(
+                    lambda c: jnp.tensordot(in_flight, c, axes=1),
+                    mut["step_counters"],
+                )
+                return mut["cache"], nxt[:, None].astype(jnp.int32), counted
+
+            return jax.jit(step_counted, donate_argnums=(1, 2))
+
         def step(params, cache, tok, temps, rngs, steps):
             logits, mut = dm.apply(
                 {"params": params, "cache": cache}, tok,
@@ -751,11 +779,14 @@ class SlotDecodeEngine:
 
             return jax.jit(prefill_lora)
 
+        told = self._prefill_takes_len and dm is self._dm_prefill
+
         def prefill(params, prompt_pad, true_len, temp, rng, step0):
             cache = _empty_cache(shapes)
             logits, mut = dm.apply(
                 {"params": params, "cache": cache}, prompt_pad,
                 train=False, mutable=["cache"],
+                **({"true_len": true_len} if told else {}),
             )
             # Causal prefill: the padded tail cannot influence position
             # true_len-1, whose logits sample token 0 (fold counter
@@ -1498,6 +1529,13 @@ class SlotDecodeEngine:
             np.int32(slot), np.int32(p),
         )
 
+    def _in_flight(self) -> np.ndarray:
+        """1 for each slot that holds a running request, for the counters
+        of a decode step (a free slot computes garbage nobody counts)."""
+        rows = np.zeros((self.max_batch,), np.int32)
+        rows[list(self._active)] = 1
+        return rows
+
     def _finished(self, req: Request, token: int) -> bool:
         """Finish-and-unbind if ``req`` just completed; True if so."""
         done = (
@@ -1579,19 +1617,26 @@ class SlotDecodeEngine:
         extra = (
             (self._lora_vars(self._adapter_rows),) if self._lora_on
             else (self._quant,) if self.quant_int8
+            else (self._in_flight(),) if self._counter_args is not None
             else ()
         )
         with span("serve_decode", engine_step=self._step_seq,
                   active=active_before, requests=step_requests):
             with span("serve_decode.dispatch"):
-                self.cache, self.tok = self._decode(
+                self.cache, self.tok, *counted = self._decode(
                     self.params, self.cache, self.tok,
                     self._temps, self._rngs, self._steps, *extra,
                 )
-            with span("serve_decode.fence"):
+            with span("serve_decode.fence") as fence_args:
                 # The step's ONE fence: every later read this iteration
                 # is host data.  # graft-lint: sync-ok
                 toks = np.asarray(self.tok[:, 0])  # blocks: the step landed
+                if counted:
+                    # The counters left the device with the tokens: the
+                    # same fence.  # graft-lint: sync-ok
+                    landed = jax.device_get(counted[0])
+                    fence_args.update(
+                        self._counter_args(landed, active_before))
         dt = time.perf_counter() - t0
         with span("serve_deliver", emitted=0, freed=0) as delivered:
             # Host mirror of the device's idx += 1 (every row advances).
