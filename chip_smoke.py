@@ -228,7 +228,6 @@ def kernel_cases(cfg: SmokeConfig):
         return jnp.asarray(rng.normal(size=shape) * scale, dtype)
 
     # -- flash attention: the default training and prefill path ------------
-    block = min(128, L)
     for tag, dtype, b, s, causal, lens in (
         ("train bf16 causal", jnp.bfloat16, 2, L, True, None),
         ("prefill f32 causal", jnp.float32, 1, min(256, L), True, None),
@@ -242,7 +241,7 @@ def kernel_cases(cfg: SmokeConfig):
 
         def flash(q, k, v, lens=lens, causal=causal):
             return flash_attention(
-                q, k, v, lens, causal, None, block, block, interp
+                q, k, v, lens, causal, None, None, None, interp
             )
 
         def lax_attn(q, k, v, mask=mask, causal=causal):

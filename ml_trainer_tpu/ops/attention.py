@@ -73,16 +73,188 @@ def dot_product_attention(
 
 
 # --------------------------------------------------------------------- flash
-def _flash_kernel(*refs, block_k: int, causal: bool, scale: float,
+# A grid step of a Pallas kernel costs about 0.35 us on a v5e before it
+# computes anything, so the three kernels below do as much in a step as VMEM
+# allows.  The grid walks (batch*head, q block, kv block) with blocks chosen
+# from the shape (``_flash_blocks``), and INSIDE a step the body sweeps the
+# step's keys in sub-blocks of ``sub_k``, so the score tile is
+# [block_q, sub_k] however many keys the step holds.  The sweep's trip count
+# is the causal (and ``kv_lens``) bound: the dead part of a block the
+# diagonal crosses is not computed, and only the sub-blocks the diagonal or
+# the padded tail crosses pay for a mask.  A kv block that is dead
+# altogether names, in its index map, the block already in VMEM, so the
+# pipeline fetches nothing for it.  The products take their operands in the
+# dtype they arrive in (bfloat16 into the MXU, float32 out); the softmax
+# statistics and every accumulator are float32.
+_NEG = float(np.finfo(np.float32).min)
+_NT = (((1,), (1,)), ((), ()))   # a @ b.T
+_NN = (((1,), (0,)), ((), ()))   # a @ b
+
+# What scripts/flash_tune.py measured on a v5e (PERF.md section 6, PR 28).
+_BLOCK_Q = 512                   # rows of the score tile
+_SUB_K = 512                     # keys a sweep step takes: its columns
+# Of K (and as much of V) a grid step holds: 1,024 keys of 64 in bfloat16.
+# Bytes, not keys: a head of 128 gets half the keys a head of 64 gets, and
+# float32 half of bfloat16, so the step's VMEM stays what was measured.
+_KV_BLOCK_BYTES = 128 * 1024
+
+
+def _largest_block(s: int, cap: int) -> int:
+    """Largest multiple of 128 that divides ``s`` and is <= ``cap``; 128
+    where none larger does; ``s`` itself where not even 128 divides it (one
+    block: only the interpreter is handed such a shape)."""
+    if s % 128:
+        return s
+    return max(m for m in range(128, max(cap, 128) + 1, 128) if s % m == 0)
+
+
+def _flash_blocks(s_q: int, s_k: int, d: int, dtype) -> tuple:
+    """(block_q, block_k) from the shape and the dtype alone.  ``block_k`` is
+    what a grid step holds of K and V, swept in sub-blocks; ``block_q`` is
+    the height of the score tile.  On the v5e all three kernels are bound by
+    the MXU, half filled by heads of 64, and by a fixed cost a tile, so one
+    pair is best for all three: a q block of 256 wastes less above the
+    diagonal (1.25x against 1.5x) and is slower for its twice as many
+    tiles."""
+    return (
+        _largest_block(s_q, _BLOCK_Q),
+        _largest_block(
+            s_k, _KV_BLOCK_BYTES // (d * jnp.dtype(dtype).itemsize)),
+    )
+
+
+def _resolve_blocks(block_q, block_k, q, k) -> tuple:
+    """An explicit integer is honoured; ``None`` is the chooser's."""
+    bq, bk = _flash_blocks(q.shape[2], k.shape[2], q.shape[3], q.dtype)
+    return block_q or bq, block_k or bk
+
+
+def _sub_block(block_k: int) -> int:
+    """Keys one sweep step takes of a kv block of ``block_k``."""
+    return _largest_block(block_k, _SUB_K)
+
+
+def _lanes(stat, n: int):
+    """A per-row statistic against a tile ``n`` lanes wide.  The kernels
+    keep the running maximum, the sum, ``lse`` and ``delta`` replicated
+    across 128 lanes ([block_q, 128]: whole vregs, and a tile takes them
+    by repeating the vreg, which costs nothing) where a [block_q, 1] column
+    would use one lane of each vreg and pay a lane broadcast at every use
+    (2.5 against 1.5 ms for the forward at the training cell's shape, q
+    blocks of 256, v5e, PR 28).  Blocks that 128 does not divide (the
+    interpreter's) keep the column, which broadcasts by itself."""
+    w = stat.shape[-1]
+    if w == 1 or n == w:
+        return stat
+    return jnp.tile(stat, (1, n // w)) if n > w else stat[:, :n]
+
+
+def _stat_lanes(*blocks: int) -> int:
+    """Lanes a per-row statistic is replicated across (see ``_lanes``)."""
+    return 1 if any(b % 128 for b in blocks) else 128
+
+
+def _rows_from_stat(stat):
+    """[block_q, w] statistic -> its rows' values with the POSITIONS ON THE
+    LANES, [block_q // 128, 128] (or [1, block_q] from a column): what HBM
+    keeps without padding.  From the replicated form each 128 x 128 square
+    gives up its diagonal (a select and a sum over sublanes): 0.06 ms a call
+    at the training cell's shape, where reshaping the column costs 0.64 and
+    a transpose 0.12 (v5e, PR 28)."""
+    block_q, w = stat.shape
+    if w == 1:
+        return stat.reshape(1, block_q)
+    squares = stat.reshape(block_q // w, w, w)
+    diagonal = (jax.lax.broadcasted_iota(jnp.int32, squares.shape, 1)
+                == jax.lax.broadcasted_iota(jnp.int32, squares.shape, 2))
+    return jnp.sum(jnp.where(diagonal, squares, 0.0), axis=1)
+
+
+def _stat_from_row(row, w: int):
+    """[1, block_q] row, positions on the lanes -> [block_q, w] replicated
+    across ``w`` lanes: copy the row down 128 sublanes (cheap) and transpose
+    the squares.  0.09 ms a call and statistic at the training cell's shape,
+    where reshaping to a column costs 0.2 (v5e, PR 28)."""
+    block_q = row.shape[-1]
+    if w == 1:
+        return row.reshape(block_q, 1)
+    return jnp.broadcast_to(row, (w, block_q)).T
+
+
+def _keep_mask(p_shape, q_start, kv_start, kv_len, causal, masked,
+               q_axis=0):
+    """The score-keep mask shared by all three kernels (forward and the
+    two backward passes): causal diagonal and/or the padded-key tail —
+    one definition so value and gradient masking cannot diverge.  The
+    queries lie along ``q_axis`` of the tile (dK/dV works on its transpose)."""
+    qpos = q_start + jax.lax.broadcasted_iota(jnp.int32, p_shape, q_axis)
+    kpos = kv_start + jax.lax.broadcasted_iota(jnp.int32, p_shape, 1 - q_axis)
+    keep = None
+    if causal:
+        keep = qpos >= kpos
+    if masked:
+        keep_pad = kpos < kv_len
+        keep = keep_pad if keep is None else jnp.logical_and(keep, keep_pad)
+    return keep
+
+
+def _kv_sweep(step, *, q_start, block_q, kv_start, block_k, sub_k, kv_len,
+              causal):
+    """Call ``step(k0, needs_mask)`` for the sub-blocks [k0, k0 + sub_k) of
+    one kv block that some query of the q block attends: first those every
+    query attends whole (no mask), then those the diagonal or the padded
+    tail crosses.  A block that is dead altogether runs nothing."""
+    from jax.experimental import pallas as pl
+
+    n_sub = block_k // sub_k
+
+    def subs_below(bound, partly):
+        # sub-blocks lying wholly (or, with `partly`, at all) below `bound`
+        keys = jnp.maximum(bound - kv_start + (sub_k - 1) * partly, 0)
+        return jnp.minimum(keys // sub_k, n_sub)
+
+    def sweep(lo, hi, needs_mask):
+        jax.lax.fori_loop(
+            lo, hi,
+            lambda i, c: step(pl.multiple_of(i * sub_k, sub_k), needs_mask),
+            None,
+        )
+
+    n_full = n_live = n_sub
+    if causal:
+        n_full = subs_below(q_start + 1, False)
+        n_live = subs_below(q_start + block_q, True)
+    if kv_len is not None:
+        n_full = jnp.minimum(n_full, subs_below(kv_len, False))
+        n_live = jnp.minimum(n_live, subs_below(kv_len, True))
+    sweep(0, n_full, False)
+    if causal or kv_len is not None:
+        sweep(n_full, n_live, True)
+
+
+def _kv_stream_map(causal: bool, block_q: int, block_k: int):
+    """Index map of K and V where they stream past a resident q block
+    (forward and dQ; grid (batch*head, q block, kv block)).  Under the
+    causal mask a dead kv block names the last one q block ``j`` attends,
+    which is in VMEM already: the pipeline fetches nothing for it."""
+    def index_map(i, j, kv):
+        if causal:
+            kv = jnp.minimum(kv, (j * block_q + block_q - 1) // block_k)
+        return (i, kv, 0)
+
+    return index_map
+
+
+def _flash_kernel(*refs, sub_k: int, causal: bool, scale: float,
                   masked: bool):
     """One (batch·head, q-block, kv-block) grid step of the online-softmax
-    recurrence.  KV streams through VMEM one [block_k, D] tile at a time
-    (the kv grid axis iterates fastest), with running (o, m, l) accumulators
-    in VMEM scratch that persist across kv steps; the final kv step
-    normalizes and writes the output block.  With ``masked`` a per-sequence
-    valid-key count streams in via SMEM and columns past it are dropped —
-    the right-padded (BERT) mask family, fused into the kernel instead of
-    falling back to the XLA path."""
+    recurrence.  The step's keys are swept ``sub_k`` at a time
+    (``_kv_sweep``) against the resident q block, with running (o, m, l)
+    accumulators in VMEM scratch that persist across kv steps; the final kv
+    step normalizes and writes the output block.  With ``masked`` a
+    per-sequence valid-key count streams in via SMEM and columns past it are
+    dropped — the right-padded (BERT) mask family, fused into the kernel
+    instead of falling back to the XLA path."""
     from jax.experimental import pallas as pl
 
     if masked:
@@ -92,62 +264,53 @@ def _flash_kernel(*refs, block_k: int, causal: bool, scale: float,
         lens_ref = None
 
     _, block_q, d = q_ref.shape
+    block_k = k_ref.shape[1]
     kv_idx = pl.program_id(2)
-    num_kv = pl.num_programs(2)
     q_start = pl.program_id(1) * block_q
     kv_start = kv_idx * block_k
 
     @pl.when(kv_idx == 0)
     def _init():
-        o_scr[:] = jnp.zeros((block_q, d), jnp.float32)
-        m_scr[:] = jnp.full((block_q, 1), jnp.finfo(jnp.float32).min,
-                            jnp.float32)
-        l_scr[:] = jnp.zeros((block_q, 1), jnp.float32)
+        o_scr[:] = jnp.zeros(o_scr.shape, jnp.float32)
+        m_scr[:] = jnp.full(m_scr.shape, _NEG, jnp.float32)
+        l_scr[:] = jnp.zeros(l_scr.shape, jnp.float32)
 
-    # Under causal masking, blocks fully above the diagonal contribute
-    # nothing — skip their matmuls entirely; likewise blocks entirely in
-    # the padded key tail.
     kv_len = lens_ref[pl.program_id(0)] if masked else None
-    live = (q_start + block_q > kv_start) if causal else True
-    if masked:
-        live = jnp.logical_and(live, kv_start < kv_len)
+    q = q_ref[0]
 
-    @pl.when(live)
-    def _attend():
-        q = q_ref[0].astype(jnp.float32) * scale
-        kk = k_ref[0].astype(jnp.float32)
-        vv = v_ref[0].astype(jnp.float32)
+    def _attend(k0, needs_mask):
+        kk = k_ref[0, pl.ds(k0, sub_k), :]
+        vv = v_ref[0, pl.ds(k0, sub_k), :]
         scores = jax.lax.dot_general(
-            q, kk, (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32,
-        )  # [block_q, block_k]
-        keep = _keep_mask(
-            (block_q, block_k), q_start, kv_start, kv_len, causal, masked,
-        )
-        if keep is not None:
-            scores = jnp.where(keep, scores, jnp.finfo(jnp.float32).min)
+            q, kk, _NT, preferred_element_type=jnp.float32,
+        ) * scale  # [block_q, sub_k]
+        if needs_mask:
+            keep = _keep_mask(
+                scores.shape, q_start, kv_start + k0, kv_len, causal, masked,
+            )
+            scores = jnp.where(keep, scores, _NEG)
         m_prev, l_prev = m_scr[:], l_scr[:]
-        m_cur = jnp.max(scores, axis=-1, keepdims=True)
-        m_new = jnp.maximum(m_prev, m_cur)
-        p = jnp.exp(scores - m_new)
+        m_new = jnp.maximum(m_prev, jnp.max(scores, axis=-1, keepdims=True))
+        p = jnp.exp(scores - _lanes(m_new, sub_k))
         alpha = jnp.exp(m_prev - m_new)
         m_scr[:] = m_new
         l_scr[:] = l_prev * alpha + jnp.sum(p, axis=-1, keepdims=True)
-        o_scr[:] = o_scr[:] * alpha + jax.lax.dot_general(
-            p, vv, (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32,
+        o_scr[:] = o_scr[:] * _lanes(alpha, d) + jax.lax.dot_general(
+            p.astype(vv.dtype), vv, _NN, preferred_element_type=jnp.float32,
         )
 
-    @pl.when(kv_idx == num_kv - 1)
+    _kv_sweep(_attend, q_start=q_start, block_q=block_q, kv_start=kv_start,
+              block_k=block_k, sub_k=sub_k, kv_len=kv_len, causal=causal)
+
+    @pl.when(kv_idx == pl.num_programs(2) - 1)
     def _finalize():
-        o_ref[0] = (o_scr[:] / l_scr[:]).astype(o_ref.dtype)
+        o_ref[0] = (o_scr[:] / _lanes(l_scr[:], d)).astype(o_ref.dtype)
         # Per-row logsumexp of the scaled scores — the only softmax
         # statistic the flash backward needs (FlashAttention-2 style).
-        # Written as a [block_q, 1] column: a trailing singleton dim is
-        # exempt from Mosaic's (8, 128) block-tiling rule, whereas a
-        # [1, block_q] row block is rejected by the compiled lowering
-        # (interpret mode never checks this).
-        lse_ref[0] = m_scr[:] + jnp.log(l_scr[:])
+        # Written with the positions on the lanes: in HBM a [.., S, 1]
+        # array is tiled with its last dimension padded to 128 lanes, 128
+        # times the bytes, and the backward would keep that.
+        lse_ref[0, 0] = _rows_from_stat(m_scr[:] + jnp.log(l_scr[:]))
 
 
 def _lens_per_bh(kv_lens, b, h):
@@ -155,6 +318,23 @@ def _lens_per_bh(kv_lens, b, h):
     return jnp.repeat(kv_lens.astype(jnp.int32), h)
 
 
+def _compiler_params():
+    """Heads and q (or kv) blocks are independent; the last grid axis
+    accumulates."""
+    from jax.experimental.pallas import tpu as pltpu
+
+    return pltpu.CompilerParams(
+        dimension_semantics=("parallel", "parallel", "arbitrary"))
+
+
+# Both wrappers are jitted so that a model's layers, which call them with the
+# same shapes, share ONE trace and ONE lowering to Mosaic of each kernel: a
+# step of 12 layers otherwise lowers 36 kernel bodies on every start, compile
+# cache or not (as ops/kernels/slot_cache_write.py found for its own call).
+_STATIC = ("causal", "scale", "block_q", "block_k", "interpret")
+
+
+@functools.partial(jax.jit, static_argnames=_STATIC)
 def _flash_forward(q, k, v, kv_lens, *, causal, scale, block_q, block_k,
                    interpret):
     from jax.experimental import pallas as pl
@@ -166,18 +346,23 @@ def _flash_forward(q, k, v, kv_lens, *, causal, scale, block_q, block_k,
     kr = k.reshape(b * h, s_k, d)
     vr = v.reshape(b * h, s_k, d)
     masked = kv_lens is not None
+    sub_k = _sub_block(block_k)
+    stat_lanes = _stat_lanes(block_q, sub_k)
+    # lse leaves as [.., q blocks, block_q / 128, 128] (or [.., 1, block_q]):
+    # row-major that IS [B*H, S].
+    lse_block = (block_q // stat_lanes, stat_lanes) if stat_lanes > 1 else (
+        1, block_q)
     kernel = functools.partial(
-        _flash_kernel, block_k=block_k, causal=causal, scale=scale,
-        masked=masked,
+        _flash_kernel, sub_k=sub_k, causal=causal, scale=scale, masked=masked,
     )
     grid = (b * h, pl.cdiv(s_q, block_q), pl.cdiv(s_k, block_k))
+
+    kv_map = _kv_stream_map(causal, block_q, block_k)
     in_specs = [
         pl.BlockSpec((1, block_q, d), lambda i, j, kv: (i, j, 0),
                      memory_space=pltpu.VMEM),
-        pl.BlockSpec((1, block_k, d), lambda i, j, kv: (i, kv, 0),
-                     memory_space=pltpu.VMEM),
-        pl.BlockSpec((1, block_k, d), lambda i, j, kv: (i, kv, 0),
-                     memory_space=pltpu.VMEM),
+        pl.BlockSpec((1, block_k, d), kv_map, memory_space=pltpu.VMEM),
+        pl.BlockSpec((1, block_k, d), kv_map, memory_space=pltpu.VMEM),
     ]
     operands = [qr, kr, vr]
     if masked:
@@ -190,50 +375,40 @@ def _flash_forward(q, k, v, kv_lens, *, causal, scale, block_q, block_k,
         out_specs=[
             pl.BlockSpec((1, block_q, d), lambda i, j, kv: (i, j, 0),
                          memory_space=pltpu.VMEM),
-            pl.BlockSpec((1, block_q, 1), lambda i, j, kv: (i, j, 0),
+            pl.BlockSpec((1, 1) + lse_block, lambda i, j, kv: (i, j, 0, 0),
                          memory_space=pltpu.VMEM),
         ],
         out_shape=[
             jax.ShapeDtypeStruct((b * h, s_q, d), q.dtype),
-            jax.ShapeDtypeStruct((b * h, s_q, 1), jnp.float32),
+            jax.ShapeDtypeStruct((b * h, grid[1]) + lse_block, jnp.float32),
         ],
         scratch_shapes=[
             pltpu.VMEM((block_q, d), jnp.float32),
-            pltpu.VMEM((block_q, 1), jnp.float32),
-            pltpu.VMEM((block_q, 1), jnp.float32),
+            pltpu.VMEM((block_q, stat_lanes), jnp.float32),
+            pltpu.VMEM((block_q, stat_lanes), jnp.float32),
         ],
+        compiler_params=_compiler_params(),
         interpret=interpret,
         name="flash_fwd",
     )(*operands)
     return out.reshape(b, h, s_q, d), lse.reshape(b, h, s_q)
 
 
-# Per-row statistics (lse, delta) travel through the backward kernels as
-# [B*H, S, 1] columns with (1, block, 1) blocks for the same Mosaic
-# block-tiling reason documented in _flash_kernel's finalize.
+# Per-row statistics (lse, delta) travel between the kernels as [B*H, 1, S]
+# rows, positions on the lanes, in (1, 1, block_q) blocks.  dQ, whose score
+# tile has the queries on the sublanes, spreads its block over the lanes
+# (``_lanes``) once a grid step; dK/dV computes the TRANSPOSED tile (keys on the sublanes,
+# queries on the lanes), so a row is what it subtracts, and all four of its
+# products are plain ones: nothing is transposed on the way to the MXU.
 
 
-def _keep_mask(p_shape, q_start, kv_start, kv_len, causal, masked):
-    """The score-keep mask shared by all three kernels (forward and the
-    two backward passes): causal diagonal and/or the padded-key tail —
-    one definition so value and gradient masking cannot diverge."""
-    row = jax.lax.broadcasted_iota(jnp.int32, p_shape, 0)
-    col = jax.lax.broadcasted_iota(jnp.int32, p_shape, 1)
-    keep = None
-    if causal:
-        keep = (q_start + row) >= (kv_start + col)
-    if masked:
-        keep_pad = (kv_start + col) < kv_len
-        keep = keep_pad if keep is None else jnp.logical_and(keep, keep_pad)
-    return keep
-
-
-def _flash_bwd_dq_kernel(*refs, block_k: int, causal: bool, scale: float,
+def _flash_bwd_dq_kernel(*refs, sub_k: int, causal: bool, scale: float,
                          masked: bool):
     """dQ pass: one q-block stays resident while KV blocks stream through
-    (kv is the fastest grid axis); dQ accumulates in VMEM scratch and is
-    written once on the last kv step.  Recomputes P from (q, k, lse) — the
-    block-recompute that keeps backward memory O(S)."""
+    (kv is the fastest grid axis), each swept ``sub_k`` keys at a time; dQ
+    accumulates in VMEM scratch and is written once on the last kv step.
+    Recomputes P from (q, k, lse) — the block-recompute that keeps backward
+    memory O(S)."""
     from jax.experimental import pallas as pl
 
     if masked:
@@ -244,8 +419,8 @@ def _flash_bwd_dq_kernel(*refs, block_k: int, causal: bool, scale: float,
         lens_ref = None
 
     _, block_q, d = q_ref.shape
+    block_k = k_ref.shape[1]
     kv_idx = pl.program_id(2)
-    num_kv = pl.num_programs(2)
     q_start = pl.program_id(1) * block_q
     kv_start = kv_idx * block_k
 
@@ -254,47 +429,46 @@ def _flash_bwd_dq_kernel(*refs, block_k: int, causal: bool, scale: float,
         dq_scr[:] = jnp.zeros((block_q, d), jnp.float32)
 
     kv_len = lens_ref[pl.program_id(0)] if masked else None
-    live = (q_start + block_q > kv_start) if causal else True
-    if masked:
-        live = jnp.logical_and(live, kv_start < kv_len)
+    q = q_ref[0]
+    do = do_ref[0]
+    lse = _stat_from_row(lse_ref[0], _stat_lanes(block_q, sub_k))
+    delta = _stat_from_row(delta_ref[0], _stat_lanes(block_q, sub_k))
 
-    @pl.when(live)
-    def _accumulate():
-        q = q_ref[0].astype(jnp.float32)
-        kk = k_ref[0].astype(jnp.float32)
-        vv = v_ref[0].astype(jnp.float32)
-        do = do_ref[0].astype(jnp.float32)
-        lse = lse_ref[0]                   # [block_q, 1]
-        delta = delta_ref[0]               # [block_q, 1]
+    def _accumulate(k0, needs_mask):
+        kk = k_ref[0, pl.ds(k0, sub_k), :]
+        vv = v_ref[0, pl.ds(k0, sub_k), :]
         scores = jax.lax.dot_general(
-            q, kk, (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32,
+            q, kk, _NT, preferred_element_type=jnp.float32,
         ) * scale
-        p = jnp.exp(scores - lse)          # [block_q, block_k]
-        keep = _keep_mask(
-            p.shape, q_start, kv_start, kv_len, causal, masked,
-        )
-        if keep is not None:
+        p = jnp.exp(scores - _lanes(lse, sub_k))   # [block_q, sub_k]
+        if needs_mask:
+            keep = _keep_mask(
+                p.shape, q_start, kv_start + k0, kv_len, causal, masked,
+            )
             p = jnp.where(keep, p, 0.0)
         dp = jax.lax.dot_general(
-            do, vv, (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32,
+            do, vv, _NT, preferred_element_type=jnp.float32,
         )
-        ds = p * (dp - delta) * scale
+        ds = p * (dp - _lanes(delta, sub_k))   # times `scale`: in _finalize
         dq_scr[:] = dq_scr[:] + jax.lax.dot_general(
-            ds, kk, (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32,
+            ds.astype(kk.dtype), kk, _NN, preferred_element_type=jnp.float32,
         )
 
-    @pl.when(kv_idx == num_kv - 1)
+    _kv_sweep(_accumulate, q_start=q_start, block_q=block_q,
+              kv_start=kv_start, block_k=block_k, sub_k=sub_k, kv_len=kv_len,
+              causal=causal)
+
+    @pl.when(kv_idx == pl.num_programs(2) - 1)
     def _finalize():
-        dq_ref[0] = dq_scr[:].astype(dq_ref.dtype)
+        dq_ref[0] = (dq_scr[:] * scale).astype(dq_ref.dtype)
 
 
-def _flash_bwd_dkv_kernel(*refs, block_q: int, causal: bool, scale: float,
+def _flash_bwd_dkv_kernel(*refs, sub_k: int, causal: bool, scale: float,
                           masked: bool):
     """dK/dV pass: one kv-block stays resident while Q blocks stream through
-    (q is the fastest grid axis); dK and dV accumulate in VMEM scratch."""
+    (q is the fastest grid axis); dK and dV accumulate in VMEM scratch, a
+    sub-block of ``sub_k`` keys at a time.  The tile is the transpose of the
+    other two kernels': [sub_k, block_q]."""
     from jax.experimental import pallas as pl
 
     if masked:
@@ -306,8 +480,8 @@ def _flash_bwd_dkv_kernel(*refs, block_q: int, causal: bool, scale: float,
         lens_ref = None
 
     _, block_k, d = k_ref.shape
+    block_q = q_ref.shape[1]
     q_idx = pl.program_id(2)
-    num_q = pl.num_programs(2)
     kv_start = pl.program_id(1) * block_k
     q_start = q_idx * block_q
 
@@ -316,50 +490,49 @@ def _flash_bwd_dkv_kernel(*refs, block_q: int, causal: bool, scale: float,
         dk_scr[:] = jnp.zeros((block_k, d), jnp.float32)
         dv_scr[:] = jnp.zeros((block_k, d), jnp.float32)
 
+    # A kv block entirely in the padded tail gets zero gradient.
     kv_len = lens_ref[pl.program_id(0)] if masked else None
-    live = (q_start + block_q > kv_start) if causal else True
-    if masked:
-        # A kv block entirely in the padded tail gets zero gradient.
-        live = jnp.logical_and(live, kv_start < kv_len)
+    q = q_ref[0]
+    do = do_ref[0]
+    lse = lse_ref[0]                       # [1, block_q]
+    delta = delta_ref[0]
 
-    @pl.when(live)
-    def _accumulate():
-        q = q_ref[0].astype(jnp.float32)
-        kk = k_ref[0].astype(jnp.float32)
-        vv = v_ref[0].astype(jnp.float32)
-        do = do_ref[0].astype(jnp.float32)
-        lse = lse_ref[0]                   # [block_q, 1]
-        delta = delta_ref[0]               # [block_q, 1]
+    def _accumulate(k0, needs_mask):
+        rows = pl.ds(k0, sub_k)
+        kk = k_ref[0, rows, :]
+        vv = v_ref[0, rows, :]
         scores = jax.lax.dot_general(
-            q, kk, (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32,
+            kk, q, _NT, preferred_element_type=jnp.float32,
         ) * scale
-        p = jnp.exp(scores - lse)          # [block_q, block_k]
-        keep = _keep_mask(
-            p.shape, q_start, kv_start, kv_len, causal, masked,
-        )
-        if keep is not None:
+        p = jnp.exp(scores - lse)          # [sub_k, block_q]
+        if needs_mask:
+            keep = _keep_mask(
+                p.shape, q_start, kv_start + k0, kv_len, causal, masked,
+                q_axis=1,
+            )
             p = jnp.where(keep, p, 0.0)
-        dv_scr[:] = dv_scr[:] + jax.lax.dot_general(
-            p, do, (((0,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32,
+        dv_scr[rows, :] = dv_scr[rows, :] + jax.lax.dot_general(
+            p.astype(do.dtype), do, _NN, preferred_element_type=jnp.float32,
         )
         dp = jax.lax.dot_general(
-            do, vv, (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32,
+            vv, do, _NT, preferred_element_type=jnp.float32,
         )
-        ds = p * (dp - delta) * scale
-        dk_scr[:] = dk_scr[:] + jax.lax.dot_general(
-            ds, q, (((0,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32,
+        ds = p * (dp - delta)              # times `scale`: in _finalize
+        dk_scr[rows, :] = dk_scr[rows, :] + jax.lax.dot_general(
+            ds.astype(q.dtype), q, _NN, preferred_element_type=jnp.float32,
         )
 
-    @pl.when(q_idx == num_q - 1)
+    _kv_sweep(_accumulate, q_start=q_start, block_q=block_q,
+              kv_start=kv_start, block_k=block_k, sub_k=sub_k, kv_len=kv_len,
+              causal=causal)
+
+    @pl.when(q_idx == pl.num_programs(2) - 1)
     def _finalize():
-        dk_ref[0] = dk_scr[:].astype(dk_ref.dtype)
+        dk_ref[0] = (dk_scr[:] * scale).astype(dk_ref.dtype)
         dv_ref[0] = dv_scr[:].astype(dv_ref.dtype)
 
 
+@functools.partial(jax.jit, static_argnames=_STATIC)
 def _flash_backward(q, k, v, kv_lens, out, lse, g, *, causal, scale, block_q,
                     block_k, interpret):
     from jax.experimental import pallas as pl
@@ -371,14 +544,13 @@ def _flash_backward(q, k, v, kv_lens, out, lse, g, *, causal, scale, block_q,
     kr = k.reshape(b * h, s_k, d)
     vr = v.reshape(b * h, s_k, d)
     dor = g.reshape(b * h, s_q, d)
-    lser = lse.reshape(b * h, s_q, 1)
+    lser = lse.reshape(b * h, 1, s_q)
     # delta_i = rowsum(dO_i * O_i) — a cheap elementwise reduce; let XLA
     # fuse it rather than adding a third kernel pass.
     delta = jnp.sum(
         dor.astype(jnp.float32) * out.reshape(b * h, s_q, d).astype(jnp.float32),
-        axis=-1, keepdims=True,
-    )
-    nq, nkv = pl.cdiv(s_q, block_q), pl.cdiv(s_k, block_k)
+        axis=-1,
+    ).reshape(b * h, 1, s_q)
     masked = kv_lens is not None
     operands = [qr, kr, vr, dor, lser, delta]
     lens_spec = []
@@ -386,37 +558,38 @@ def _flash_backward(q, k, v, kv_lens, out, lse, g, *, causal, scale, block_q,
         operands.append(_lens_per_bh(kv_lens, b, h))
         lens_spec = [pl.BlockSpec(memory_space=pltpu.SMEM)]
 
-    qspec = pl.BlockSpec((1, block_q, d), lambda i, j, x: (i, j, 0),
-                         memory_space=pltpu.VMEM)
-    kvspec_stream = pl.BlockSpec((1, block_k, d), lambda i, j, x: (i, x, 0),
-                                 memory_space=pltpu.VMEM)
-    rowspec = pl.BlockSpec((1, block_q, 1), lambda i, j, x: (i, j, 0),
-                           memory_space=pltpu.VMEM)
+    def vmem(block, index_map):
+        return pl.BlockSpec(block, index_map, memory_space=pltpu.VMEM)
+
+    qspec = vmem((1, block_q, d), lambda i, j, x: (i, j, 0))
+    rowspec = vmem((1, 1, block_q), lambda i, j, x: (i, 0, j))
+    kvspec = vmem((1, block_k, d), _kv_stream_map(causal, block_q, block_k))
     dq = pl.pallas_call(
-        functools.partial(_flash_bwd_dq_kernel, block_k=block_k,
+        functools.partial(_flash_bwd_dq_kernel, sub_k=_sub_block(block_k),
                           causal=causal, scale=scale, masked=masked),
-        grid=(b * h, nq, nkv),
-        in_specs=[qspec, kvspec_stream, kvspec_stream, qspec, rowspec,
-                  rowspec] + lens_spec,
+        grid=(b * h, pl.cdiv(s_q, block_q), pl.cdiv(s_k, block_k)),
+        in_specs=[qspec, kvspec, kvspec, qspec, rowspec, rowspec] + lens_spec,
         out_specs=qspec,
         out_shape=jax.ShapeDtypeStruct((b * h, s_q, d), q.dtype),
         scratch_shapes=[pltpu.VMEM((block_q, d), jnp.float32)],
+        compiler_params=_compiler_params(),
         interpret=interpret,
         name="flash_bwd_dq",
     )(*operands)
 
-    kvspec = pl.BlockSpec((1, block_k, d), lambda i, j, x: (i, j, 0),
-                          memory_space=pltpu.VMEM)
-    qspec_stream = pl.BlockSpec((1, block_q, d), lambda i, j, x: (i, x, 0),
-                                memory_space=pltpu.VMEM)
-    rowspec_stream = pl.BlockSpec((1, block_q, 1), lambda i, j, x: (i, x, 0),
-                                  memory_space=pltpu.VMEM)
+    def q_stream(j, x):
+        if causal:  # the q blocks before kv block j's first live one name it
+            x = jnp.maximum(x, (j * block_k) // block_q)
+        return x
+
+    kvspec = vmem((1, block_k, d), lambda i, j, x: (i, j, 0))
+    qspec = vmem((1, block_q, d), lambda i, j, x: (i, q_stream(j, x), 0))
+    rowspec = vmem((1, 1, block_q), lambda i, j, x: (i, 0, q_stream(j, x)))
     dk, dv = pl.pallas_call(
-        functools.partial(_flash_bwd_dkv_kernel, block_q=block_q,
+        functools.partial(_flash_bwd_dkv_kernel, sub_k=_sub_block(block_k),
                           causal=causal, scale=scale, masked=masked),
-        grid=(b * h, nkv, nq),
-        in_specs=[qspec_stream, kvspec, kvspec, qspec_stream, rowspec_stream,
-                  rowspec_stream] + lens_spec,
+        grid=(b * h, pl.cdiv(s_k, block_k), pl.cdiv(s_q, block_q)),
+        in_specs=[qspec, kvspec, kvspec, qspec, rowspec, rowspec] + lens_spec,
         out_specs=[kvspec, kvspec],
         out_shape=[
             jax.ShapeDtypeStruct((b * h, s_k, d), k.dtype),
@@ -426,6 +599,7 @@ def _flash_backward(q, k, v, kv_lens, out, lse, g, *, causal, scale, block_q,
             pltpu.VMEM((block_k, d), jnp.float32),
             pltpu.VMEM((block_k, d), jnp.float32),
         ],
+        compiler_params=_compiler_params(),
         interpret=interpret,
         name="flash_bwd_dkv",
     )(*operands)
@@ -444,8 +618,8 @@ def flash_attention(
     kv_lens=None,
     causal: bool = False,
     scale: Optional[float] = None,
-    block_q: int = 128,
-    block_k: int = 128,
+    block_q: Optional[int] = None,
+    block_k: Optional[int] = None,
     interpret: bool = False,
 ):
     """Pallas flash attention, [B, H, S, D] -> [B, H, S, D].
@@ -457,23 +631,25 @@ def flash_attention(
     direction.  ``interpret=True`` runs the kernels in interpreter mode for
     CPU tests.
 
+    ``block_q`` / ``block_k`` left ``None`` are chosen from the shape and
+    the dtype (``_flash_blocks``); an integer is taken as given (compiled,
+    a multiple of 128 or the whole sequence: the per-row statistics leave
+    the forward with the positions on the lanes).
+
     ``kv_lens`` ([B] int, or None) masks the padded key tail per sequence —
     key/value positions >= kv_lens[b] are dropped from the softmax (the
     right-padded BERT mask family, fused into the kernel).  Every length
     must be >= 1.  custom_vjp functions take positional arguments only.
     """
-    if scale is None:
-        scale = q.shape[-1] ** -0.5
-    out, _ = _flash_forward(
-        q, k, v, kv_lens, causal=causal, scale=scale,
-        block_q=block_q, block_k=block_k, interpret=interpret,
-    )
-    return out
+    return _flash_fwd(
+        q, k, v, kv_lens, causal, scale, block_q, block_k, interpret
+    )[0]
 
 
 def _flash_fwd(q, k, v, kv_lens, causal, scale, block_q, block_k, interpret):
     if scale is None:
         scale = q.shape[-1] ** -0.5
+    block_q, block_k = _resolve_blocks(block_q, block_k, q, k)
     out, lse = _flash_forward(
         q, k, v, kv_lens, causal=causal, scale=scale,
         block_q=block_q, block_k=block_k, interpret=interpret,
@@ -485,6 +661,7 @@ def _flash_bwd(causal, scale, block_q, block_k, interpret, res, g):
     q, k, v, kv_lens, out, lse = res
     if scale is None:
         scale = q.shape[-1] ** -0.5
+    block_q, block_k = _resolve_blocks(block_q, block_k, q, k)
     dq, dk, dv = _flash_backward(
         q, k, v, kv_lens, out, lse, g, causal=causal, scale=scale,
         block_q=block_q, block_k=block_k, interpret=interpret,
@@ -544,9 +721,12 @@ def _kernel_specs(q):
     )
 
 
-def _off_tile(q, k, block_q, block_k) -> bool:
+def _off_tile(q, k, block_q=None, block_k=None) -> bool:
+    """Whether the kernel needs ``_flash_padded``.  Blocks left to the
+    chooser are multiples of 128 that divide the sequence, so 128 is the
+    test; which shapes 'auto' hands the kernel does not follow the caps."""
     return bool(
-        q.shape[-2] % block_q or k.shape[-2] % block_k
+        q.shape[-2] % (block_q or 128) or k.shape[-2] % (block_k or 128)
         or q.shape[-1] % 64  # sublane-friendly head dim (Mosaic pads 64->128)
     )
 
@@ -586,7 +766,8 @@ def _flash_supported(q, k) -> bool:
 
 
 # In 'auto' mode the padded-flash path only engages from this sequence
-# length up: padding to the next block multiple costs up to
+# length up: padding to the next multiple of 128 (the blocks are chosen
+# AFTER padding, among its divisors) costs up to
 # (ceil(S/128)*128 / S)^2 extra score FLOPs, which at short S can hand
 # back more than flash saves, while the XLA path's materialized [S, S]
 # scores are still cheap there.  From ~1K tokens the O(S) memory and
@@ -603,7 +784,10 @@ def _flash_padded(q, k, v, kv_lens, causal, scale, block_q, block_k,
       terms to every score (q·k over the padded lanes), and zero-padding
       v makes the extra output lanes exact zeros — both sliced off, so
       the result is bit-equivalent math, not an approximation.
-    * seq -> next multiple of lcm(block_q, block_k): padded KEYS are
+    * seq -> next multiple of 128, or of lcm(block_q, block_k) where they
+      are given (blocks left to the chooser are chosen from the PADDED
+      length, so 1,100 pads to 1,152 and runs in blocks of 384, not to a
+      multiple of the largest block): padded KEYS are
       masked via the kernel's fused ``kv_lens`` right-padding (so they
       contribute nothing forward and get zero dK/dV); padded QUERY rows
       compute values that are sliced off, and their output cotangent is
@@ -616,7 +800,7 @@ def _flash_padded(q, k, v, kv_lens, causal, scale, block_q, block_k,
     b, h, s, d = q.shape
     if scale is None:
         scale = d ** -0.5
-    block = math.lcm(block_q, block_k)
+    block = math.lcm(block_q or 128, block_k or 128)
     s_pad = -(-s // block) * block
     d_pad = -(-d // 64) * 64
     pad = ((0, 0), (0, 0), (0, s_pad - s), (0, d_pad - d))
@@ -643,8 +827,8 @@ def attention(
     kv_lens: Optional[jax.Array] = None,
     scale: Optional[float] = None,
     implementation: str = "auto",
-    block_q: int = 128,
-    block_k: int = 128,
+    block_q: Optional[int] = None,
+    block_k: Optional[int] = None,
     mesh=None,
     ring_axis: str = "sequence",
     window: Optional[int] = None,
@@ -673,7 +857,9 @@ def attention(
     causal mask is aligned to the main diagonal, whereas the XLA path uses
     bottom-right alignment for cross-length decode shapes.
 
-    Off-tile shapes (sequence not divisible by the block sizes, head_dim
+    ``block_q`` / ``block_k`` left ``None`` are chosen by the kernel from
+    the shape it is handed (``_flash_blocks``).  Off-tile shapes (sequence
+    not divisible by 128, or by the blocks where they are given; head_dim
     not a multiple of 64) run the kernel through ``_flash_padded`` —
     exact math via zero-padding plus the fused kv_lens mask, at the cost
     of the padded block's extra FLOPs.  'flash' pads whenever needed;

@@ -1,14 +1,21 @@
-"""Flash-attention block-size sweep on the chip.
+"""Flash-attention block sweep on the chip, a kernel at a time.
 
-The kernel's ``block_q``/``block_k`` default to 128×128 — chosen for
-tile legality, never measured.  This sweeps the grid over the GPT-2
-north-star shape (and any ``--shape``), timing forward and
-forward+backward per geometry, and records the table + the best choice
-to docs/flash_block_tune.json.  If a non-default geometry wins by more
-than ~5%, ops/attention.py's defaults should follow the data.
+``ops/attention.py`` chooses ``block_q`` / ``block_k`` of its three kernels
+from the shape (``_flash_blocks``), under caps that THIS script measured
+(PERF.md section 6 has the table).  A geometry is
+``block_q x block_k x sub_k``: what a grid step holds of q and of K/V, and
+how many keys one step of the sweep inside it takes.  Each geometry runs
+forward, dQ and dK/dV under the profiler and reads each kernel's own device
+time by its name in the trace, so a kernel's time holds nothing of XLA's
+layout copies round it; ``chosen`` is what the chooser picks.  Beside them
+two yardsticks, each by the whole device time of its calls: JAX's own
+Pallas TPU kernel and the XLA path (at 4 rows of the batch: the scores of
+more do not fit).  Results go to standard output and
+``chiprun_out/flash_tune.json``.
 
     python scripts/flash_tune.py
-    python scripts/flash_tune.py --shape 8,12,1024,64 --blocks 128,256,512
+    python scripts/flash_tune.py --shape 1,20,512,64 --fwd-only \
+        --geometries 128x128x128,256x512x512
 
 ``--paged`` sweeps the paged-attention DECODE kernel instead
 (ops/kernels/paged_attention.py): the tunable geometry there is the
@@ -25,7 +32,6 @@ land in docs/paged_decode_tune.json.
 """
 
 import argparse
-import itertools
 import json
 import os
 import sys
@@ -38,7 +44,6 @@ ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 sys.path.insert(0, ROOT)
 sys.path.insert(0, os.path.dirname(os.path.abspath(__file__)))
 
-from ml_trainer_tpu.ops.attention import flash_attention  # noqa: E402
 # ONE definition of the data-dependent chained timing harness (in-order
 # completion cannot be assumed on this platform): reuse it, never fork it.
 from validate_flash_tpu import bench  # noqa: E402
@@ -116,11 +121,101 @@ def run_paged(args) -> None:
     print(f"-> {out} best={best}")
 
 
+def kernel_ms(fn, args, iters=5):
+    """Device milliseconds a call of ``fn``, by operation name, from a
+    profiler trace of ``iters`` calls (the first, untraced, compiles)."""
+    import tempfile
+
+    from benchmark.trace_reduce import find_xplane, load_xplane
+    from ml_trainer_tpu.utils.profiler import force, trace
+
+    force(fn(*args))
+    with tempfile.TemporaryDirectory() as logdir:
+        with trace(logdir):
+            for _ in range(iters):
+                force(fn(*args))
+        events = next(iter(load_xplane(find_xplane(logdir))["devices"].values()))
+    total = {}
+    for name, _, dur in events:
+        name = name.split("|")[0].split(".")[0]
+        total[name] = total.get(name, 0.0) + dur / 1e6 / iters
+    return total
+
+
+def flash_fns(causal, block_q, block_k, fwd_only):
+    """(forward, backward) over the module's own two entry points: the
+    backward alone is the two backward kernels and nothing else."""
+    from ml_trainer_tpu.ops import attention as A
+
+    options = dict(causal=causal, block_q=block_q, block_k=block_k,
+                   interpret=False)
+
+    def fwd(q, k, v):
+        return A._flash_forward(
+            q, k, v, None, scale=q.shape[-1] ** -0.5, **options)
+
+    def bwd(q, k, v, out, lse, g):
+        return A._flash_backward(
+            q, k, v, None, out, lse, g, scale=q.shape[-1] ** -0.5, **options)
+
+    return jax.jit(fwd), None if fwd_only else jax.jit(bwd)
+
+
+def yardsticks(q, k, v, fwd_only):
+    """JAX's own kernel at its largest blocks that divide, and the XLA path
+    on 4 rows: whole device time of a call, every operation counted."""
+    from jax.experimental.pallas.ops.tpu import flash_attention as jfa
+
+    from ml_trainer_tpu.ops.attention import dot_product_attention
+
+    s, d = q.shape[2], q.shape[3]
+    blk = max(m for m in (128, 256, 512) if s % m == 0)
+    sizes = jfa.BlockSizes(
+        block_q=blk, block_k_major=blk, block_k=blk, block_b=1,
+        block_q_major_dkv=blk, block_k_major_dkv=blk, block_k_dkv=blk,
+        block_q_dkv=blk, block_k_major_dq=blk, block_k_dq=blk,
+        block_q_dq=blk)
+
+    def jax_kernel(q, k, v):
+        return jfa.flash_attention(
+            q, k, v, causal=True, sm_scale=d ** -0.5, block_sizes=sizes)
+
+    def xla(q, k, v):
+        return dot_product_attention(q, k, v, causal=True)
+
+    rows = []
+    for name, fn, args in (
+        (f"jax_pallas_flash_{blk}", jax_kernel, (q, k, v)),
+        ("xla_4_rows", xla, (q[:4], k[:4], v[:4])),
+    ):
+        row = {"yardstick": name, "rows": int(args[0].shape[0])}
+        try:
+            row["fwd_ms"] = round(sum(kernel_ms(jax.jit(fn), args).values()), 3)
+            if not fwd_only:
+                grad = jax.jit(jax.grad(
+                    lambda *a, fn=fn: fn(*a).astype(jnp.float32).sum(),
+                    argnums=(0, 1, 2)))
+                row["fwd_bwd_ms"] = round(
+                    sum(kernel_ms(grad, args).values()), 3)
+        except Exception as e:  # refused by Mosaic or out of memory
+            row["error"] = str(e).splitlines()[0][:160]
+        rows.append(row)
+        print(json.dumps(row), flush=True)
+    return rows
+
+
 def main():
     ap = argparse.ArgumentParser()
-    ap.add_argument("--shape", default="8,12,1024,64",
-                    help="B,H,S,D (default: the GPT-2 124M bench shape)")
-    ap.add_argument("--blocks", default="128,256,512")
+    ap.add_argument("--shape", default="32,12,1024,64",
+                    help="B,H,S,D (default: gpt2-124m.pretrain-1k's)")
+    ap.add_argument("--geometries",
+                    default="128x128x128,256x256x256,512x512x512,"
+                    "256x1024x512,512x1024x512,512x1024x256,512x1024x1024,"
+                    "1024x1024x512",
+                    help="block_q x block_k x sub_k, comma-separated")
+    ap.add_argument("--fwd-only", action="store_true",
+                    help="a serving shape: the forward alone")
+    ap.add_argument("--no-yardsticks", action="store_true")
     ap.add_argument("--dtype", default="bfloat16")
     ap.add_argument("--paged", action="store_true",
                     help="sweep the paged-attention decode kernel's page "
@@ -137,53 +232,56 @@ def main():
     if args.paged:
         run_paged(args)
         return
+    from ml_trainer_tpu.ops import attention as A
+
     b, h, s, d = (int(x) for x in args.shape.split(","))
-    blocks = [int(x) for x in args.blocks.split(",")]
     dtype = jnp.dtype(args.dtype)
     rng = np.random.default_rng(0)
-    q, k, v = (
+    q, k, v, g = (
         jnp.asarray(rng.normal(size=(b, h, s, d)) * 0.5, dtype)
-        for _ in range(3)
+        for _ in range(4)
     )
-
+    chosen, chosen_sub = A._flash_blocks(s, s, d, dtype), A._SUB_K
+    geometries = [("chosen", chosen, chosen_sub)] + [
+        (geo, (bq, bk), sub)
+        for geo in args.geometries.split(",")
+        for bq, bk, sub in [map(int, geo.split("x"))]
+        if s % bq == 0 and s % bk == 0 and bk % sub == 0
+    ]
+    names = {"flash_fwd": "fwd_ms", "flash_bwd_dq": "dq_ms",
+             "flash_bwd_dkv": "dkv_ms"}
     rows = []
-    for bq, bk in itertools.product(blocks, blocks):
-        if s % bq or s % bk:
-            continue
-
-        def fwd(q, k, v, _bq=bq, _bk=bk):
-            return flash_attention(q, k, v, None, True, None, _bq, _bk)
-
-        def loss(q, k, v, _bq=bq, _bk=bk):
-            return flash_attention(
-                q, k, v, None, True, None, _bq, _bk
-            ).sum().astype(jnp.float32)
-
-        grad = jax.jit(jax.grad(loss, argnums=(0, 1, 2)))
+    for geo, blocks, sub in geometries:
+        A._SUB_K = sub  # read when the call is traced
+        row = {"geometry": geo, "blocks": blocks, "sub_k": sub}
         try:
-            row = {
-                "block_q": bq, "block_k": bk,
-                "fwd_ms": round(bench(jax.jit(fwd), q, k, v) * 1e3, 3),
-                "fwd_bwd_ms": round(bench(grad, q, k, v) * 1e3, 3),
-            }
+            fwd, bwd = flash_fns(True, *blocks, args.fwd_only)
+            times = kernel_ms(fwd, (q, k, v))
+            if bwd is not None:
+                out, lse = fwd(q, k, v)
+                times.update(kernel_ms(bwd, (q, k, v, out, lse, g)))
+            row.update({col: round(times[name], 3)
+                        for name, col in names.items() if name in times})
         except Exception as e:  # geometry rejected by Mosaic (VMEM etc.)
-            row = {"block_q": bq, "block_k": bk,
-                   "error": str(e).splitlines()[0][:160]}
+            row["error"] = str(e).splitlines()[0][:160]
         rows.append(row)
         print(json.dumps(row), flush=True)
+    A._SUB_K = chosen_sub
 
-    timed = [r for r in rows if "fwd_bwd_ms" in r]
-    best = min(timed, key=lambda r: r["fwd_bwd_ms"]) if timed else None
     record = {
         "device": str(jax.devices()[0]),
-        "shape": [b, h, s, d], "dtype": str(dtype),
-        "rows": rows, "best": best,
-        "default": {"block_q": 128, "block_k": 128},
+        "shape": [b, h, s, d], "dtype": str(dtype), "causal": True,
+        "rows": rows, "chosen": chosen,
+        "yardsticks": [] if args.no_yardsticks else yardsticks(
+            q, k, v, args.fwd_only),
     }
-    out = os.path.join(ROOT, "docs", "flash_block_tune.json")
+    out_dir = os.path.join(ROOT, "chiprun_out")
+    os.makedirs(out_dir, exist_ok=True)
+    out = os.path.join(out_dir, "flash_tune.json")
+    history = json.load(open(out)) if os.path.exists(out) else []
     with open(out, "w") as fp:
-        json.dump(record, fp, indent=1)
-    print(f"-> {out} best={best}")
+        json.dump(history + [record], fp, indent=1)
+    print(f"-> {out}")
 
 
 if __name__ == "__main__":

@@ -49,11 +49,28 @@ def test_explicit_mask_matches_causal():
     )
 
 
+# (sequence, blocks, keys a sweep step takes or None for the module's own):
+# explicit blocks; blocks left to the chooser at the training cell's
+# sequence (one kv block of 1,024 swept in two sub-blocks under the causal
+# bound, and in eight); a sequence that only 128 divides.
+GEOMETRIES = [
+    pytest.param(256, (128, 128), None, id="s256-128x128"),
+    pytest.param(1024, (None, None), None, id="s1024-chosen"),
+    pytest.param(1024, (None, None), 128, id="s1024-chosen-sub128"),
+    pytest.param(384, (None, None), None, id="s384-chosen"),
+]
+
+
+@pytest.mark.parametrize("s,blocks,sub_k", GEOMETRIES)
 @pytest.mark.parametrize("causal", [False, True])
-def test_flash_matches_reference(causal):
-    q, k, v = qkv(b=1, h=2, s=256, d=64)
+def test_flash_matches_reference(causal, s, blocks, sub_k, monkeypatch):
+    from ml_trainer_tpu.ops import attention as A
+
+    if sub_k is not None:
+        monkeypatch.setattr(A, "_SUB_K", sub_k)
+    q, k, v = qkv(b=1, h=2, s=s, d=64)
     ref = dot_product_attention(q, k, v, causal=causal)
-    out = flash_attention(q, k, v, None, causal, None, 128, 128, True)  # interpret
+    out = flash_attention(q, k, v, None, causal, None, *blocks, True)  # interpret
     np.testing.assert_allclose(out, ref, atol=2e-3, rtol=2e-3)
 
 
@@ -128,16 +145,25 @@ def test_each_flash_kernel_carries_its_own_name():
     assert names(grad) == ["flash_fwd", "flash_bwd_dq", "flash_bwd_dkv"]
 
 
+@pytest.mark.parametrize("s,d,blocks", [
+    pytest.param(128, 32, (64, 32), id="s128-64x32"),
+    # Blocks of 128 and 256 at 512: under the causal mask both backward
+    # kernels meet dead blocks, whose index maps name a live one.
+    pytest.param(512, 64, (256, 128), id="s512-256x128"),
+    pytest.param(512, 64, (128, 256), id="s512-128x256"),
+    pytest.param(1024, 64, (None, None), id="s1024-chosen"),
+])
 @pytest.mark.parametrize("causal", [False, True])
-def test_flash_gradients_match_reference_uneven_blocks(causal):
+def test_flash_gradients_match_reference_uneven_blocks(causal, s, d, blocks):
     """Backward kernels with block_q != block_k and multiple blocks on both
     grid axes (dQ streams 4 kv blocks; dK/dV streams 2 q blocks)."""
-    q, k, v = qkv(b=2, h=2, s=128, d=32)
+    q, k, v = qkv(b=2 if s == 128 else 1, h=2, s=s, d=d)
     g = jnp.asarray(
         np.random.default_rng(7).normal(size=q.shape), jnp.float32
     )
     _, vjp_f = jax.vjp(
-        lambda q, k, v: flash_attention(q, k, v, None, causal, None, 64, 32, True),
+        lambda q, k, v: flash_attention(
+            q, k, v, None, causal, None, *blocks, True),
         q, k, v,
     )
     _, vjp_r = jax.vjp(
@@ -162,29 +188,40 @@ def test_flash_backward_preserves_dtype():
     assert all(gr.dtype == jnp.bfloat16 for gr in grads)
 
 
+@pytest.mark.parametrize("s,blocks", [
+    pytest.param(128, (64, 32), id="s128-64x32"),
+    pytest.param(512, (256, 128), id="s512-256x128"),
+    pytest.param(1024, (None, None), id="s1024-chosen"),
+])
 @pytest.mark.parametrize("causal", [False, True])
-def test_flash_kv_lens_matches_masked_reference(causal):
+def test_flash_kv_lens_matches_masked_reference(causal, s, blocks):
     """VERDICT r2 weak #7: the right-padded mask family (BERT's actual
     inference mode) runs INSIDE the flash kernel.  Values must match the
     XLA path under the equivalent boolean key mask."""
-    b, s = 3, 128
+    b = 3
     q, k, v = qkv(b=b, h=2, s=s, d=64, seed=3)
-    kv_lens = jnp.asarray([s, 70, 1], jnp.int32)  # full / padded / minimal
+    # full / padded (past the middle of a sub-block) / minimal
+    kv_lens = jnp.asarray([s, s // 2 + 6, 1], jnp.int32)
     mask = (jnp.arange(s)[None, None, None, :] < kv_lens[:, None, None, None])
     ref = dot_product_attention(q, k, v, causal=causal, mask=mask)
-    out = flash_attention(q, k, v, kv_lens, causal, None, 64, 32, True)
+    out = flash_attention(q, k, v, kv_lens, causal, None, *blocks, True)
     np.testing.assert_allclose(out, ref, atol=2e-3, rtol=2e-3)
 
 
-def test_flash_kv_lens_gradients_match_reference():
-    b, s = 2, 128
+@pytest.mark.parametrize("s,blocks", [
+    pytest.param(128, (64, 32), id="s128-64x32"),
+    pytest.param(512, (128, 256), id="s512-128x256"),
+    pytest.param(384, (None, None), id="s384-chosen"),
+])
+def test_flash_kv_lens_gradients_match_reference(s, blocks):
+    b = 2
     q, k, v = qkv(b=b, h=2, s=s, d=64, seed=4)
     kv_lens = jnp.asarray([s, 50], jnp.int32)
     mask = (jnp.arange(s)[None, None, None, :] < kv_lens[:, None, None, None])
 
     def loss_flash(q, k, v):
         return jnp.sum(
-            flash_attention(q, k, v, kv_lens, False, None, 64, 32, True) ** 2
+            flash_attention(q, k, v, kv_lens, False, None, *blocks, True) ** 2
         )
 
     def loss_ref(q, k, v):
@@ -232,20 +269,42 @@ def test_bert_right_padded_flag_equivalence():
     np.testing.assert_allclose(out_fast, out_exact, atol=1e-4, rtol=1e-4)
 
 
-def test_flash_bf16_matches_reference():
+@pytest.mark.parametrize("s,blocks,causal", [
+    pytest.param(128, (64, 64), True, id="s128-64x64"),
+    pytest.param(1024, (None, None), True, id="s1024-chosen-causal"),
+    pytest.param(1024, (None, None), False, id="s1024-chosen-full"),
+])
+def test_flash_bf16_matches_reference(s, blocks, causal):
     """The north-star configs run bf16 activations; the kernel must hold
-    its accuracy with bf16 inputs (f32 accumulation inside)."""
-    q, k, v = qkv(b=1, h=2, s=128, d=64, seed=6)
+    its accuracy with bf16 inputs: bfloat16 into the products, float32 out
+    of them and in every statistic, forward and backward."""
+    q, k, v = qkv(b=1, h=2, s=s, d=64, seed=6)
     qb, kb, vb = (t.astype(jnp.bfloat16) for t in (q, k, v))
-    ref = dot_product_attention(
-        qb.astype(jnp.float32), kb.astype(jnp.float32),
-        vb.astype(jnp.float32), causal=True,
-    )
-    out = flash_attention(qb, kb, vb, None, True, None, 64, 64, True)
+
+    def ref(q, k, v):
+        return dot_product_attention(
+            q.astype(jnp.float32), k.astype(jnp.float32),
+            v.astype(jnp.float32), causal=causal,
+        )
+
+    def flash(q, k, v):
+        return flash_attention(q, k, v, None, causal, None, *blocks, True)
+
+    out, vjp = jax.vjp(flash, qb, kb, vb)
+    want, vjp_ref = jax.vjp(ref, qb, kb, vb)
     assert out.dtype == jnp.bfloat16
     np.testing.assert_allclose(
-        out.astype(jnp.float32), ref, atol=2e-2, rtol=2e-2
+        out.astype(jnp.float32), want, atol=2e-2, rtol=2e-2
     )
+    g = jnp.asarray(np.random.default_rng(16).normal(size=q.shape))
+    for a, b, name in zip(vjp(g.astype(jnp.bfloat16)),
+                          vjp_ref(g.astype(jnp.bfloat16).astype(jnp.float32)),
+                          "qkv"):
+        assert a.dtype == jnp.bfloat16
+        scale = float(jnp.max(jnp.abs(b.astype(jnp.float32))))
+        np.testing.assert_allclose(
+            a.astype(jnp.float32) / scale, b.astype(jnp.float32) / scale,
+            atol=2e-2, err_msg=f"d{name}")
 
 
 def test_flash_inside_shard_map_matches_dense():
@@ -330,7 +389,7 @@ def test_flash_on_a_declared_mesh_runs_in_shard_map(shape, monkeypatch):
     def lower_for_tpu(declared):
         def compiled_kernel(q, k, v):
             with A.kernel_mesh(declared):
-                return A._flash_on_mesh(q, k, v, None, True, None, 64, 64)
+                return A._flash_on_mesh(q, k, v, None, True, None, None, None)
 
         return jax.jit(compiled_kernel).trace(*args).lower(
             lowering_platforms=("tpu",))
@@ -355,16 +414,22 @@ def test_flash_on_a_declared_mesh_runs_in_shard_map(shape, monkeypatch):
         assert bool(jax.jit(inner)(args[0]))
 
 
+@pytest.mark.parametrize("s,d,blocks", [
+    pytest.param(197, 48, (128, 128), id="s197-d48-128x128"),
+    # 1,100 pads to 1,152 = 9 x 128 and runs in blocks the chooser picks
+    # among ITS divisors (384 x 384 in float32).
+    pytest.param(1100, 64, (None, None), id="s1100-chosen"),
+])
 @pytest.mark.parametrize("causal", [False, True])
-def test_flash_padded_off_tile_shapes_match_reference(causal):
+def test_flash_padded_off_tile_shapes_match_reference(causal, s, d, blocks):
     """VERDICT r2 weak #7 (remaining half): off-tile shapes — a ViT-like
     sequence (197) and a head_dim that is not a multiple of 64 — run the
     kernel through the zero-padding wrapper with exact-math results."""
     from ml_trainer_tpu.ops.attention import _flash_padded
 
-    q, k, v = qkv(b=2, h=2, s=197, d=48, seed=8)
+    q, k, v = qkv(b=2 if s < 1024 else 1, h=2, s=s, d=d, seed=8)
     ref = dot_product_attention(q, k, v, causal=causal)
-    out = _flash_padded(q, k, v, None, causal, None, 128, 128, interpret=True)
+    out = _flash_padded(q, k, v, None, causal, None, *blocks, interpret=True)
     assert out.shape == q.shape
     np.testing.assert_allclose(out, ref, atol=2e-3, rtol=2e-3)
 
@@ -382,17 +447,21 @@ def test_flash_padded_respects_kv_lens():
     np.testing.assert_allclose(out, ref, atol=2e-3, rtol=2e-3)
 
 
-def test_flash_padded_gradients_match_reference():
+@pytest.mark.parametrize("s,d,blocks", [
+    pytest.param(77, 40, (64, 64), id="s77-d40-64x64"),
+    pytest.param(300, 64, (None, None), id="s300-chosen"),
+])
+def test_flash_padded_gradients_match_reference(s, d, blocks):
     """Padded query rows receive zero cotangent through the slice VJP and
     padded keys are masked, so gradients must equal the dense reference
     on the real region — and carry no NaNs from the padding."""
     from ml_trainer_tpu.ops.attention import _flash_padded
 
-    q, k, v = qkv(b=1, h=2, s=77, d=40, seed=10)
+    q, k, v = qkv(b=1, h=2, s=s, d=d, seed=10)
 
     def loss_flash(q, k, v):
         return jnp.sum(
-            _flash_padded(q, k, v, None, True, None, 64, 64,
+            _flash_padded(q, k, v, None, True, None, *blocks,
                           interpret=True) ** 2
         )
 
@@ -458,3 +527,46 @@ def test_flash_padded_head_dim_only_keeps_unmasked_variant():
         out, dot_product_attention(q, k, v, causal=True),
         atol=2e-3, rtol=2e-3,
     )
+
+
+def test_flash_blocks_are_chosen_from_the_shape():
+    """The chooser alone: multiples of 128 that divide the sequence, under
+    its caps (the kv block's in bytes, so heads of 128, or float32, get half
+    the keys), 128 for the 128 bucket; an explicit integer is honoured; the
+    padding wrapper pads to 128 and lets the kernel choose after."""
+    from unittest import mock
+
+    import ml_trainer_tpu.ops.attention as A
+
+    for s in (128, 256, 384, 512, 640, 1024, 1152, 2048, 8192):
+        for d, dtype in ((64, jnp.bfloat16), (128, jnp.bfloat16),
+                         (64, jnp.float32), (256, jnp.float32)):
+            bq, bk = A._flash_blocks(s, s, d, dtype)
+            assert bq % 128 == 0 and bk % 128 == 0, (s, d, bq, bk)
+            assert s % bq == 0 and s % bk == 0, (s, d, bq, bk)
+            assert bq <= A._BLOCK_Q
+            assert bk * d * jnp.dtype(dtype).itemsize <= max(
+                A._KV_BLOCK_BYTES, 128 * d * jnp.dtype(dtype).itemsize)
+            sub_k = A._sub_block(bk)
+            assert sub_k % 128 == 0 and sub_k <= A._SUB_K and bk % sub_k == 0
+    assert A._flash_blocks(128, 128, 64, jnp.bfloat16) == (128, 128)
+    assert A._flash_blocks(384, 384, 64, jnp.bfloat16) == (384, 384)
+    assert A._flash_blocks(640, 640, 64, jnp.bfloat16) == (128, 640)
+    wide = A._flash_blocks(1024, 1024, 64, jnp.bfloat16)
+    assert wide[1] == 2 * A._flash_blocks(
+        1024, 1024, 128, jnp.bfloat16)[1]              # bytes, not keys
+    q = jnp.zeros((1, 1, 1024, 64), jnp.bfloat16)
+    assert A._resolve_blocks(64, 32, q, q) == (64, 32)
+    assert A._resolve_blocks(None, 128, q, q) == (wide[0], 128)
+    # 'auto' hands the kernel what 128 divides, whatever the caps are.
+    q = jnp.zeros((1, 1, 1152, 64), jnp.bfloat16)
+    assert not A._off_tile(q, q) and A._off_tile(q[:, :, :1100], q)
+    assert A._off_tile(q, q, 512, 512)
+    q = jnp.zeros((1, 1, 1100, 64), jnp.float32)
+    with mock.patch.object(
+        A, "flash_attention", side_effect=lambda q, *a: q
+    ) as spy:
+        assert A._flash_padded(q, q, q, None, True, None, None, None).shape \
+            == q.shape
+    padded, *_, block_q, block_k, _ = spy.call_args[0]
+    assert padded.shape[2] == 1152 and (block_q, block_k) == (None, None)

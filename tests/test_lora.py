@@ -475,6 +475,10 @@ def test_router_adapter_affinity(model_and_vars, tmp_path):
         model, variables, roles=["both", "both"], max_batch=2,
         kv_page_size=PS,
         adapters=AdapterConfig(slots=3, rank=8, sources={"x": path}),
+        # Placement alone is under test: a hedge (a duplicate prefill on
+        # the OTHER replica when the first result is slow, as the first,
+        # compiling request is on a loaded host) is counted there too.
+        router_kwargs={"hedging": False},
     )
     try:
         p = _prompt(0, 2 * PS)

@@ -11,6 +11,7 @@ The topology is described inside a fixture, never at import: only the
 worker that is given this file loads the TPU's library.
 """
 
+import math
 import os
 import re
 
@@ -68,3 +69,45 @@ def test_slot_cache_write_leaves_the_donated_cache_in_place(
     assert " while(" not in text
     cache_bytes = 2 * b * h * L * d
     assert compiled.memory_analysis().temp_size_in_bytes < cache_bytes // 64
+
+
+def test_flash_kernels_compile_at_the_training_shape(one_chip):
+    """Forward and backward at ``gpt2-124m.pretrain-1k``'s attention, 32 x 12
+    heads of 64 over 1,024 positions in bfloat16 under the causal mask, with
+    the blocks left to the chooser and the operands in the order written
+    (what a kernel is handed inside the step): three Mosaic calls under
+    their three names, nothing copied round them, and the per-row
+    statistics dense (positions on the lanes), not a [.., S, 1] column that
+    the tiled layout pads 128-fold."""
+    from jax.experimental.layout import Format, Layout
+
+    from ml_trainer_tpu.ops.attention import flash_attention
+
+    b, h, s, d = 32, 12, 1024, 64
+    as_written = Format(Layout(major_to_minor=(0, 1, 2, 3)), one_chip)
+    q = jax.ShapeDtypeStruct((b, h, s, d), jnp.bfloat16, sharding=as_written)
+
+    def grads(q, k, v):
+        return jax.grad(
+            lambda *a: flash_attention(*a, None, True)
+            .astype(jnp.float32).sum(), argnums=(0, 1, 2))(q, k, v)
+
+    compiled = jax.jit(grads, out_shardings=(as_written,) * 3).lower(
+        q, q, q).compile()
+    text = compiled.as_text()
+    calls = re.findall(
+        r"%(\S+) = (.*?) custom-call\(.*custom_call_target=\"tpu_custom_call\"",
+        text)
+    assert len(calls) == 3, [name for name, _ in calls]
+    for kernel in ("flash_fwd", "flash_bwd_dq", "flash_bwd_dkv"):
+        assert sum(kernel in name for name, _ in calls) == 1, (kernel, calls)
+    assert not re.findall(r" copy(?:-start)?\(", text)
+    (fwd_result,) = [result for name, result in calls if "flash_fwd" in name]
+    lse = re.search(r"f32\[([\d,]+)\]\{[\d,]+:T\((\d+),128\)", fwd_result)
+    dims, sublanes = [int(n) for n in lse.group(1).split(",")], int(lse.group(2))
+    assert dims[-1] == 128 and math.prod(dims) == b * h * s, fwd_result
+    assert dims[-2] % sublanes == 0, fwd_result            # nothing padded
+    assert f"f32[{b * h},{s},1]" not in text
+    operand = 2 * b * h * s * d
+    # the output cotangent, `out`, and two rows of statistics a position
+    assert compiled.memory_analysis().temp_size_in_bytes < 2.1 * operand
