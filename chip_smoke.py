@@ -408,6 +408,30 @@ def _lower_train_step(trainer):
     )
 
 
+def mosaic_call_sites(lowered_text: str) -> int:
+    """Mosaic custom calls that ``main`` of a lowered module executes.  A
+    jitted kernel wrapper lowers to ONE private function that every layer
+    calls, so the text holds each kernel once however many layers run it:
+    follow the calls instead of counting the text."""
+    import re
+
+    bodies = re.split(r"func\.func (?:public |private )?@([\w.$-]+)\(",
+                      lowered_text)
+    funcs = dict(zip(bodies[1::2], bodies[2::2]))
+    totals: dict = {}
+
+    def total(name: str) -> int:
+        if name not in totals:
+            body = funcs.get(name, "")
+            totals[name] = body.count("tpu_custom_call") + sum(
+                total(callee)
+                for callee in re.findall(r"(?<!\w)call @([\w.$-]+)\(", body)
+            )
+        return totals[name]
+
+    return total("main")
+
+
 def phase_kernels(cfg: SmokeConfig, workdir: str) -> dict:
     facts = {"native_library": check_native_library()}
     _say(f"    native batch worker: {facts['native_library']}")
@@ -416,7 +440,7 @@ def phase_kernels(cfg: SmokeConfig, workdir: str) -> dict:
     # to the lax path unnoticed, so look at the lowered train step.
     trainer = _trainer(cfg, workdir, "lowering")
     facts["loader"] = type(trainer.train_loader).__name__
-    calls = _lower_train_step(trainer).as_text().count("tpu_custom_call")
+    calls = mosaic_call_sites(_lower_train_step(trainer).as_text())
     facts["train_step_custom_calls"] = calls
     if cfg.platform == "tpu":
         depth = trainer.model.depth

@@ -10,8 +10,8 @@ Two front ends over one findings/report/baseline surface:
   host-only modules, host syncs in hot loops, unused imports.
 
 ``scripts/graft_lint.py`` is the CLI; ``docs/graft_lint_baseline.json``
-the committed clean-tree artifact; ``scripts/bench_gate.py gate_lint``
-the hard gate on new findings.
+the committed clean-tree artifact; ``tests/test_analysis.py`` the hard
+gate on new findings.
 """
 
 from __future__ import annotations

@@ -223,10 +223,10 @@ def gpt2_moe_tiny(**kw) -> GPT2:
 def gpt2_mini(**kw) -> GPT2:
     """Mid-size GPT-2 (≈29M params): 4 layers, 512 wide, 8k vocab.
 
-    The speculative-decoding bench/serving demo target: large enough
-    that a decode forward is weight-streaming-bound — a K+1-token verify
+    The speculative-decoding serving demo target: large enough that a
+    decode forward is weight-streaming-bound — a K+1-token verify
     window costs ~2x a single-token step, not K+1x — which is the regime
-    where drafting pays (bench.py --spec)."""
+    where drafting pays."""
     kw.setdefault("vocab_size", 8192)
     kw.setdefault("embed_dim", 512)
     kw.setdefault("depth", 4)

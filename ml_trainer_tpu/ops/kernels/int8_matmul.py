@@ -17,7 +17,7 @@ math by construction.  The discipline here is:
   upcast x and the int8 weights to f32, run the full-K dot, and apply
   the column scales to the f32 product;
 * fp32 quality is gated end-to-end instead (argmax agreement >= 99.5%
-  and bounded logit error on the bench leg / smoke).
+  and bounded logit error, tests/test_kernels.py).
 
 Symmetric per-output-channel quantization: ``scale[n] =
 max(|w[:, n]|) / 127`` (all-zero columns get scale 1 so dequant is

@@ -106,7 +106,7 @@ def record_collective(op: str, n_bytes: float, calls: int = 1,
     """Accumulate ``n_bytes`` against ``op`` and mirror the running totals
     into the default registry (``comm_bytes_total{op=...}`` /
     ``comm_calls_total{op=...}`` gauges — gauges, not counters, because
-    ``reset_comm_stats`` legally zeroes them between bench legs).  With
+    ``reset_comm_stats`` legally zeroes them between runs).  With
     ``bucket`` set the bytes additionally land in the per-bucket
     breakdown (``comm_bucket_bytes_total{op=,bucket=}``) — the op totals
     always include bucketed traffic, so the breakdown is a view, not a
@@ -152,7 +152,7 @@ def record_hop(schedule: str, hop: str, n_bytes: float,
     ``grad_input_broadcast``) for ``schedule``, and mirror the running
     total into the registry as ``comm_hop_bytes_total{schedule=,hop=}``
     (a gauge, like the other comm mirrors, because ``reset_comm_stats``
-    legally zeroes it between bench legs).  The hop breakdown is a VIEW
+    legally zeroes it between runs).  The hop breakdown is a VIEW
     beside the per-op totals — pipeline call sites record the same
     bytes into both, so op totals already include hop traffic."""
     key = (str(schedule), str(hop))
@@ -267,8 +267,8 @@ def comm_delta(since: Dict[str, float]) -> Dict[str, float]:
 
 
 def reset_comm_stats() -> None:
-    """Zero the accumulators (and their registry mirrors) — bench legs and
-    the multichip dryrun reset between measurements."""
+    """Zero the accumulators (and their registry mirrors) — tests and
+    the multichip dryrun reset between runs."""
     with _lock:
         ops: Tuple[str, ...] = tuple(_bytes)
         buckets = tuple(_bucket_bytes)

@@ -261,8 +261,8 @@ class Server:
         ``quant_int8`` serves the decode step with int8-quantized
         qkv/proj/fc_in/fc_out weights + per-column scales
         (ops/kernels/int8_matmul.py; prefill stays fp32).  Opt-in and
-        quality-gated (argmax agreement vs fp32 on the bench leg), NOT
-        bit-identical to fp32; refused with ``spec_k > 0`` or
+        quality-gated (argmax agreement vs fp32, tests/test_kernels.py),
+        NOT bit-identical to fp32; refused with ``spec_k > 0`` or
         ``adapters``."""
         if role not in ("prefill", "decode", "both"):
             raise ValueError(

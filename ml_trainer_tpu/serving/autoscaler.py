@@ -1,9 +1,8 @@
 """SLO-burn autoscaler: the control loop that acts on the serving fleet.
 
 The serving stack can now SEE overload (serving/slo.py publishes
-attainment and burn rate; ``docs/serving_slo_cpu.json`` shows attainment
-collapsing 1.0 -> 0.33 past the knee) — this module is the loop that
-DOES something about it (ROADMAP items 2/3; the Gemma-on-TPU serving
+attainment and burn rate) — this module is the loop that DOES something
+about it (ROADMAP items 2/3; the Gemma-on-TPU serving
 paper's SLO/cost framing, PAPERS.md arXiv 2605.25645):
 
 * **Signals.**  Each poll reads the router's windowed request timelines
@@ -465,8 +464,8 @@ class Autoscaler:
     # -- reading ----------------------------------------------------------
 
     def summary(self) -> dict:
-        """The ``run_report``-style section the bench artifact embeds:
-        every action with its cause, plus per-action counts."""
+        """A ``run_report``-style section: every action with its cause,
+        plus per-action counts."""
         with self._lock:
             actions = [dict(a) for a in self.actions]
         counts: dict = {}

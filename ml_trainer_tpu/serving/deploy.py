@@ -660,9 +660,8 @@ class Deployment:
     # -- reporting --------------------------------------------------------
 
     def report(self) -> dict:
-        """JSON-safe rollout record (the bench artifact's deploy
-        section): verdict, traffic plan, fingerprints, burn, events,
-        shadow diff."""
+        """JSON-safe rollout record: verdict, traffic plan, fingerprints,
+        burn, events, shadow diff."""
         with self._lock:
             events = list(self.events)
         return {

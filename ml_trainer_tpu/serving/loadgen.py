@@ -201,14 +201,13 @@ def run_open_loop(schedule: Sequence[ScheduledRequest],
     ``url`` is the explicit TARGET — a single replica's front end or
     the disaggregated router's, interchangeably (POST
     ``{url}/v1/generate`` per request: the full production path — JSON
-    parse, admission/routing, engine, response), which is what lets
-    ``bench.py --slo``/``--serve-disagg`` drive both topologies with
-    the same recorded trace; ``server`` drives the in-process API
-    (tests).  Exactly one must be given.  A dispatcher thread sleeps to
-    each ABSOLUTE scheduled arrival and hands the request to its own
-    worker thread — completions never gate arrivals (no coordinated
-    omission), and the report's ``send_lag_ms`` records how faithfully
-    the schedule fired.  ``time_scale`` stretches (>1) or compresses
+    parse, admission/routing, engine, response), so both topologies
+    can be driven with the same recorded trace; ``server`` drives the
+    in-process API (tests).  Exactly one must be given.  A dispatcher
+    thread sleeps to each ABSOLUTE scheduled arrival and hands the
+    request to its own worker thread — completions never gate arrivals
+    (no coordinated omission), and the report's ``send_lag_ms`` records
+    how faithfully the schedule fired.  ``time_scale`` stretches (>1) or compresses
     (<1) the schedule's arrival offsets without touching its content.
     ``collect_tokens`` keeps each request's full output ids on its
     per-request row — the byte-identity evidence a topology comparison

@@ -3,9 +3,9 @@ circuit breakers, and the rolling latency clocks behind hedged prefills.
 
 A serving fleet that only knows how to be healthy has two failure modes
 under pressure, both bad: it either admits everything and lets every
-request's latency collapse together (the ``docs/serving_slo_cpu.json``
-knee — attainment 1.0 -> 0.33 with nothing pushing back), or it falls
-over entirely when a replica wedges.  This module is the middle ground
+request's latency collapse together (past the knee, with nothing
+pushing back), or it falls over entirely when a replica wedges.  This
+module is the middle ground
 (the Gemma-on-TPU serving paper's SLO/cost framing, PAPERS.md arXiv
 2605.25645; TorchTitan's fault-tolerance-as-a-composable-feature thesis
 applied to the serve side):

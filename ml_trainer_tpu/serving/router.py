@@ -16,8 +16,8 @@ analog of the mp_worker cluster harness):
   residency — then its KV migrates at page granularity
   (serving/transfer.py) to a decode replica that carries the stream to
   completion.  In COLOCATED mode (every replica ``both``) the same
-  router serves the same traffic with no migration, which is what makes
-  ``bench.py --serve-disagg`` an equal-replica-count comparison.
+  router serves the same traffic with no migration, so the two modes
+  compare at an equal replica count.
 
 * **Placement.**  Prefill placement is tenant-affinity-aware:
   consistent hashing (a vnode ring) on ``tenant + the prompt's first
@@ -1029,8 +1029,7 @@ class Router:
         }
 
     def snapshot(self) -> dict:
-        """Router metrics + health in one JSON-safe dict (the bench
-        artifact's router section)."""
+        """Router metrics + health in one JSON-safe dict."""
         snap = self.metrics.snapshot()
         snap["mode"] = self.mode
         snap["degradation"] = self.ladder.snapshot()

@@ -70,7 +70,6 @@ from ml_trainer_tpu.telemetry import compile_watch, goodput, memory
 from ml_trainer_tpu.telemetry.flops import (
     chip_hbm_capacity_bytes,
     chip_peak_flops,
-    chip_peak_hbm_bytes,
     train_step_flops,
 )
 from ml_trainer_tpu.telemetry.goodput import GoodputMeter
@@ -120,7 +119,6 @@ __all__ = [
     "get_recorder",
     "FLIGHT_DIR_ENV",
     "chip_peak_flops",
-    "chip_peak_hbm_bytes",
     "chip_hbm_capacity_bytes",
     "train_step_flops",
     "compile_watch",

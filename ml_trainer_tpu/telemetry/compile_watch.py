@@ -5,8 +5,8 @@ shape leaked into a traced argument, a Python float toggled weak_type,
 a cache key drifted — and the symptom (a multi-second stall every N
 steps) points nowhere near the cause.  The repo used to pin "no silent
 recompiles" through ad-hoc ``jit._cache_size() == 1`` asserts scattered
-across tests and smoke scripts; this module replaces those with one
-real instrument on JAX's own compilation path:
+across tests; this module replaces those with one real instrument on
+JAX's own compilation path:
 
 * every backend compile is recorded as a :class:`CompileEvent` —
   function name, elapsed ms, timestamp — and counted in the registry as

@@ -2,9 +2,8 @@
 
 One place owns the numbers two kinds of math previously duplicated:
 
-* **chip peaks** — published per-chip bf16 FLOP/s and HBM bandwidth by
-  TPU generation (previously private to ``bench.py`` /
-  ``scripts/mfu_ledger.py``);
+* **chip peaks** — published per-chip FLOP/s by compute dtype and HBM
+  capacity by TPU generation;
 * **per-step FLOPs** — analytical training-step FLOPs for every
   north-star family (mlmodel / resnet / vit / bert / gpt2 / llama),
   computed from the registry configs' module attributes, so an MFU
@@ -23,7 +22,7 @@ Conventions (documented in docs/observability.md):
 
 These are ESTIMATES for MFU lines and dashboards.  Where a compiled
 executable is at hand, XLA's measured ``cost_analysis()`` stays the
-source of truth (``bench.py`` prefers it and falls back here).
+source of truth.
 """
 
 from __future__ import annotations
@@ -67,14 +66,8 @@ _DTYPE_ALIASES = {
 }
 # Back-compat alias (pre-dtype-keyed callers read the bf16 table).
 PEAK_FLOPS = PEAK_FLOPS_BY_DTYPE["bf16"]
-PEAK_HBM_BYTES = {
-    "v6e": 1640e9, "v6": 1640e9,
-    "v5p": 2765e9,
-    "v5e": 819e9, "v5 lite": 819e9, "v5lite": 819e9,
-    "v4": 1228e9,
-}
 # HBM *capacity* per chip (bytes) — the denominator of the fit-or-OOM
-# planner (telemetry/memory.py), next to the bandwidth table above.
+# planner (telemetry/memory.py).
 HBM_CAPACITY_BYTES = {
     "v6e": 32 * 2 ** 30, "v6": 32 * 2 ** 30,
     "v5p": 95 * 2 ** 30,
@@ -120,11 +113,6 @@ def chip_peak_flops(dtype: str = "bf16",
             f"{sorted(_DTYPE_ALIASES)}"
         )
     return PEAK_FLOPS_BY_DTYPE[key][chip_generation(generation)]
-
-
-def chip_peak_hbm_bytes(generation: Optional[str] = None) -> float:
-    """Peak HBM bytes/s of one chip."""
-    return PEAK_HBM_BYTES[chip_generation(generation)]
 
 
 def chip_hbm_capacity_bytes(generation: Optional[str] = None) -> float:

@@ -18,14 +18,15 @@ than trial-and-error.  This module owns that budget:
   input batch with its prefetch depth;
 * **formula walk** — :func:`plan_train_memory` computes the same ledger
   from a config alone (``jax.eval_shape`` of model + optimizer init, no
-  state built), so ``bench.py --memplan`` can predict peak HBM for a
-  topology this host does not have, judged against the chip capacity
-  table ``telemetry/flops.py`` owns;
+  state built), so peak HBM can be predicted for a topology this host
+  does not have, judged against the chip capacity table
+  ``telemetry/flops.py`` owns;
 * **live cross-check** — :func:`live_memory_snapshot` reads per-device
   ``memory_stats()`` on TPU and falls back to live-array nbytes
   accounting on CPU; :func:`measured_tree_bytes` measures what a state
   tree actually holds per device, and :func:`cross_check` pins the
-  analytic walk against it (the smoke legs enforce 10% agreement);
+  analytic walk against it (tests/test_memory_goodput.py enforces 10%
+  agreement);
 * **exposition** — ``MemoryLedger.publish()`` emits
   ``mem_analytic_bytes{component=}`` gauges,
   :func:`publish_live_memory` emits ``mem_live_bytes{device=}`` /
@@ -510,8 +511,8 @@ def plan_train_memory(
     batch_dtype=None,
 ) -> MemoryLedger:
     """Formula-driven per-device ledger — no state built, no device
-    memory touched (``jax.eval_shape`` only), so ``bench.py --memplan``
-    can price a config BEFORE trying to allocate it.
+    memory touched (``jax.eval_shape`` only), so a config can be priced
+    BEFORE trying to allocate it.
 
     Division rules mirror the Trainer's placement exactly: params
     replicate over data axes and divide per ``sharding_rules`` on model
@@ -666,33 +667,6 @@ def activation_bytes(model, batch_shape, data_parallel: int = 1,
     dtype = getattr(model, "dtype", jnp.float32)
     itemsize = jnp.dtype(dtype).itemsize
     return float(b_local) * seq * d * depth * 12 * itemsize
-
-
-def bench_step_ledger(state, model, batch) -> MemoryLedger:
-    """Ledger for a bare bench train step (bench.py model rows): the
-    state tree as resident, fp32 grads + the chunked-LM-head peak as
-    transients, plus the one on-device batch."""
-    comps = [
-        Component("state", tree_device_bytes(state), "resident"),
-        Component(
-            "grads",
-            sum(
-                _leaf_device_bytes(l) / jnp.dtype(l.dtype).itemsize * 4
-                for l in jax.tree.leaves(state.params)
-            ),
-            "transient", {"dtype": "float32"},
-        ),
-    ]
-    batch_bytes = sum(
-        float(getattr(a, "nbytes", 0)) for a in jax.tree.leaves(batch)
-    )
-    if batch_bytes:
-        comps.append(Component("batch", batch_bytes, "resident"))
-        x = jax.tree.leaves(batch)[0]
-        lc = _loss_chunk_component(model, getattr(x, "shape", ()), 1)
-        if lc is not None:
-            comps.append(lc)
-    return MemoryLedger(comps)
 
 
 # ------------------------------------------------------------ serving KV

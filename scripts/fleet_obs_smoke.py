@@ -1,5 +1,5 @@
 #!/usr/bin/env python
-"""Fleet observability-plane smoke leg (scripts/fastlane.sh) — the
+"""Fleet observability-plane smoke — the
 PR 19 tentpole end to end, with REAL OS processes (serving/fleet.py +
 the router's fleet plane in serving/router.py):
 

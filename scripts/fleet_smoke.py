@@ -1,5 +1,5 @@
 #!/usr/bin/env python
-"""Multi-process serving-fleet smoke leg (scripts/fastlane.sh) — the
+"""Multi-process serving-fleet smoke — the
 PR 16 tentpole end to end, with REAL OS processes (serving/fleet.py):
 
 1. A 4-process fleet (2 prefill + 2 decode), every replica its own

@@ -2,38 +2,24 @@
 """Perf-regression attribution: diff two perf artifacts and rank what
 changed, attributed through the existing ledgers.
 
-Any two JSON artifacts the repo emits are diffable — a ``bench.py``
-artifact (``docs/*_cpu.json``), a ``run_report.json``, a Watchtower
-TSDB dump (``TimeSeriesStore.save()``), or a fastlane timings file —
-because everything reduces to numeric leaves under dotted keys.  The
-output is a ranked "what changed" table, each row attributed to the
+Two ``run_report.json`` files or two Watchtower TSDB dumps
+(``TimeSeriesStore.save()``) are diffable (as is any pair of JSON
+files) because everything reduces to numeric leaves under dotted keys.
+The output is a ranked "what changed" table, each row attributed to the
 ledger family its key belongs to (goodput buckets, comm bytes, compile
-counts, step-ms percentiles, kv/adapter pool pressure, ...), so a
-ratchet failure in ``bench_gate.py`` prints WHERE the regression lives
-rather than just that one scalar moved::
+counts, step-ms percentiles, kv/adapter pool pressure, ...)::
 
-    python scripts/perf_diff.py docs/serving_cpu.json /tmp/serving_now.json
     python scripts/perf_diff.py old_report.json new_report.json --top 15
 
-``record`` is the fastlane timing helper (one call per leg in
-``scripts/fastlane.sh``; the resulting ``docs/fastlane_timings.json``
-files are themselves diffable)::
-
-    python scripts/perf_diff.py record --file docs/fastlane_timings.json \
-        --leg serving --seconds 41.2
-
-Stdlib-only, host-only — importable from ``bench_gate.py`` without
-touching jax.
+Stdlib-only, host-only.
 """
 
 from __future__ import annotations
 
 import argparse
 import json
-import os
 import re
 import sys
-import time
 from typing import Dict, List, Optional, Tuple
 
 # Attribution: first matching pattern names the ledger family a key
@@ -180,8 +166,7 @@ def _fmt(v: Optional[float]) -> str:
 
 
 def format_table(rows: List[dict], top: int = 20) -> str:
-    """The ranked attribution table plus a per-ledger rollup — what
-    ``bench_gate.py`` prints under a failed ratchet."""
+    """The ranked attribution table plus a per-ledger rollup."""
     if not rows:
         return "no numeric leaves changed"
     shown = rows[:top]
@@ -222,57 +207,8 @@ def format_table(rows: List[dict], top: int = 20) -> str:
     return "\n".join(lines)
 
 
-# -- fastlane timing recorder ---------------------------------------------
-
-
-def record_timing(path: str, leg: str, seconds: float,
-                  rc: Optional[int] = None) -> dict:
-    """Upsert one leg's wall-clock into a timings file (atomic; the file
-    itself is a diffable artifact: ``perf_diff.py old new`` attributes
-    fastlane slowdowns per leg)."""
-    try:
-        with open(path, encoding="utf-8") as fp:
-            payload = json.load(fp)
-    except (OSError, json.JSONDecodeError):
-        payload = {"version": 1, "legs": {}}
-    entry = {"seconds": round(float(seconds), 3),
-             "recorded_at": round(time.time(), 3)}
-    if rc is not None:
-        entry["rc"] = int(rc)
-    payload.setdefault("legs", {})[leg] = entry
-    payload["total_seconds"] = round(
-        sum(v.get("seconds", 0.0) for v in payload["legs"].values()), 3
-    )
-    d = os.path.dirname(os.path.abspath(path))
-    os.makedirs(d, exist_ok=True)
-    tmp = path + ".tmp"
-    with open(tmp, "w", encoding="utf-8") as fp:
-        json.dump(payload, fp, indent=1, sort_keys=True)
-        fp.write("\n")
-    os.replace(tmp, path)
-    return payload
-
-
 def main(argv: Optional[List[str]] = None) -> int:
     argv = list(sys.argv[1:] if argv is None else argv)
-    if argv and argv[0] == "record":
-        ap = argparse.ArgumentParser(
-            prog="perf_diff.py record",
-            description="record one fastlane leg's wall-clock",
-        )
-        ap.add_argument("--file", required=True)
-        ap.add_argument("--leg", required=True)
-        ap.add_argument("--seconds", type=float, required=True)
-        ap.add_argument("--rc", type=int, default=None)
-        args = ap.parse_args(argv[1:])
-        payload = record_timing(
-            args.file, args.leg, args.seconds, rc=args.rc
-        )
-        print(
-            f"recorded {args.leg}={args.seconds:.1f}s "
-            f"(total {payload['total_seconds']:.1f}s) -> {args.file}"
-        )
-        return 0
     ap = argparse.ArgumentParser(
         description="diff two perf artifacts and attribute what changed",
     )

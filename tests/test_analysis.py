@@ -29,13 +29,14 @@ from ml_trainer_tpu.analysis import (
     check_dtype_policy,
     check_program,
     check_traceable,
+    default_baseline_path,
     diff_against_baseline,
     modules_from_sources,
     run_ast_checks,
     scan_tree,
 )
 from ml_trainer_tpu.analysis import ast_checks, jaxpr_checks
-from ml_trainer_tpu.analysis.findings import Finding
+from ml_trainer_tpu.analysis.findings import Finding, load_baseline
 
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
@@ -423,6 +424,11 @@ class TestRealTreeClean:
         assert len(modules) > 80  # the real tree, not an empty walk
         report = run_ast_checks(modules)
         assert report == [], Report(report).render()
+        # The committed baseline is what this fresh run gives (the real
+        # programs' half of it is TestRealProgramsClean's zero findings):
+        # accepting a finding is a deliberate diff of that file.
+        committed = load_baseline(default_baseline_path())
+        assert committed == baseline_payload(Report(report))
 
     def test_fixed_modules_stay_import_clean(self):
         # Regression for the unused-import sweep this PR landed
@@ -537,10 +543,7 @@ class TestFlightContext:
     def test_baseline_fingerprint_rides_flight_dumps(self, tmp_path):
         import json
 
-        from ml_trainer_tpu.analysis import (
-            default_baseline_path,
-            register_flight_context,
-        )
+        from ml_trainer_tpu.analysis import register_flight_context
         from ml_trainer_tpu.telemetry.flight import FlightRecorder
 
         rec = FlightRecorder(capacity=4, default_dir=str(tmp_path))
@@ -549,7 +552,7 @@ class TestFlightContext:
         path = rec.dump("test")
         payload = json.load(open(path))
         ctx = payload["context"]["lint_baseline"]
-        committed = json.load(open(default_baseline_path()))
+        committed = load_baseline(default_baseline_path())
         assert ctx["present"] is True
         assert ctx["fingerprint"] == committed["fingerprint"]
 

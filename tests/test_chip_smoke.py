@@ -32,6 +32,31 @@ def test_phases_pass_at_tiny_width(tmp_path, capsys):
     assert "compiles after warm-up: 0" in out
 
 
+def test_mosaic_call_sites_follows_calls_not_text():
+    """A jitted kernel wrapper is one function in the lowered text, called
+    by every layer: the count the kernels phase holds to the model's depth
+    is of executed calls."""
+    text = """
+module @jit_step {
+  func.func public @main(%arg0: tensor<4xf32>) -> tensor<4xf32> {
+    %0 = call @layer(%arg0) : (tensor<4xf32>) -> tensor<4xf32>
+    %1 = call @layer(%0) : (tensor<4xf32>) -> tensor<4xf32>
+    %2 = stablehlo.custom_call @tpu_custom_call(%1) : (tensor<4xf32>)
+    return %2 : tensor<4xf32>
+  }
+  func.func private @layer(%arg0: tensor<4xf32>) -> tensor<4xf32> {
+    %0 = call @_flash_forward(%arg0) : (tensor<4xf32>) -> tensor<4xf32>
+    return %0 : tensor<4xf32>
+  }
+  func.func private @_flash_forward(%arg0: tensor<4xf32>) -> tensor<4xf32> {
+    %0 = stablehlo.custom_call @tpu_custom_call(%arg0) : (tensor<4xf32>)
+    return %0 : tensor<4xf32>
+  }
+}"""
+    assert text.count("tpu_custom_call") == 2
+    assert chip_smoke.mosaic_call_sites(text) == 3
+
+
 def test_non_tpu_platform_is_a_nonzero_exit(tmp_path, capsys):
     on_chip = dataclasses.replace(TINY, platform="tpu")
     for phase in chip_smoke.PHASES:

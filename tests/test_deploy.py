@@ -213,7 +213,10 @@ def test_deploy_ramps_and_promotes(model_and_two_weights):
     """Healthy rollout: staging spawns a full new generation, traffic
     walks canary -> 100%, the new generation is promoted and the old
     one retires — and the fleet then serves the new weights
-    byte-identically to generate() on them."""
+    byte-identically to generate() on them, all on the programs the
+    stable fleet had already compiled."""
+    from ml_trainer_tpu.telemetry import compile_watch
+
     model, v0, v1 = model_and_two_weights
     p = _prompt(3, 8)
     ref1 = np.asarray(generate(model, v1, p[None], 6))[0]
@@ -221,26 +224,29 @@ def test_deploy_ramps_and_promotes(model_and_two_weights):
                        min_window_requests=1, drain_timeout_s=30.0)
     with _deploy_router(model, v0) as router:
         router.complete(p, 4, timeout=180)  # warm the stable fleet
-        dep = Deployment(router, "ckpt-v1",
-                         _server_factory(model, v1), config=cfg)
-        assert dep.tick() == "canary"
-        assert router._deploy_generation == 1
-        assert router._deploy_fraction == pytest.approx(0.25)
-        assert len(dep.new_replicas) == 2  # mirrors the stable role mix
-        assert dep.weights_fp != dep.old_weights_fp
-        for t in _tenants(0.25, True, n=2):
-            router.complete(p, 6, timeout=180, tenant=t)
-        time.sleep(cfg.hold_s + 0.01)
-        assert dep.tick() == "ramping"
-        assert router._deploy_fraction == pytest.approx(1.0)
-        time.sleep(cfg.hold_s + 0.01)
-        assert dep.tick() == "done"
-        # Promoted: default traffic serves the new weights...
-        assert router._serving_generation == 1
-        assert router._deploy_generation is None
-        np.testing.assert_array_equal(
-            router.complete(p, 6, timeout=180), ref1
-        )
+        # The new generation shares the warm fleet's compiled programs
+        # (weights are arguments): a rollout compiles nothing.
+        with compile_watch.expect_no_compiles("deploy on a warm fleet"):
+            dep = Deployment(router, "ckpt-v1",
+                             _server_factory(model, v1), config=cfg)
+            assert dep.tick() == "canary"
+            assert router._deploy_generation == 1
+            assert router._deploy_fraction == pytest.approx(0.25)
+            assert len(dep.new_replicas) == 2  # mirrors the stable role mix
+            assert dep.weights_fp != dep.old_weights_fp
+            for t in _tenants(0.25, True, n=2):
+                router.complete(p, 6, timeout=180, tenant=t)
+            time.sleep(cfg.hold_s + 0.01)
+            assert dep.tick() == "ramping"
+            assert router._deploy_fraction == pytest.approx(1.0)
+            time.sleep(cfg.hold_s + 0.01)
+            assert dep.tick() == "done"
+            # Promoted: default traffic serves the new weights...
+            assert router._serving_generation == 1
+            assert router._deploy_generation is None
+            np.testing.assert_array_equal(
+                router.complete(p, 6, timeout=180), ref1
+            )
         # ...and the old generation is fully retired.
         assert set(router.replicas) == set(dep.new_replicas)
         actions = [e["action"] for e in dep.events]

@@ -7,13 +7,12 @@ KV cache crosses a process boundary as serialized bytes over
 ``POST /v1/adopt``, must still reproduce the standalone batch-1
 ``generate()`` output byte-for-byte — greedy and seeded-sampling
 alike.  The full 4-process fleet (spawned workers, real SIGKILL,
-autoscaler respawn) lives in scripts/fleet_smoke.py and the bench
-gate's gate_fleet; these tests pin the underlying mechanics with
-in-process servers (the socket tests still cross a real HTTP socket —
-the servers just live in this process behind ``serve_http``).
+autoscaler respawn) lives in scripts/fleet_smoke.py; these tests pin
+the underlying mechanics with in-process servers (the socket tests
+still cross a real HTTP socket — the servers just live in this process
+behind ``serve_http``).
 """
 
-import importlib.util
 import os
 
 import jax
@@ -24,8 +23,6 @@ from ml_trainer_tpu.generate import generate
 from ml_trainer_tpu.models import get_model
 from ml_trainer_tpu.serving import Router, Server
 from ml_trainer_tpu.serving.fleet import RemoteServer
-
-REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
 
 @pytest.fixture(scope="module")
@@ -197,47 +194,3 @@ def test_socket_adopt_round_trip_bit_exact(model_and_vars):
             router.close()
         for srv in servers.values():
             srv.close()
-
-
-# -- changed-only gate-leg mapping ---------------------------------------
-
-def _load_bench_gate():
-    spec = importlib.util.spec_from_file_location(
-        "bench_gate", os.path.join(REPO, "scripts", "bench_gate.py")
-    )
-    mod = importlib.util.module_from_spec(spec)
-    spec.loader.exec_module(mod)
-    return mod
-
-
-def test_changed_only_leg_mapping():
-    """`bench_gate.py --changed-only` must select a strict subset on a
-    docs-only diff and every leg on a serving diff — the mapping is a
-    CI contract (a miss silently skips a gate)."""
-    bg = _load_bench_gate()
-    assert bg.legs_for_changes(
-        ["docs/serving.md", "README.md", "tests/test_fleet.py"]
-    ) == set()
-    assert bg.legs_for_changes(["docs/serving_fleet_cpu.json"]) == {
-        "fleet"
-    }
-    assert bg.legs_for_changes(
-        ["ml_trainer_tpu/serving/router.py"]
-    ) == set(bg.ALL_LEGS)
-    assert bg.legs_for_changes(
-        ["ml_trainer_tpu/resilience/faults.py"]
-    ) == {"elastic", "overload", "fleet"}
-    # The observability spine rides the legs that read it — the SLO
-    # plane, the fleet gate (which pins the federation/trace/bundle
-    # invariants), the rollout gate's SLO-burn rollback, and the
-    # watchtower gate (TSDB/alerts overhead + detection).
-    assert bg.legs_for_changes(
-        ["ml_trainer_tpu/telemetry/federation.py"]
-    ) == {"slo", "fleet", "deploy", "watchtower"}
-    assert bg.legs_for_changes(["docs/watchtower_cpu.json"]) == {
-        "watchtower"
-    }
-    assert bg.legs_for_changes(["docs/fleet_obs_cpu.json"]) == {"fleet"}
-    # Unmapped file or unknown diff -> run everything (fail safe).
-    assert bg.legs_for_changes(["setup.py"]) == set(bg.ALL_LEGS)
-    assert bg.legs_for_changes(None) == set(bg.ALL_LEGS)

@@ -6,8 +6,7 @@ multi-pid payloads (fast, no processes); the router-side plumbing —
 scrape, re-label, aggregate ``/healthz``, trace context over the wire,
 incident bundles — is pinned with in-process servers behind REAL HTTP
 sockets (the test_fleet.py idiom: the socket is real, the processes are
-not).  The true multi-process run lives in scripts/fleet_obs_smoke.py
-and the bench gate's gate_fleet observability invariants.
+not).  The true multi-process run lives in scripts/fleet_obs_smoke.py.
 """
 
 import json
@@ -268,7 +267,7 @@ def test_federated_scrape_labels_and_idempotency(socket_fleet):
         # text and grow as the router publishes between renders; filter
         # them to the worker-owned families (a real fleet worker has
         # its own process registry — the multi-process idempotency is
-        # pinned by scripts/fleet_obs_smoke.py and gate_fleet).
+        # pinned by scripts/fleet_obs_smoke.py).
         return [ln for ln in text.splitlines()
                 if ln and not ln.startswith(("#", "router_"))
                 and 'replica="' in ln]

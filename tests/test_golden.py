@@ -5,7 +5,7 @@ loss/accuracy + throughput lines) act as its golden-run record.  Ours is
 captured by ``GOLDEN_OUT=... python examples/01_local_training.py``
 (synthetic CIFAR-10, the zero-egress stand-in): canonically
 ``tests/golden/local_run_tpu.json`` from the real chip, with
-``local_run_cpu.json`` as the stand-in record until a chip run captures
+``local_cpu_run.json`` as the stand-in record until a chip run captures
 one (the record notes its ``backend``).  This test re-runs the exact
 same configuration on the CPU test mesh and asserts the trajectory still
 lands where the committed record says, within tolerances generous enough
@@ -28,7 +28,7 @@ _GOLDEN_DIR = os.path.join(os.path.dirname(__file__), "golden")
 # regression net ACTIVE rather than skipped.
 _CANDIDATES = [
     os.path.join(_GOLDEN_DIR, "local_run_tpu.json"),
-    os.path.join(_GOLDEN_DIR, "local_run_cpu.json"),
+    os.path.join(_GOLDEN_DIR, "local_cpu_run.json"),
 ]
 GOLDEN = next((p for p in _CANDIDATES if os.path.exists(p)), _CANDIDATES[0])
 

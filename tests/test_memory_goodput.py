@@ -154,6 +154,7 @@ def test_ledger_publish_and_live_snapshot():
     led.publish(registry=r)
     snap = r.snapshot()
     assert snap["mem_analytic_bytes{component=params}"] == 1000
+    assert snap["mem_analytic_resident_bytes"] == 1000
     assert snap["mem_analytic_peak_bytes"] == 1500
     anchor = jnp.ones((1024,), jnp.float32)  # guarantee a live buffer
     anchor.block_until_ready()
@@ -230,12 +231,15 @@ def test_compile_counter_fresh_vs_steady(tmp_path):
     compile_watch.install()
     before = compile_watch.compile_count("jit(train_step)")
     pw_before = compile_watch.post_warmup_count()
+    compile_secs_before = goodput.snapshot()["compile"]
     t = _image_trainer(tmp_path / "cw", epochs=2, telemetry=True)
     t.fit()
     assert compile_watch.compile_count("jit(train_step)") == before + 1, (
         compile_watch.counts_by_fn()
     )
     assert compile_watch.post_warmup_count() == pw_before
+    # The fresh compile was charged to the goodput ledger's bucket.
+    assert goodput.snapshot()["compile"] > compile_secs_before
     # The labeled counter reached the registry.
     from ml_trainer_tpu.telemetry import default_registry
 

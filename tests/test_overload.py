@@ -465,6 +465,8 @@ def test_autoscaler_replaces_dead_replica(model_and_vars):
     """Repair rule: a replica death drops the decode fleet below its
     floor — the next tick adds a replacement (no hysteresis wait), and
     the fleet serves again."""
+    from ml_trainer_tpu.telemetry.registry import MetricsRegistry
+
     model, variables = model_and_vars
     p = _prompt(18, 8)
     ref = np.asarray(generate(model, variables, p[None], 8))[0]
@@ -484,9 +486,14 @@ def test_autoscaler_replaces_dead_replica(model_and_vars):
         assert router.replica("auto1").role == "decode"
         out = np.asarray(router.complete(p, 8, timeout=180))
         summary = asc.summary()
+        reg = MetricsRegistry()
+        asc.publish(reg)
+        text = reg.prometheus_text()
     np.testing.assert_array_equal(out, ref)
     assert summary["counts"]["scale_up"] == 1
     assert summary["actions"][0]["cause"].startswith("decode fleet")
+    assert 'autoscaler_actions_total{action="scale_up"} 1' in text
+    assert "autoscaler_replicas{" in text
 
 
 def test_autoscaler_hysteresis_cooldown_and_ladder(model_and_vars):

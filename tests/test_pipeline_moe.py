@@ -18,9 +18,11 @@ from ml_trainer_tpu.parallel import (
     stack_stage_params,
 )
 
-# Integration layer: multi-epoch fits / trajectory equality / compiled
-# programs — the CI fast lane is `-m 'not slow'` (see pyproject.toml).
-pytestmark = pytest.mark.slow
+# The schedule engine's own invariants (serial-fold equality of value and
+# gradient, one program a schedule, the tick-table accounting, one tiny
+# pipelined fit) run in the fast lane, `-m 'not slow'`; the multi-epoch
+# fits at full mesh size and the MoE layer are marked slow one by one.
+slow = pytest.mark.slow
 
 
 # ----------------------------------------------------------------- pipeline
@@ -45,6 +47,7 @@ def _serial(stages, x):
     return x
 
 
+@slow
 @pytest.mark.parametrize("n_micro", [4, 8])
 def test_pipeline_matches_serial(n_micro):
     mesh = create_mesh({"stage": 4}, devices=jax.devices()[:4])
@@ -59,6 +62,7 @@ def test_pipeline_matches_serial(n_micro):
     np.testing.assert_allclose(out, _serial(stages, x), atol=1e-5, rtol=1e-5)
 
 
+@slow
 def test_pipeline_under_jit_and_grad():
     """The schedule is one lax.scan: jit-able and reverse-differentiable —
     gradients equal the serial composition's."""
@@ -80,6 +84,7 @@ def test_pipeline_under_jit_and_grad():
         np.testing.assert_allclose(np.asarray(a), np.asarray(b), atol=1e-4)
 
 
+@slow
 def test_pipeline_remat_matches_stored_activations():
     """remat=True recomputes stage bodies in the backward — identical
     values AND gradients to the stored-activation schedule."""
@@ -269,33 +274,55 @@ def test_pipeline_1f1b_bubble_and_comm_accounting():
     )
 
 
-def test_pipeline_1f1b_trains_dp_x_pp(tmp_path):
-    """dp x pp composition under the tick-table engine: gpt2_pipe_tiny
-    with pipeline_schedule='1f1b' on a {data:2, stage:4} mesh matches
-    the serial-fold trajectory (the engine's hand-written backward must
-    psum stage grads across data replicas itself — the regression this
-    test pins)."""
+@pytest.mark.parametrize(
+    "mesh_shape,schedule,n_virtual",
+    [
+        pytest.param({"data": 2, "stage": 4}, "1f1b", 1, marks=slow,
+                     id="dp2xpp4-1f1b"),
+        pytest.param({"data": 4, "stage": 2}, "1f1b", 1, id="dp4xpp2-1f1b"),
+        pytest.param({"data": 4, "stage": 2}, "interleaved", 2,
+                     id="dp4xpp2-interleaved"),
+    ],
+)
+def test_pipeline_trains_through_the_trainer(tmp_path, mesh_shape, schedule,
+                                             n_virtual):
+    """The tick-table engine under the Trainer: gpt2_pipe_tiny on a
+    data x stage mesh (the engine's hand-written backward must psum stage
+    grads across data replicas itself) matches the serial-fold trajectory
+    of the SAME module on one device, compiles one train step and nothing
+    after the first epoch, attributes its bytes hop by hop, and publishes
+    the schedule's bubble."""
+    from ml_trainer_tpu.parallel import pipeline_schedule_info
+    from ml_trainer_tpu.parallel.comm_stats import (
+        comm_hop_bytes,
+        reset_comm_stats,
+    )
+    from ml_trainer_tpu.telemetry import compile_watch, default_registry
+
     ds = SyntheticTokens(size=32, seq_len=32, vocab_size=256, seed=0)
     common = dict(
-        epochs=2, batch_size=8, seed=3, lr=0.01, optimizer="adamw",
-        metric=None,
+        epochs=2, batch_size=4 * mesh_shape["data"], seed=3, lr=0.01,
+        optimizer="adamw", metric=None,
     )
+    n_stages = mesh_shape["stage"] * n_virtual
     t_serial = Trainer(
-        get_model("gpt2_pipe_tiny"), datasets=(ds, ds),
+        get_model("gpt2_pipe_tiny", n_stages=n_stages), datasets=(ds, ds),
         model_dir=str(tmp_path / "serial"), **common,
     )
     t_serial.fit()
-    mesh = create_mesh({"data": 2, "stage": 4})
+    mesh = create_mesh(mesh_shape)
+    reset_comm_stats()
     t_pp = Trainer(
-        get_model("gpt2_pipe_tiny", mesh=mesh, n_microbatches=4),
+        get_model("gpt2_pipe_tiny", n_stages=n_stages, mesh=mesh,
+                  n_microbatches=4, n_virtual=n_virtual),
         datasets=(ds, ds), model_dir=str(tmp_path / "pp"),
-        is_parallel=True, backend="cpu",
-        mesh_shape={"data": 2, "stage": 4},
-        sharding_rules=rules_for("gpt2", "pp"),
-        pipeline_schedule="1f1b",
+        mesh_shape=mesh_shape, sharding_rules=rules_for("gpt2", "pp"),
+        pipeline_schedule=schedule, telemetry=True, log_every_steps=2,
         **common,
     )
-    assert t_pp.model.schedule == "1f1b"  # the knob really cloned
+    assert t_pp.model.schedule == schedule  # the knob really cloned
+    compile_watch.install()
+    warm_before = compile_watch.post_warmup_count()
     t_pp.fit()
     np.testing.assert_allclose(
         t_serial.train_losses, t_pp.train_losses, rtol=1e-3
@@ -304,9 +331,20 @@ def test_pipeline_1f1b_trains_dp_x_pp(tmp_path):
         t_serial.val_losses, t_pp.val_losses, rtol=1e-3
     )
     assert t_pp._train_step._cache_size() == 1
+    assert compile_watch.post_warmup_count() == warm_before, (
+        [e.as_dict() for e in compile_watch.events(last=4)]
+    )
+    assert {"fwd", "bwd", "output_broadcast"} <= set(
+        comm_hop_bytes().get(schedule, {})
+    )
+    gauge = f"train_pipeline_bubble_fraction{{schedule={schedule}}}"
+    assert default_registry().snapshot()[gauge] == pytest.approx(
+        pipeline_schedule_info()[schedule]["bubble_fraction"], abs=1e-9
+    )
 
 
 # ---------------------------------------------------------------------- moe
+@slow
 def test_moe_single_expert_equals_dense_mlp():
     """E=1 with ample capacity: routing is the identity, so the MoE layer is
     exactly its one expert MLP (gate prob = softmax over 1 = 1.0)."""
@@ -321,6 +359,7 @@ def test_moe_single_expert_equals_dense_mlp():
     np.testing.assert_allclose(out, ref, atol=1e-5, rtol=1e-5)
 
 
+@slow
 def test_moe_routes_and_balances():
     x = jnp.asarray(
         np.random.default_rng(1).normal(size=(4, 16, 32)), jnp.float32
@@ -334,6 +373,7 @@ def test_moe_routes_and_balances():
     assert float(aux) >= 0.99 and np.isfinite(float(aux))
 
 
+@slow
 def test_moe_trains_expert_parallel(tmp_path):
     """gpt2_moe_tiny trains on a {data:2, expert:4} mesh with EP rules:
     expert weights really shard the expert axis and the loss is finite."""
@@ -353,6 +393,7 @@ def test_moe_trains_expert_parallel(tmp_path):
     assert np.isfinite(t.train_losses[0])
 
 
+@slow
 def test_moe_aux_loss_applied_in_train_step(tmp_path):
     """VERDICT r2 #3: the sown load-balance loss must be CONSUMED by the
     train step, not just computed.  With a huge ``moe_aux_weight`` the
@@ -380,6 +421,7 @@ def test_moe_aux_loss_applied_in_train_step(tmp_path):
     assert boosted - base >= 1800.0
 
 
+@slow
 def test_moe_aux_loss_rebalances_collapsed_router():
     """Behavioral check: start from a router biased hard onto expert 0 and
     train on random data.  With the aux loss the expert-assignment entropy
@@ -438,6 +480,7 @@ def test_moe_aux_loss_rebalances_collapsed_router():
     assert ent_with > ent_without + 0.5, (ent_with, ent_without)
 
 
+@slow
 def test_pipeline_parallel_training_matches_serial(tmp_path):
     """VERDICT r2 #4: pipeline parallelism trains a REAL model through the
     Trainer.  gpt2_pipe_tiny — embedding and tied head outside the trunk,
@@ -475,6 +518,7 @@ def test_pipeline_parallel_training_matches_serial(tmp_path):
     np.testing.assert_allclose(t_serial.val_losses, t_pp.val_losses, rtol=1e-3)
 
 
+@slow
 def test_moe_top2_routing():
     """GShard top-2: (a) num_selected=1 reproduces the original top-1
     numbers exactly; (b) with ample capacity, top-2 output equals the
@@ -512,6 +556,7 @@ def test_moe_top2_routing():
     )
 
 
+@slow
 def test_moe_top2_priority_dispatch_drops_second_choices_first():
     """At tight capacity, first choices claim slots before ANY second
     choice.  Checked against an explicit numpy reference that claims

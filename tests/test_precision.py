@@ -4,7 +4,9 @@ the gradient-bucket planner behind the sharded DP update.
 
 The distributed trajectory-equality pins for dp_update='sharded' live in
 tests/test_parallel.py (slow tier); this module is the fast lane:
-single-device Trainer runs and pure-host units.
+single-device Trainer runs, pure-host units, and what the
+{precision} x {dp_update} matrix must hold on the virtual data mesh
+(finite loss, one program, the per-bucket comm ledger).
 """
 
 import tempfile
@@ -110,22 +112,79 @@ def test_plan_grad_buckets_reverse_order_and_rule():
 
 
 # ----------------------------------------------- scaling x accum x guard
-def test_dynamic_scale_halves_on_overflow_without_burning_rollback(tmp_path):
-    """The satellite matrix: loss scaling x grad accumulation x NaN guard.
-    An injected non-finite step under bf16+dynamic scaling must (a) skip
-    the update, (b) halve the scale, (c) land in the skipped-step ledger,
-    and (d) NOT advance the rollback streak — overflow is the scale's
-    fault, not the run's."""
+@pytest.mark.parametrize("composed_with", [
+    pytest.param({"grad_accum_steps": 2}, id="grad-accum"),
+    pytest.param({"mesh_shape": {"data": 8}, "dp_update": "sharded"},
+                 id="sharded-dp-update"),
+])
+def test_dynamic_scale_halves_on_overflow_without_burning_rollback(
+    tmp_path, composed_with
+):
+    """The satellite matrix: loss scaling x NaN guard x {grad accumulation,
+    the bucketed sharded update on a data mesh}.  An injected non-finite
+    step under bf16+dynamic scaling must (a) skip the update, (b) halve
+    the scale, (c) land in the skipped-step ledger, (d) NOT advance the
+    rollback streak — overflow is the scale's fault, not the run's — and
+    (e) stay one compiled program: the backoff is where-selected, not
+    branched."""
     with faults.injected("nan_grad@step=2"):
-        t = make_trainer(
-            tmp_path / "bf16", precision="bf16", grad_accum_steps=2,
-        )
+        t = make_trainer(tmp_path / "bf16", precision="bf16", **composed_with)
         s0 = float(t.state.loss_scale)
         t.fit()
     assert float(t.state.loss_scale) == s0 * 0.5
     assert t.skipped_steps == [1]
     assert int(jax.device_get(t.state.bad_streak)) == 0
     assert all(np.isfinite(t.train_losses))
+    assert t._train_step._cache_size() == 1
+
+
+@pytest.mark.parametrize("dp_update", ["fused", "sharded"])
+@pytest.mark.parametrize("precision", ["fp32", "bf16"])
+def test_dp_matrix_finite_loss_one_program_and_bucket_gauges(
+    tmp_path, precision, dp_update
+):
+    """{fp32, bf16} x {fused psum, bucketed reduce-scatter + sharded
+    update} on the 8-device data mesh, through the real Trainer: every
+    cell trains finite, compiles one train step and nothing after the
+    first epoch; the sharded cells leave one reduce-scatter and one
+    all-gather entry a bucket in the comm ledger and publish the plan's
+    overlap fraction."""
+    from ml_trainer_tpu.data import SyntheticTokens
+    from ml_trainer_tpu.models import get_model
+    from ml_trainer_tpu.parallel.comm_stats import (
+        comm_bucket_bytes,
+        reset_comm_stats,
+    )
+    from ml_trainer_tpu.telemetry import compile_watch, default_registry
+
+    ds = SyntheticTokens(size=32, seq_len=32, vocab_size=256, seed=0)
+    reset_comm_stats()
+    t = Trainer(
+        get_model("gpt2_tiny", vocab_size=256), datasets=(ds, ds),
+        epochs=2, batch_size=16, model_dir=str(tmp_path), metric=None,
+        lr=1e-3, optimizer="adamw", mesh_shape={"data": 8},
+        precision=precision, dp_update=dp_update, bucket_mb=0.25,
+        telemetry=True, log_every_steps=2,
+    )
+    compile_watch.install()
+    warm_before = compile_watch.post_warmup_count()
+    t.fit()
+    assert all(np.isfinite(t.train_losses)), t.train_losses
+    assert t._train_step._cache_size() == 1
+    assert compile_watch.post_warmup_count() == warm_before, (
+        [e.as_dict() for e in compile_watch.events(last=4)]
+    )
+    if dp_update == "sharded":
+        plan = t._bucket_plan
+        assert len(plan.buckets) > 1
+        ledger = comm_bucket_bytes()
+        assert len(ledger["reduce_scatter"]) == len(plan.buckets)
+        assert len(ledger["all_gather"]) == len(plan.buckets)
+        snap = default_registry().snapshot()
+        assert snap["train_overlap_fraction"] == pytest.approx(
+            plan.overlap_fraction, abs=1e-9
+        )
+        assert any(k.startswith("comm_bucket_bytes_total{") for k in snap)
 
 
 def test_fp32_ledger_unchanged_by_the_scaling_feature(tmp_path):

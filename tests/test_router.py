@@ -176,7 +176,11 @@ def test_import_no_memory_reports_instead_of_wedging(model_and_vars):
 def test_router_disagg_byte_identity_greedy_and_sampled(model_and_vars):
     """Requests routed prefill -> migrate -> decode reproduce their
     standalone generate() outputs, greedy and seeded sampling alike,
-    and migrations actually happened."""
+    migrations actually happened, and once the trace has been served
+    (cold, then with its prefixes cached) replaying it compiles
+    nothing: export, wire, import and decode are steady programs."""
+    from ml_trainer_tpu.telemetry import compile_watch
+
     model, variables = model_and_vars
     pA, pB, pC = _prompt(5, 9), _prompt(6, 5), _prompt(7, 12)
     refA = np.asarray(generate(model, variables, pA[None], 16))[0]
@@ -192,9 +196,13 @@ def test_router_disagg_byte_identity_greedy_and_sampled(model_and_vars):
         sC = router.submit(pC, 10, temperature=0.7, rng=42)
         outs = [s.result(timeout=180) for s in (sA, sB, sC)]
         snap = router.snapshot()
+        router.complete(pA, 16, timeout=180)  # warms the prefix-hit path
+        with compile_watch.expect_no_compiles("router steady state"):
+            again = router.complete(pA, 16, timeout=180)
     np.testing.assert_array_equal(outs[0], refA)
     np.testing.assert_array_equal(outs[1], refB)
     np.testing.assert_array_equal(outs[2], refC)
+    np.testing.assert_array_equal(again, refA)
     assert snap["migrations_total"] >= 3
     assert snap["kv_migrated_bytes_total"] > 0
     assert snap["mode"] == "disagg"
@@ -202,8 +210,7 @@ def test_router_disagg_byte_identity_greedy_and_sampled(model_and_vars):
 
 def test_router_colocated_matches_disagg(model_and_vars):
     """Colocated mode (every replica both roles, no migration) serves
-    the same trace byte-identically — the equal-replica-count
-    comparison bench.py --serve-disagg runs."""
+    the same trace byte-identically at an equal replica count."""
     model, variables = model_and_vars
     prompts = [_prompt(s, 6 + s % 5) for s in (8, 9, 10)]
     refs = [
@@ -270,7 +277,9 @@ def test_session_stickiness_pins_decode_replica(model_and_vars):
 def test_replica_kill_redistributes_in_flight(model_and_vars):
     """The acceptance pin: a decode replica dies MID-STREAM; the router
     redistributes its in-flight requests to a survivor, the job
-    completes, and every output stays byte-identical."""
+    completes, and every output stays byte-identical — and the failure
+    it recorded opens the dead replica's circuit breaker without
+    waiting for the health poller."""
     model, variables = model_and_vars
     prompts = [_prompt(30 + i, 7 + i) for i in range(4)]
     refs = [
@@ -279,7 +288,8 @@ def test_replica_kill_redistributes_in_flight(model_and_vars):
     ]
     with Router.build(model, variables,
                       roles=["prefill", "decode", "decode"],
-                      max_batch=2, kv_page_size=PS) as router:
+                      max_batch=2, kv_page_size=PS,
+                      router_kwargs={"breaker_threshold": 1}) as router:
         streams = [router.submit(p, 28) for p in prompts]
         deadline = time.monotonic() + 120
         while any(len(s.tokens) < 2 for s in streams):
@@ -288,11 +298,13 @@ def test_replica_kill_redistributes_in_flight(model_and_vars):
         router.kill_replica("decode0")
         outs = [np.asarray(s.result(timeout=180)) for s in streams]
         snap = router.snapshot()
+        breakers = {n: r.breaker.state for n, r in router.replicas.items()}
     for out, ref in zip(outs, refs):
         np.testing.assert_array_equal(out, ref)
     assert snap["redistributes_total"] >= 1
     assert snap["replica_healthy"]["decode0"] == 0
     assert snap["replica_healthy"]["decode1"] == 1
+    assert breakers["decode0"] == "open" and breakers["decode1"] == "closed"
 
 
 def test_redistribution_budget_exhaustion_is_structured(model_and_vars):
@@ -317,7 +329,7 @@ def test_redistribution_budget_exhaustion_is_structured(model_and_vars):
 
 def test_router_metrics_on_registry(model_and_vars):
     """router_* series land on the registry with their labels — what
-    the smoke leg's /metrics scrape asserts over HTTP."""
+    a /metrics scrape of the router's HTTP front serves."""
     from ml_trainer_tpu.telemetry.registry import MetricsRegistry
 
     model, variables = model_and_vars
@@ -334,6 +346,12 @@ def test_router_metrics_on_registry(model_and_vars):
     assert 'router_replica_healthy{replica="decode0"} 1' in text
     assert 'router_replica_slo_attainment{' in text
     assert "router_redistributes_total" in text
+    assert "router_migrations_total" in text
+    # The overload stack's series ride the same scrape.
+    assert "serving_degradation_level" in text
+    assert "router_hedges_total" in text
+    assert "router_flaps_damped_total" in text
+    assert 'router_breaker_state{replica="decode0"} 0' in text
 
 
 def test_router_rejects_heterogeneous_or_contiguous_fleet(model_and_vars):

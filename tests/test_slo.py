@@ -32,6 +32,7 @@ from ml_trainer_tpu.serving.loadgen import schedule_to_records
 from ml_trainer_tpu.serving.metrics import ServingMetrics
 from ml_trainer_tpu.serving.scheduler import Request
 from ml_trainer_tpu.serving.slo import aggregate_timelines
+from ml_trainer_tpu.telemetry import spans
 from ml_trainer_tpu.telemetry.registry import MetricsRegistry
 
 
@@ -254,8 +255,9 @@ def test_loadgen_trace_round_trip(tmp_path):
 
 def test_open_loop_populates_slo_accounting(model_and_vars):
     """A small in-process open-loop run: every request completes, the
-    tracker observed each, the snapshot carries the TTFT decomposition
-    fields (with the legacy keys intact), and attainment is computed."""
+    tracker observed each, its lifecycle spans nest on the trace, the
+    snapshot carries the TTFT decomposition fields (with the legacy keys
+    intact), and attainment is computed."""
     model, variables = model_and_vars
     sched = poisson_schedule(
         40.0, 6, model.vocab_size,
@@ -263,12 +265,30 @@ def test_open_loop_populates_slo_accounting(model_and_vars):
                                        output_len=(2, 4))},
         seed=1,
     )
+    spans.clear_trace()
     with Server(model, variables, max_batch=2, max_queue=16,
                 slo=SloPolicy(ttft_ms=60_000, tpot_ms=60_000)) as srv:
         report = run_open_loop(sched, server=srv, timeout=300)
         snap = srv.metrics.snapshot()
         slo = srv.slo.snapshot()
     assert report["n_completed"] == 6 and report["n_errors"] == 0
+    # Every finished request is a ``request N`` span on the trace whose
+    # queue_wait / prefill / decode children nest by time containment.
+    events = spans.trace_events()
+    parents = {
+        e["args"]["request"]: e for e in events
+        if e["name"].startswith("request ") and "args" in e
+    }
+    kids = [
+        e for e in events
+        if e["name"] in ("queue_wait", "prefill", "decode")
+        and e.get("args", {}).get("request") in parents
+    ]
+    assert len(parents) == 6 and len(kids) >= 12
+    for k in kids:
+        parent = parents[k["args"]["request"]]
+        assert parent["ts"] - 1 <= k["ts"], (k, parent)
+        assert k["ts"] + k["dur"] <= parent["ts"] + parent["dur"] + 1
     assert report["tokens_per_sec"] > 0
     assert slo["requests_observed"] == 6
     assert slo["attainment"] == {"ttft": 1.0, "tpot": 1.0}

@@ -223,16 +223,37 @@ def test_flight_ring_bounded_and_dump(tmp_path):
 
 def test_nan_grad_fault_dumps_flight_naming_step(tmp_path, monkeypatch):
     """The acceptance scenario: an injected ``nan_grad`` (with rollback
-    armed) must leave a flight dump on disk naming the offending step."""
+    armed) must leave a flight dump on disk naming the offending step —
+    through the env-var plumbing (flight-dir redirect, JSONL sink), with
+    the run's gauges, host spans and history ledger behind it."""
+    from ml_trainer_tpu.telemetry import default_registry
+    from ml_trainer_tpu.telemetry.spans import clear_trace, trace_events
+
     monkeypatch.setenv("ML_TRAINER_TPU_FLIGHT_DIR", str(tmp_path))
+    sink = tmp_path / "metrics.jsonl"
+    monkeypatch.setenv("ML_TRAINER_TPU_METRICS_JSONL", str(sink))
     get_recorder().clear()
+    clear_trace()
     with faults.injected("nan_grad@step=3"):
         t = make_trainer(
             tmp_path / "m", telemetry=True, log_every_steps=1,
-            rollback_bad_steps=1,
+            rollback_bad_steps=1, save_history=True,
         )
         t.fit()
     assert t.rollbacks == 1
+    assert default_registry().snapshot()["train_skipped_steps_total"] >= 1
+    text = prometheus_text(default_registry())
+    assert "# TYPE train_grad_norm gauge" in text
+    assert all(ln.startswith("#") or " " in ln for ln in text.splitlines())
+    records = [json.loads(ln) for ln in open(sink) if ln.strip()]
+    assert any(r.get("kind") == "train_step" for r in records)
+    assert {"data_load", "h2d", "ckpt_write"} <= {
+        e["name"] for e in trace_events()
+    }
+    hist = load_history(str(tmp_path / "m"))
+    assert hist["rollbacks"] == 1 and sum(hist["skipped_steps"]) == 1
+    report = json.load(open(tmp_path / "m" / "run_report.json"))
+    assert report["resilience"]["rollbacks"] == 1
     dumps = sorted(
         f for f in os.listdir(tmp_path) if f.startswith("flight_")
     )

@@ -24,8 +24,7 @@ observability pillar's contracts:
   bit-identical trajectory, while the store actually fills;
 * JSONL sink rotation: ``max_bytes`` rotates segments + sidecar index,
   and ``read_sink_records`` replays every segment in order;
-* perf_diff (scripts/perf_diff.py): flatten/diff/categorize/format and
-  the fastlane ``record_timing`` upsert.
+* perf_diff (scripts/perf_diff.py): flatten/diff/categorize/format.
 """
 
 import json
@@ -543,16 +542,3 @@ def test_perf_diff_reads_tsdb_dumps(perf_diff, tmp_path):
     rows = perf_diff.diff_files(pa, pb)
     assert [r["key"] for r in rows] == ["queue_depth{tenant=x}"]
     assert rows[0]["old"] == 10.0 and rows[0]["new"] == 40.0
-
-
-def test_perf_diff_record_timing_upserts(perf_diff, tmp_path):
-    path = str(tmp_path / "timings.json")
-    perf_diff.record_timing(path, "serving", 40.0, rc=0)
-    payload = perf_diff.record_timing(path, "watchtower", 12.5, rc=0)
-    assert payload["total_seconds"] == pytest.approx(52.5)
-    payload = perf_diff.record_timing(path, "serving", 38.0, rc=1)
-    on_disk = json.load(open(path))
-    assert on_disk["legs"]["serving"] == payload["legs"]["serving"]
-    assert on_disk["legs"]["serving"]["seconds"] == 38.0  # upserted
-    assert on_disk["legs"]["serving"]["rc"] == 1
-    assert on_disk["total_seconds"] == pytest.approx(50.5)
