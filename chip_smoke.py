@@ -209,6 +209,7 @@ def kernel_cases(cfg: SmokeConfig):
         flash_attention,
     )
     from ml_trainer_tpu.ops.kernels import (
+        decode_attention,
         fused_adam_update,
         int8_matmul,
         paged_attention,
@@ -333,6 +334,31 @@ def kernel_cases(cfg: SmokeConfig):
         # It moves bytes and rounds nothing: no floor.
         yield (f"slot_cache_write [{dtype.__name__}] {(B, H, L, D)}",
                write("pallas"), write("reference"), args, 0.0)
+
+    # -- decode attention: every decode step of the slot engine -----------
+    # The two serving cells' caches, one a layout (heads of 64: the
+    # position on the lanes; of 128: on the sublanes, eight query heads a
+    # key-value head), rows of ragged lengths; the interpreter gets the same
+    # two layouts small.
+    for b, h, g, length, d in (
+            ((4, 4, 4, 256, 64), (4, 16, 2, 256, 128)) if interp else
+            ((32, 20, 20, 1024, 64), (64, 64, 8, 2048, 128))):
+        lens = rng.integers(1, length + 1, size=b)
+        lens[:4] = (1, length, length + 500, 257)   # the ends, clamp, an edge
+        args = (
+            normal((b, h, 1, d), jnp.bfloat16, 0.5),
+            normal((b, g, length, d), jnp.bfloat16, 0.5),
+            normal((b, g, length, d), jnp.bfloat16, 0.5),
+            jnp.asarray(lens, jnp.int32),
+        )
+
+        def attend(impl):
+            return lambda q, kc, vc, n: decode_attention(
+                q, kc, vc, n, implementation=impl, interpret=interp)
+
+        yield (f"decode_attention [bfloat16] {(b, h, 1, d)} x "
+               f"{(b, g, length, d)}", attend("pallas"), attend("reference"),
+               args, BF16_FLOOR)
 
     # -- int8 decode matmul (opt-in: Server(quant_int8=True)) --------------
     for tag, kk, nn in (("qkv", E, 3 * E), ("proj", E, E),

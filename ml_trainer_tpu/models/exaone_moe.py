@@ -41,6 +41,10 @@ import jax.numpy as jnp
 from ml_trainer_tpu.models.moe import GatedMLP, HeldExpertsMoE
 from ml_trainer_tpu.models.registry import register_model
 from ml_trainer_tpu.ops.attention import attention
+from ml_trainer_tpu.ops.kernels.decode_attention import (
+    decode_attention,
+    grouped_decode_attention,
+)
 from ml_trainer_tpu.ops.kernels.slot_cache_write import slot_cache_write
 
 PERIOD = ("sliding_attention",) * 3 + ("full_attention",)
@@ -57,28 +61,6 @@ def rotate_half(x, positions, theta: float):
     x2 = x[..., half:].astype(jnp.float32)
     return jnp.concatenate(
         [x1 * cos - x2 * sin, x1 * sin + x2 * cos], axis=-1).astype(x.dtype)
-
-
-def grouped_decode_attention(q, k_cache, v_cache, valid):
-    """One query position a row against a cache that keeps the key-value
-    heads only.  q: [B, H, 1, D]; caches [B, G, L, D]; valid: [B, L].  The
-    H/G query heads of a key-value head form a group, so each cache row is
-    read once (repeating the cache to H heads would move H/G times as
-    much)."""
-    b, h, _, d = q.shape
-    g = k_cache.shape[1]
-    qg = q.reshape(b, g, h // g, d)
-    scores = jnp.einsum(
-        "bgrd,bgld->bgrl", qg, k_cache, preferred_element_type=jnp.float32,
-    ) * d ** -0.5
-    scores = jnp.where(
-        valid[:, None, None, :], scores, jnp.finfo(jnp.float32).min)
-    weights = jax.nn.softmax(scores, axis=-1)
-    out = jnp.einsum(
-        "bgrl,bgld->bgrd", weights.astype(v_cache.dtype), v_cache,
-        preferred_element_type=jnp.float32,
-    )
-    return out.astype(q.dtype).reshape(b, h, 1, d)
 
 
 class ExaoneAttention(nn.Module):
@@ -194,6 +176,12 @@ class ExaoneAttention(nn.Module):
                 cached_k.value, cached_v.value, k, v, at)
         else:
             put(at[0])
+        if idx.ndim and not w:
+            # A full layer of the slot engine: each row's live blocks and
+            # no others (ops/kernels/decode_attention.py).  A ring is one
+            # block, full after ``window`` tokens: nothing to skip.
+            return decode_attention(
+                q, cached_k.value, cached_v.value, rows + 1)
         slots = jnp.arange(length)[None, :]
         valid = slots <= rows[:, None]
         if w:

@@ -23,6 +23,7 @@ import jax
 import jax.numpy as jnp
 
 from ml_trainer_tpu.ops.attention import attention
+from ml_trainer_tpu.ops.kernels.decode_attention import decode_attention
 from ml_trainer_tpu.ops.kernels.slot_cache_write import (
     slot_cache_write,
     slot_cache_write_reference,
@@ -244,16 +245,19 @@ class MultiHeadAttention(nn.Module):
             # and attend their own valid prefix.  ``s == 1`` is the
             # ordinary decode step: one in-place write a layer with every
             # row in flight (ops/kernels/slot_cache_write.py; XLA runs the
-            # scatter it replaces as a sequential loop over the rows).
+            # scatter it replaces as a sequential loop over the rows), then
+            # one read of each row's live blocks and no others
+            # (ops/kernels/decode_attention.py; the masked attention it
+            # replaces reads all ``L`` positions of every row).
             # ``s > 1`` is the speculative VERIFY window (speculative.py): a
             # length-``s`` token window lands at each row's own dynamic
             # offset — one dynamic_update_slice per row, shapes static at
             # fixed ``s``, so a fixed draft length K never recompiles — and
             # query position j attends cached positions <= idx + j (the
-            # in-window causal rule).  The window keeps the scatter (the
-            # kernel's reference): it may cross the edge of the kernel's
-            # block, which would take a second kernel, and no measured
-            # traffic runs it.
+            # in-window causal rule).  The window keeps the scatter and the
+            # masked attention (the kernels' references): it may cross the
+            # edge of the write's block and has ``s`` lengths a row, which
+            # would take second kernels, and no measured traffic runs it.
             # Prefill still runs per request at batch 1 with the ordinary
             # scalar index and is inserted into the slot cache afterwards.
             write = slot_cache_write if s == 1 else slot_cache_write_reference
@@ -262,6 +266,10 @@ class MultiHeadAttention(nn.Module):
                 k.astype(self.dtype), v.astype(self.dtype), idx,
             )
             idx_var.value = idx + s
+            if s == 1:
+                # The positions before this step's and the one just written.
+                return decode_attention(
+                    q, cached_k.value, cached_v.value, idx + 1)
             valid = (
                 jnp.arange(L)[None, None, :]
                 <= idx[:, None, None] + jnp.arange(s)[None, :, None]

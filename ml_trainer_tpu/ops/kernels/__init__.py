@@ -7,8 +7,9 @@ Every kernel in this package ships as a PAIR under one dispatcher:
   ``tests/test_kernels.py``), shipped as the CPU/GPU runtime path;
 * a **Pallas TPU kernel** — the fused program that removes the HBM
   round-trips the XLA path pays, pinned bit-for-bit against the lax
-  reference in interpret mode on CPU (the repo's kernel discipline,
-  same as ``ops/attention.py``'s flash kernel).
+  reference in interpret mode on CPU (the repo's kernel discipline;
+  ``decode_attention``, whose softmax runs online, by a stated tolerance
+  as ``ops/attention.py``'s flash kernel is).
 
 ``implementation='auto'`` resolves to the Pallas kernel on TPU and the
 lax reference everywhere else, so enabling a kernel knob never changes
@@ -31,7 +32,19 @@ Catalog (see docs/kernels.md for block layouts and measured numbers):
 * ``slot_cache_write`` — the slot engine's per-row append of this step's
   K and V, every decode step and behind no knob: one in-place grid over
   the rows where XLA runs the scatter as a sequential loop over them.
+* ``decode_attention`` — the slot engine's read of the cache it has just
+  written, every decode step and behind no knob: one query position a row
+  against the blocks of that row that hold a live position, where XLA's
+  masked attention reads all ``L`` positions of every row.  Online
+  softmax, so pinned to its reference by tolerance, not bit for bit.
 """
+
+from ml_trainer_tpu.ops.kernels.decode_attention import (  # noqa: F401
+    attended_positions,
+    decode_attention,
+    decode_attention_reference,
+    grouped_decode_attention,
+)
 
 from ml_trainer_tpu.ops.kernels.paged_attention import (  # noqa: F401
     paged_attention,
@@ -53,6 +66,10 @@ from ml_trainer_tpu.ops.kernels.int8_matmul import (  # noqa: F401
 )
 
 __all__ = [
+    "attended_positions",
+    "decode_attention",
+    "decode_attention_reference",
+    "grouped_decode_attention",
     "paged_attention",
     "paged_attention_reference",
     "adam_scalars",

@@ -230,6 +230,47 @@ def test_a_window_is_the_band_of_the_causal_mask():
         attention(q, k, v, causal=False, window=5)
 
 
+def test_only_the_full_layers_read_through_decode_attention(monkeypatch):
+    """The slot engine's decode step calls the kernel's entry point in the
+    full layers (a cache of ``positions`` a row) and not in the window
+    layers, whose ring is one block and full after ``window`` tokens; with
+    the parent's expression (PR 29) behind that entry point the streams keep
+    their bytes (off the TPU the kernel's reference IS that expression)."""
+    from ml_trainer_tpu.models import exaone_moe
+    from ml_trainer_tpu.serving import engine
+
+    s = sizes((0, 4))
+    variables = {"params": reference.make_weights(seed_key(13), **s)}
+    model = get_model("exaone_moe_tiny", experts_held=(0, 4))
+    calls = []
+
+    def parent(q, k_cache, v_cache, lengths):
+        calls.append((q.shape, k_cache.shape))
+        slots = jnp.arange(k_cache.shape[2])[None, :]
+        return exaone_moe.grouped_decode_attention(
+            q, k_cache, v_cache, slots <= (lengths - 1)[:, None])
+
+    def streams():
+        with Server(model, variables, max_batch=3) as server:
+            return [np.asarray(server.submit(prompt(n, n), 12).result(
+                timeout=300)) for n in (5, 11, 19)]
+
+    plain = streams()
+    monkeypatch.setattr(engine, "_COMPILED", {})   # trace anew under the spy
+    monkeypatch.setattr(exaone_moe, "decode_attention", parent)
+    for a, b in zip(plain, streams()):
+        np.testing.assert_array_equal(a, b)
+    full = sum(kind == "full_attention" for kind in s["layer_types"])
+    assert calls and len(calls) % full == 0
+    assert {k for _, k in calls} == {
+        (3, s["kv_heads"], s["positions"], s["head_dim"])}
+    assert {q for q, _ in calls} == {(3, s["heads"], 1, s["head_dim"])}
+    # generate() decodes under a scalar index: the grouped XLA read
+    del calls[:]
+    generate(model, variables, prompt(9, 3)[None], 4)
+    assert not calls
+
+
 def test_served_over_http_as_generate_computes_it_and_refused_as_others():
     s = sizes((0, 4))
     weights = reference.make_weights(seed_key(13), **s)

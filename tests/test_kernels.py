@@ -28,6 +28,14 @@ from ml_trainer_tpu.ops.kernels import (
     quantize_tree,
     unscale_sqsum,
 )
+from ml_trainer_tpu.ops.attention import dot_product_attention
+from ml_trainer_tpu.ops.kernels.decode_attention import (
+    _decode_attention_pallas,
+    _decode_block,
+    attended_positions,
+    decode_attention,
+    decode_attention_reference,
+)
 from ml_trainer_tpu.ops.kernels.slot_cache_write import (
     _position_on_lanes,
     slot_cache_write,
@@ -293,6 +301,164 @@ def test_slot_cache_write_refusals():
                          implementation="scatter")
 
 
+# ---------------------------------------------------- decode_attention
+# [B, G, L, D] per layout, as for the write above; blocks of 128 positions,
+# so a row meets one, two or all three of its blocks.
+_DECODE_SHAPES = {"lanes": (6, 2, 384, 16), "sublanes": (6, 2, 384, 128)}
+_DECODE_BLOCK = 128
+_DECODE_TOL = {"float32": 1e-5, "bfloat16": 1e-2}
+_DECODES = {}
+
+
+def _decode_case(layout, dtype, rep):
+    """q, K, V of a layout, dtype and group size, with the two jitted
+    paths: the lengths are an argument, so every case shares them."""
+    key = (layout, jnp.dtype(dtype).name, rep)
+    if key not in _DECODES:
+        b, g, L, d = _DECODE_SHAPES[layout]
+        assert _position_on_lanes(L, d) == (layout == "lanes")
+        rng = np.random.default_rng(len(_DECODES))
+        arrays = tuple(
+            jnp.asarray(rng.normal(size=shape), dtype)
+            for shape in [(b, g * rep, 1, d)] + [(b, g, L, d)] * 2)
+        _DECODES[key] = arrays, jax.jit(decode_attention_reference), jax.jit(
+            lambda *a: _decode_attention_pallas(*a, _DECODE_BLOCK, True))
+    return _DECODES[key]
+
+
+@pytest.mark.parametrize("layout", sorted(_DECODE_SHAPES))
+@pytest.mark.parametrize("rep", [1, 8], ids=["G==H", "H/G==8"])
+@pytest.mark.parametrize("dtype,lengths", [
+    (jnp.bfloat16, (1, 127, 128, 129, "L", "L+500")),
+    (jnp.bfloat16, (256, 257, 2, "L-1", 0, -4)),
+    (jnp.float32, (129, 1, "L+500", 255, "L", 128)),
+], ids=str)
+def test_decode_attention_is_the_masked_attention(layout, rep, dtype,
+                                                  lengths):
+    """The kernel (interpret mode) against the engine's masked attention
+    over all ``L`` positions, both under jit: lengths of 1, either side of
+    a block edge and on it, ``L``, and past ``L`` or under 1 (clamped), rows
+    of different lengths in one call, one query head a key-value head and
+    eight.  By tolerance: the online softmax sums in another order
+    (docs/kernels.md)."""
+    (q, k, v), reference, kernel = _decode_case(layout, dtype, rep)
+    L = k.shape[2]
+    at = {"L-1": L - 1, "L": L, "L+500": L + 500}
+    lengths = jnp.asarray([at.get(n, n) for n in lengths], jnp.int32)
+    want, got = reference(q, k, v, lengths), kernel(q, k, v, lengths)
+    assert got.shape == q.shape and got.dtype == q.dtype
+    tol = _DECODE_TOL[jnp.dtype(dtype).name]
+    np.testing.assert_allclose(
+        np.asarray(got, np.float32), np.asarray(want, np.float32),
+        atol=tol, rtol=tol)
+
+
+@pytest.mark.parametrize("layout", sorted(_DECODE_SHAPES))
+@pytest.mark.parametrize("poison", [np.nan, 1e30], ids=["nan", "1e30"])
+def test_decode_attention_never_sees_past_a_rows_length(layout, poison):
+    """Exact: what lies past a row's length, in the block the length
+    crosses and in the blocks never fetched, changes no bit of the
+    output (a freed slot leaves its old tokens there)."""
+    (q, k, v), _, kernel = _decode_case(layout, jnp.bfloat16, 8)
+    L = k.shape[2]
+    lengths = jnp.asarray([1, 127, 128, 129, L - 1, L], jnp.int32)
+    dead = (jnp.arange(L)[None, :] >= lengths[:, None])[:, None, :, None]
+    clean = kernel(q, k, v, lengths)
+    dirty = kernel(q, jnp.where(dead, poison, k).astype(k.dtype),
+                   jnp.where(dead, poison, v).astype(v.dtype), lengths)
+    assert np.isfinite(np.asarray(clean, np.float32)).all()
+    assert np.array_equal(_bits(dirty), _bits(clean))
+
+
+@pytest.mark.parametrize("layout", sorted(_DECODE_SHAPES))
+def test_decode_attention_of_a_full_row_is_the_unmasked_attention(layout):
+    """At length ``L`` no position is masked: the kernel's bits there are
+    its bits at any length past ``L`` (exact), and both paths are
+    ``dot_product_attention`` with no mask (to float32 rounding: XLA fuses
+    an all-true mask's sums in another order); the public call with the
+    chooser's block (one block at this size) agrees with both."""
+    (q, k, v), reference, kernel = _decode_case(layout, jnp.float32, 1)
+    b, _, L, _ = k.shape
+    full = jnp.full((b,), L, jnp.int32)
+    assert np.array_equal(
+        _bits(kernel(q, k, v, full)), _bits(kernel(q, k, v, full + 77)))
+    unmasked = jax.jit(dot_product_attention)(q, k, v)
+    np.testing.assert_allclose(reference(q, k, v, full), unmasked, atol=1e-6)
+    np.testing.assert_allclose(kernel(q, k, v, full), unmasked, atol=1e-5)
+    chosen = _jrun(decode_attention, q, k, v, full,
+                   implementation="pallas", interpret=True)
+    np.testing.assert_allclose(chosen, reference(q, k, v, full), atol=1e-5)
+    np.testing.assert_allclose(chosen, kernel(q, k, v, full), atol=1e-5)
+    # off the TPU 'auto' IS the reference
+    assert np.array_equal(
+        _bits(_jrun(decode_attention, q, k, v, full - 5)),
+        _bits(reference(q, k, v, full - 5)))
+
+
+def test_decode_attention_refusals():
+    (q, k, v), _, _ = _decode_case("lanes", jnp.float32, 8)
+    lengths = jnp.ones((q.shape[0],), jnp.int32)
+    with pytest.raises(ValueError, match="differ"):
+        decode_attention(q, k, v[:, :1], lengths)
+    with pytest.raises(ValueError, match="differ"):
+        decode_attention(q, k, v.astype(jnp.bfloat16), lengths)
+    with pytest.raises(ValueError, match="one position a row"):
+        decode_attention(q[:, :, 0], k, v, lengths)
+    with pytest.raises(ValueError, match="one position a row"):
+        decode_attention(jnp.concatenate([q, q], axis=2), k, v, lengths)
+    with pytest.raises(ValueError, match="query heads over"):
+        decode_attention(q[:, :15], k, v, lengths)
+    with pytest.raises(ValueError, match="one a cache row"):
+        decode_attention(q, k, v, lengths[:-1])
+    with pytest.raises(ValueError, match="Unknown"):
+        decode_attention(q, k, v, lengths, implementation="flash")
+
+
+def test_decode_block_is_chosen_from_shape_and_dtype():
+    """Bytes of K a grid step, not positions: the two serving cells' caches
+    get the same block, float32 half of bfloat16, and a length 128 does not
+    divide (the interpreter's) is one block."""
+    assert _decode_block(20, 1024, 64, jnp.bfloat16) == 256
+    assert _decode_block(8, 2048, 128, jnp.bfloat16) == 256
+    assert _decode_block(20, 1024, 64, jnp.float32) == 128
+    assert _decode_block(96, 1024, 128, jnp.bfloat16) == 128
+    assert _decode_block(2, 384, 16, jnp.float32) == 384
+    assert _decode_block(2, 200, 16, jnp.float32) == 200
+
+
+def test_attended_positions_on_the_benchmark_traffic():
+    """What the kernel fetches of the pool in the steady state of
+    ``benchmark/traffic/batch-decode.json`` (a request of prompt P and
+    output O holds P + 1 .. P + O positions, a decode step each): the live
+    share plus about half a block a row.  PERF.md quotes these."""
+    import json
+    import os
+
+    from benchmark.loadgen import base_block
+
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    with open(os.path.join(
+            root, "benchmark", "traffic", "batch-decode.json")) as fp:
+        base = base_block(json.load(fp))
+    lengths = np.concatenate([
+        p + 1 + np.arange(o)
+        for p, o in zip(base["prompt_len"], base["output_len"])])
+    L = 1024
+    share = {block: attended_positions(lengths, L, block).mean() / L
+             for block in (1, 128, 256, 512, 1024)}
+    assert share[1] == pytest.approx(0.4246, abs=1e-4)      # the live share
+    assert share[128] == pytest.approx(0.4861, abs=1e-4)
+    assert share[256] == pytest.approx(0.5482, abs=1e-4)
+    assert share[512] == pytest.approx(0.6840, abs=1e-4)
+    assert share[1024] == 1.0
+    # the clamp, and a traced vector in, a traced vector out
+    assert attended_positions(np.array([0, -3, 1, 128, 129, 5000]), 1024,
+                              128).tolist() == [128, 128, 128, 128, 256, 1024]
+    traced = jax.jit(lambda n: attended_positions(n, 1024, 256))(
+        jnp.asarray([1, 257, 2000]))
+    assert traced.tolist() == [256, 512, 1024]
+
+
 def _kernel_calls():
     rng = np.random.default_rng(9)
     g = jnp.asarray(rng.normal(size=(64, 16)), jnp.float32)
@@ -307,6 +473,9 @@ def _kernel_calls():
         "slot_cache_write": lambda: slot_cache_write(
             *_slot_write("lanes", jnp.float32)[0],
             jnp.zeros((4,), jnp.int32), jnp.arange(4), **kw),
+        "decode_attention": lambda: decode_attention(
+            *_decode_case("lanes", jnp.float32, 1)[0],
+            jnp.ones((6,), jnp.int32), **kw),
         "fused_adam_norm": lambda: unscale_sqsum(g, 2.0, **kw),
         "fused_adam_update": lambda: fused_adam_update(
             g, g, g, jnp.abs(g), bc1=0.1, bc2=0.001, step_size=-1e-3,
@@ -316,7 +485,7 @@ def _kernel_calls():
 
 @pytest.mark.parametrize("name", ["paged_attention_decode", "int8_matmul",
                                   "fused_adam_norm", "fused_adam_update",
-                                  "slot_cache_write"])
+                                  "slot_cache_write", "decode_attention"])
 def test_each_kernel_carries_the_name_the_profiler_shows(name):
     """docs/kernels.md: a Pallas call's ``name`` is the instruction's name
     on the device trace, the handle a per-kernel metric finds it by."""
@@ -380,6 +549,77 @@ def _run_requests(model, variables, **server_kw):
         for s in streams:
             outs.append(np.asarray(s.result(timeout=300)))
     return outs
+
+
+def _parent_decode_attention(calls):
+    """``layers.py``'s read of the slot cache as it stood before the kernel
+    (PR 29), written out, behind the kernel's signature; keeps what it was
+    called with."""
+    from ml_trainer_tpu.ops.attention import attention
+
+    def spy(q, k_cache, v_cache, lengths):
+        calls.append((q.shape, k_cache.shape, lengths.shape))
+        idx, s, L = lengths - 1, q.shape[2], k_cache.shape[2]
+        valid = (
+            jnp.arange(L)[None, None, :]
+            <= idx[:, None, None] + jnp.arange(s)[None, :, None]
+        )[:, None, :, :]
+        return attention(q, k_cache, v_cache, causal=False, mask=valid,
+                         implementation="xla")
+
+    return spy
+
+
+def _slot_streams(model, variables, **server_kw):
+    from ml_trainer_tpu.serving import Server
+
+    prompts = [_prompt(s, n) for s, n in ((0, 5), (1, 3), (2, 12), (3, 7))]
+    with Server(model, variables, max_batch=3, **server_kw) as server:
+        streams = [server.submit(p, 9) for p in prompts]
+        return [np.asarray(s.result(timeout=300)) for s in streams]
+
+
+def test_slot_engine_reads_through_decode_attention_and_streams_the_same(
+        model_and_vars, monkeypatch):
+    """The slot engine's streams are byte for byte what the parent's
+    expression gives (off the TPU the kernel's reference IS that
+    expression), and ``_decode_step`` takes ``decode_attention`` only at one
+    position a row with a per-row index: the speculative verify window and
+    ``generate()``'s scalar index keep the masked XLA attention."""
+    from ml_trainer_tpu.generate import generate
+    from ml_trainer_tpu.models import layers
+    from ml_trainer_tpu.serving import engine
+
+    model, variables = model_and_vars
+    plain = _slot_streams(model, variables)
+    calls, steps = [], []
+    # the engines' programs are kept by model: trace them anew under the spy
+    monkeypatch.setattr(engine, "_COMPILED", {})
+    decode_step = layers.MultiHeadAttention._decode_step
+    monkeypatch.setattr(layers, "decode_attention",
+                        _parent_decode_attention(calls))
+    monkeypatch.setattr(
+        layers.MultiHeadAttention, "_decode_step",
+        lambda self, q, k, v: (steps.append(q.shape[2]),
+                               decode_step(self, q, k, v))[1])
+    for a, b in zip(plain, _slot_streams(model, variables)):
+        np.testing.assert_array_equal(a, b)
+    assert calls and all(
+        q[2] == 1 and q[0] == 3 and lengths == (3,)
+        for q, _, lengths in calls)
+    assert all(k == (3, q[1], 64, q[3]) for q, k, _ in calls)
+
+    # generate(): one position a step too, under a SCALAR index
+    del calls[:], steps[:]
+    tokens = generate(model, variables, _prompt(7, 6)[None], 5,
+                      eos_token_id=None)
+    assert tokens.shape == (1, 11) and 1 in steps and not calls
+
+    # the verify window (s = spec_k + 1) goes round the kernel
+    del calls[:], steps[:]
+    for a, b in zip(plain, _slot_streams(model, variables, spec_k=3)):
+        np.testing.assert_array_equal(a, b)
+    assert 4 in steps and not calls
 
 
 def test_server_paged_kernel_byte_identity(model_and_vars):

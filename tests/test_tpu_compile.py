@@ -20,6 +20,7 @@ import jax.numpy as jnp
 import pytest
 from jax.sharding import SingleDeviceSharding
 
+from ml_trainer_tpu.ops.kernels.decode_attention import decode_attention
 from ml_trainer_tpu.ops.kernels.slot_cache_write import (
     _position_on_lanes,
     slot_cache_write,
@@ -69,6 +70,48 @@ def test_slot_cache_write_leaves_the_donated_cache_in_place(
     assert " while(" not in text
     cache_bytes = 2 * b * h * L * d
     assert compiled.memory_analysis().temp_size_in_bytes < cache_bytes // 64
+
+
+@pytest.mark.parametrize("heads,shape,layout", [
+    (20, (32, 20, 1024, 64), "{2,3,1,0"),   # gpt2-large.batch-decode
+    (64, (64, 8, 2048, 128), "{3,2,1,0"),   # k-exaone's full layers: 8 a group
+], ids=["position_on_lanes", "position_on_sublanes"])
+def test_decode_step_reads_the_cache_where_the_write_left_it(
+        one_chip, heads, shape, layout):
+    """A layer's decode step as the slot engine states it, the write then
+    the read of each row's live blocks, compiled with the cache donated:
+    two Mosaic calls under their names, the cache in the layout both
+    foresee, and no copy or transpose of a cache-sized operand round
+    either (a wrong guess of the layout would cost two a call)."""
+    b, g, L, d = shape
+
+    def spec(shape, dtype=jnp.bfloat16):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    def step(kc, vc, q, kn, vn, idx):
+        kc, vc = slot_cache_write(kc, vc, kn, vn, idx,
+                                  implementation="pallas")
+        return kc, vc, decode_attention(q, kc, vc, idx + 1,
+                                        implementation="pallas")
+
+    compiled = jax.jit(step, donate_argnums=(0, 1)).lower(
+        spec(shape), spec(shape), spec((b, heads, 1, d)),
+        spec((b, g, 1, d)), spec((b, g, 1, d)), spec((b,), jnp.int32),
+    ).compile()
+    text = compiled.as_text()
+    entry = re.search(r"entry_computation_layout=\{\((.*?)\)->", text).group(1)
+    assert entry.startswith(f"bf16[{b},{g},{L},{d}]{layout}"), entry[:80]
+    calls = re.findall(
+        r"%(\S+) = .*? custom-call\(.*custom_call_target=\"tpu_custom_call\"",
+        text)
+    assert len(calls) == 2, calls
+    for kernel in ("slot_cache_write", "decode_attention"):
+        assert sum(kernel in name for name in calls) == 1, (kernel, calls)
+    cache = rf"bf16\[{b},{g},(?:{L},{d}|{d},{L})\]"
+    assert not re.findall(rf"= {cache}\S* (?:copy|transpose|scatter)\(", text)
+    assert " while(" not in text
+    cache_bytes = 2 * b * g * L * d
+    assert compiled.memory_analysis().temp_size_in_bytes < cache_bytes // 16
 
 
 def test_flash_kernels_compile_at_the_training_shape(one_chip):
