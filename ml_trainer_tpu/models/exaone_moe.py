@@ -38,7 +38,11 @@ import flax.linen as nn
 import jax
 import jax.numpy as jnp
 
-from ml_trainer_tpu.models.moe import GatedMLP, HeldExpertsMoE
+from ml_trainer_tpu.models.moe import (
+    GatedMLP,
+    HeldExpertsMoE,
+    held_expert_counter_args,
+)
 from ml_trainer_tpu.models.registry import register_model
 from ml_trainer_tpu.ops.attention import attention
 from ml_trainer_tpu.ops.kernels.decode_attention import (
@@ -319,15 +323,9 @@ class ExaoneMoeLM(nn.Module):
                           preferred_element_type=jnp.float32)
 
     def step_counter_args(self, counters: dict, rows_in_flight: int) -> dict:
-        """The decode step's counters as arguments of its fence span.
-        ``counters["expert_rows"][0]``: ``[expert layers, experts held]``,
-        the rows in flight whose token fell on each held expert."""
-        per_layer = counters["expert_rows"][0]
-        return {
-            "expert_rows": float(per_layer.sum(axis=1).mean()),
-            "expert_rows_max": float(per_layer.max(axis=1).mean()),
-            "routed_rows": float(rows_in_flight * self.num_experts_per_tok),
-        }
+        """The decode step's counters as arguments of its fence span."""
+        return held_expert_counter_args(
+            counters, rows_in_flight, self.num_experts_per_tok)
 
 
 def _build(kw: dict) -> ExaoneMoeLM:

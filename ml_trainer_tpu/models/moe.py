@@ -154,6 +154,20 @@ class GatedMLP(nn.Module):
 EVERY_EXPERT_ROWS = 256
 
 
+def held_expert_counter_args(counters: dict, rows_in_flight: int,
+                             num_selected: int) -> dict:
+    """A decode step's ``HeldExpertsMoE`` counters as arguments of its fence
+    span (a model's ``step_counter_args``).  ``counters["expert_rows"][0]``:
+    ``[expert layers, experts held]``, the rows in flight whose token fell on
+    each held expert."""
+    per_layer = counters["expert_rows"][0]
+    return {
+        "expert_rows": float(per_layer.sum(axis=1).mean()),
+        "expert_rows_max": float(per_layer.max(axis=1).mean()),
+        "routed_rows": float(rows_in_flight * num_selected),
+    }
+
+
 class HeldExpertsMoE(nn.Module):
     """Dropless routed feed-forward over the experts THIS chip holds.
 

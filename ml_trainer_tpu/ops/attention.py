@@ -721,13 +721,20 @@ def _kernel_specs(q):
     )
 
 
+def _padded_head(d: int) -> int:
+    """The head dim the kernel takes: a multiple of 64 up to the 128 lanes
+    (Mosaic pads 64 -> 128), whole lane tiles beyond them (the statistics
+    are spread along 128 lanes; a head of 192 is refused by the lowering)."""
+    return -(-d // 64) * 64 if d <= 128 else -(-d // 128) * 128
+
+
 def _off_tile(q, k, block_q=None, block_k=None) -> bool:
     """Whether the kernel needs ``_flash_padded``.  Blocks left to the
     chooser are multiples of 128 that divide the sequence, so 128 is the
     test; which shapes 'auto' hands the kernel does not follow the caps."""
     return bool(
         q.shape[-2] % (block_q or 128) or k.shape[-2] % (block_k or 128)
-        or q.shape[-1] % 64  # sublane-friendly head dim (Mosaic pads 64->128)
+        or q.shape[-1] != _padded_head(q.shape[-1])
     )
 
 
@@ -780,7 +787,7 @@ def _flash_padded(q, k, v, kv_lens, causal, scale, block_q, block_k,
                   interpret=False):
     """Run the flash kernel on shapes it cannot take directly, by padding.
 
-    * head_dim -> next multiple of 64: zero-padding q and k adds zero
+    * head_dim -> ``_padded_head``: zero-padding q and k adds zero
       terms to every score (q·k over the padded lanes), and zero-padding
       v makes the extra output lanes exact zeros — both sliced off, so
       the result is bit-equivalent math, not an approximation.
@@ -802,7 +809,7 @@ def _flash_padded(q, k, v, kv_lens, causal, scale, block_q, block_k,
         scale = d ** -0.5
     block = math.lcm(block_q or 128, block_k or 128)
     s_pad = -(-s // block) * block
-    d_pad = -(-d // 64) * 64
+    d_pad = _padded_head(d)
     pad = ((0, 0), (0, 0), (0, s_pad - s), (0, d_pad - d))
     qp, kp, vp = (jnp.pad(t, pad) for t in (q, k, v))
     if kv_lens is None and s_pad == s:
@@ -860,10 +867,10 @@ def attention(
     ``block_q`` / ``block_k`` left ``None`` are chosen by the kernel from
     the shape it is handed (``_flash_blocks``).  Off-tile shapes (sequence
     not divisible by 128, or by the blocks where they are given; head_dim
-    not a multiple of 64) run the kernel through ``_flash_padded`` —
-    exact math via zero-padding plus the fused kv_lens mask, at the cost
-    of the padded block's extra FLOPs.  'flash' pads whenever needed;
-    'auto' pads only from ``_AUTO_PAD_MIN_SEQ`` tokens up, where the
+    not a multiple of 64, or of 128 beyond 128) run the kernel through
+    ``_flash_padded`` — exact math via zero-padding plus the fused kv_lens
+    mask, at the cost of the padded block's extra FLOPs.  'flash' pads
+    whenever needed; 'auto' pads only from ``_AUTO_PAD_MIN_SEQ`` tokens up, where the
     O(S) memory win dominates, and otherwise falls back to XLA.
 
     'ring' runs sequence-parallel ring attention (parallel.ring) over

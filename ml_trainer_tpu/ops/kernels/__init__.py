@@ -53,6 +53,7 @@ from ml_trainer_tpu.ops.kernels.paged_attention import (  # noqa: F401
 from ml_trainer_tpu.ops.kernels.slot_cache_write import (  # noqa: F401
     slot_cache_write,
     slot_cache_write_reference,
+    slot_row_write,
 )
 from ml_trainer_tpu.ops.kernels.fused_adam import (  # noqa: F401
     adam_scalars,
@@ -80,4 +81,5 @@ __all__ = [
     "quantize_tree",
     "slot_cache_write",
     "slot_cache_write_reference",
+    "slot_row_write",
 ]

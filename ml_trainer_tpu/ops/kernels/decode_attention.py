@@ -111,18 +111,19 @@ def _decode_block(g: int, L: int, d: int, dtype) -> int:
         L, _BLOCK_BYTES // (g * d * jnp.dtype(dtype).itemsize))
 
 
-def grouped_decode_attention(q, k_cache, v_cache, valid):
+def grouped_decode_attention(q, k_cache, v_cache, valid, scale=None):
     """One query position a row against a cache that keeps the key-value
     heads only.  q: [B, H, 1, D]; caches [B, G, L, D]; valid: [B, L].  The
     H/G query heads of a key-value head form a group, so each cache row is
     read once (repeating the cache to H heads would move H/G times as
-    much)."""
+    much).  ``scale`` (default ``D ** -0.5``) is the scores' factor: a
+    latent cache's row is wider than the head the scores are scaled by."""
     b, h, _, d = q.shape
     g = k_cache.shape[1]
     qg = q.reshape(b, g, h // g, d)
     scores = jnp.einsum(
         "bgrd,bgld->bgrl", qg, k_cache, preferred_element_type=jnp.float32,
-    ) * d ** -0.5
+    ) * (d ** -0.5 if scale is None else scale)
     scores = jnp.where(
         valid[:, None, None, :], scores, jnp.finfo(jnp.float32).min)
     weights = jax.nn.softmax(scores, axis=-1)

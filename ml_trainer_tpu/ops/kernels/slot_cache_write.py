@@ -64,21 +64,25 @@ import jax.numpy as jnp
 LANES = 128
 
 
+def _write_rows(cache, new, pos, rows=None):
+    """One cache's scatter: a window ``[B, H, s, D]`` at ``pos[b]`` of row
+    ``b`` (or ``rows[b]``)."""
+    def write_row(cache_row, new_row, i):
+        # [H, L, D] <- [H, s, D] at position i of THIS row only.
+        return jax.lax.dynamic_update_slice(cache_row, new_row, (0, i, 0))
+
+    if rows is None:
+        return jax.vmap(write_row)(cache, new, pos)
+    return cache.at[rows].set(jax.vmap(write_row)(cache[rows], new, pos))
+
+
 def slot_cache_write_reference(k_cache, v_cache, k_new, v_new, pos,
                                rows=None):
     """The scatter, as the engine stated it before the kernel; a window
     ``[B, H, s, D]`` of any length ``s`` (the speculative verify window
     still takes this path)."""
-    def write_row(cache_row, new_row, i):
-        # [H, L, D] <- [H, s, D] at position i of THIS row only.
-        return jax.lax.dynamic_update_slice(cache_row, new_row, (0, i, 0))
-
-    def write(cache, new):
-        if rows is None:
-            return jax.vmap(write_row)(cache, new, pos)
-        return cache.at[rows].set(jax.vmap(write_row)(cache[rows], new, pos))
-
-    return write(k_cache, k_new), write(v_cache, v_new)
+    return (_write_rows(k_cache, k_new, pos, rows),
+            _write_rows(v_cache, v_new, pos, rows))
 
 
 def _position_on_lanes(L: int, d: int) -> bool:
@@ -97,31 +101,36 @@ def _wide(dtype):
     return jnp.float32 if jnp.dtype(dtype).itemsize < 4 else dtype
 
 
-def _sublane_kernel(rows_ref, pos_ref, k_new, v_new, k_in, v_in, k_out,
-                    v_out, *, tile):
+def _triples(refs):
+    """The kernel's operands after the scalars: the new rows, the caches
+    in, the caches out, as many of each as the call has caches."""
+    n = len(refs) // 3
+    return zip(refs[:n], refs[n:2 * n], refs[2 * n:])
+
+
+def _sublane_kernel(rows_ref, pos_ref, *refs, tile):
     """Blocks ``[1, H, tile, D]``; the new rows ``[1, H, 1, D]``."""
     from jax.experimental import pallas as pl
 
     offset = pos_ref[pl.program_id(0)] % tile
-    shape = k_in.shape[1:]
+    shape = refs[-1].shape[1:]
     hit = jax.lax.broadcasted_iota(jnp.int32, shape, 1) == offset
-    wide = _wide(k_in.dtype)
-    for new, old, out in ((k_new, k_in, k_out), (v_new, v_in, v_out)):
+    wide = _wide(refs[-1].dtype)
+    for new, old, out in _triples(refs):
         row = jnp.broadcast_to(new[0].astype(wide), shape)
         out[0] = jnp.where(hit, row, old[0].astype(wide)).astype(out.dtype)
 
 
-def _lane_kernel(rows_ref, pos_ref, k_new, v_new, k_in, v_in, k_out, v_out,
-                 *, tile):
+def _lane_kernel(rows_ref, pos_ref, *refs, tile):
     """Blocks ``[1, H, D, tile]``; the new rows ``[1, D, H padded]``, a
     head's values down one column."""
     from jax.experimental import pallas as pl
 
     offset = pos_ref[pl.program_id(0)] % tile
-    _, heads, d, _ = k_in.shape
+    _, heads, d, _ = refs[-1].shape
     hit = jax.lax.broadcasted_iota(jnp.int32, (d, tile), 1) == offset
-    wide = _wide(k_in.dtype)
-    for new, old, out in ((k_new, k_in, k_out), (v_new, v_in, v_out)):
+    wide = _wide(refs[-1].dtype)
+    for new, old, out in _triples(refs):
         columns = new[0].astype(wide)                           # [D, Hp]
         for h in range(heads):
             row = jnp.broadcast_to(columns[:, h:h + 1], (d, tile))
@@ -134,13 +143,15 @@ def _lane_kernel(rows_ref, pos_ref, k_new, v_new, k_in, v_in, k_out, v_out,
 # call to Mosaic takes about 0.15 s, which 36 layers would pay on every
 # start of a server, compile cache or not.
 @functools.partial(jax.jit, static_argnames=("interpret",))
-def _slot_cache_write_pallas(k_cache, v_cache, k_new, v_new, rows, pos,
-                             interpret):
+def _slot_cache_write_pallas(caches, news, rows, pos, interpret):
+    """``caches``, ``news``: tuples of as many caches of ONE shape and dtype
+    (K and V; a latent cache alone) and the row each takes."""
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
-    n, h, L, d = k_cache.shape
-    b = k_new.shape[0]
+    count = len(caches)
+    n, h, L, d = caches[0].shape
+    b = news[0].shape[0]
     # As jax.lax.dynamic_update_slice reads a start: a negative one counts
     # from the end, and the result is clamped into the array.
     pos = jnp.clip(jnp.where(pos < 0, pos + L, pos), 0, L - 1)
@@ -153,15 +164,14 @@ def _slot_cache_write_pallas(k_cache, v_cache, k_new, v_new, rows, pos,
             new = new[:, :, 0, :].transpose(0, 2, 1)
             return jnp.pad(new, ((0, 0), (0, 0), (0, padded - h)))
 
-        caches = [c.transpose(0, 1, 3, 2) for c in (k_cache, v_cache)]
-        news = [arrange(k_new), arrange(v_new)]
+        caches = [c.transpose(0, 1, 3, 2) for c in caches]
+        news = [arrange(new) for new in news]
         new_spec = pl.BlockSpec((1, d, padded), lambda bi, r, p: (bi, 0, 0))
         cache_spec = pl.BlockSpec(
             (1, h, d, tile), lambda bi, r, p: (r[bi], 0, 0, p[bi] // tile))
         kernel = _lane_kernel
     else:
-        tile = min(max(8, 32 // k_cache.dtype.itemsize), L)
-        caches, news = [k_cache, v_cache], [k_new, v_new]
+        tile = min(max(8, 32 // caches[0].dtype.itemsize), L)
         new_spec = pl.BlockSpec((1, h, 1, d), lambda bi, r, p: (bi, 0, 0, 0))
         cache_spec = pl.BlockSpec(
             (1, h, tile, d), lambda bi, r, p: (r[bi], 0, p[bi] // tile, 0))
@@ -169,22 +179,47 @@ def _slot_cache_write_pallas(k_cache, v_cache, k_new, v_new, rows, pos,
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2,
         grid=(b,),
-        in_specs=[new_spec, new_spec, cache_spec, cache_spec],
-        out_specs=[cache_spec, cache_spec],
+        in_specs=[new_spec] * count + [cache_spec] * count,
+        out_specs=[cache_spec] * count,
     )
     shape = jax.ShapeDtypeStruct(caches[0].shape, caches[0].dtype)
     out = pl.pallas_call(
         functools.partial(kernel, tile=tile),
         grid_spec=grid_spec,
-        out_shape=[shape, shape],
-        # Operands count from the scalars: 4 and 5 are the two caches.
-        input_output_aliases={4: 0, 5: 1},
+        out_shape=[shape] * count,
+        # Operands count from the scalars: after the two of them and the
+        # new rows come the caches (4 and 5 where there are two).
+        input_output_aliases={2 + count + i: i for i in range(count)},
         interpret=interpret,
         name="slot_cache_write",
     )(rows, pos, *news, *caches)
     if on_lanes:
         out = [c.transpose(0, 1, 3, 2) for c in out]
     return tuple(out)
+
+
+def _write(caches, news, pos, rows, implementation, interpret, name):
+    """What both entry points do once their shapes are checked: the
+    reference's scatter, or the kernel over as many caches as they hand."""
+    news = tuple(n.astype(c.dtype) for n, c in zip(news, caches))
+    if implementation == "auto":
+        implementation = (
+            "pallas" if jax.default_backend() == "tpu" else "reference"
+        )
+    if implementation in ("reference", "xla"):
+        return tuple(_write_rows(c, n, pos, rows)
+                     for c, n in zip(caches, news))
+    if implementation != "pallas":
+        raise ValueError(
+            f"Unknown {name} implementation {implementation!r}; "
+            "expected 'auto', 'pallas', or 'reference'"
+        )
+    if rows is None:
+        rows = jnp.arange(pos.shape[0], dtype=jnp.int32)
+    return _slot_cache_write_pallas(
+        caches, news, jnp.asarray(rows, jnp.int32),
+        jnp.asarray(pos, jnp.int32), interpret,
+    )
 
 
 def slot_cache_write(
@@ -220,23 +255,27 @@ def slot_cache_write(
         )
     if rows is None and b != n:
         raise ValueError(f"{b} positions for {n} cache rows and no `rows`")
-    k_new, v_new = k_new.astype(k_cache.dtype), v_new.astype(v_cache.dtype)
-    if implementation == "auto":
-        implementation = (
-            "pallas" if jax.default_backend() == "tpu" else "reference"
-        )
-    if implementation in ("reference", "xla"):
-        return slot_cache_write_reference(
-            k_cache, v_cache, k_new, v_new, pos, rows
-        )
-    if implementation != "pallas":
+    return _write((k_cache, v_cache), (k_new, v_new), pos, rows,
+                  implementation, interpret, "slot_cache_write")
+
+
+def slot_row_write(
+    cache: jax.Array,
+    new: jax.Array,
+    pos: jax.Array,
+    *,
+    implementation: str = "auto",
+    interpret: bool = False,
+):
+    """The same write for a cache that is ONE leaf (a latent cache: keys and
+    values are both read from it): ``new[b]`` (``[B, H, 1, D]``) at position
+    ``pos[b]`` of row ``b`` of ``cache`` (``[B, H, L, D]``).  One kernel, one
+    contract (the clamp, the layouts): the call above with one cache."""
+    b, h, L, d = cache.shape
+    if new.shape != (b, h, 1, d) or pos.shape != (b,):
         raise ValueError(
-            f"Unknown slot_cache_write implementation {implementation!r}; "
-            "expected 'auto', 'pallas', or 'reference'"
+            f"new must be {(b, h, 1, d)} and pos [{b}] (one position a row "
+            f"of the cache {cache.shape}), got {new.shape} and {pos.shape}"
         )
-    if rows is None:
-        rows = jnp.arange(b, dtype=jnp.int32)
-    return _slot_cache_write_pallas(
-        k_cache, v_cache, k_new, v_new, jnp.asarray(rows, jnp.int32),
-        jnp.asarray(pos, jnp.int32), interpret,
-    )
+    return _write((cache,), (new,), pos, None, implementation, interpret,
+                  "slot_row_write")[0]

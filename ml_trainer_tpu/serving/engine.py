@@ -1287,7 +1287,8 @@ class SlotDecodeEngine:
             if self._lora_on else ()
         )
         with span("serve_prefill", prompt_len=p, bucket=bucket, slot=slot,
-                  request=req.id, tenant=req.tenant):
+                  request=req.id, tenant=req.tenant, prompt_tokens=p,
+                  bucket_tokens=bucket):
             cache1, tok0 = run(
                 self.params, padded, np.int32(p),
                 jnp.asarray(req.temperature, jnp.float32), key,

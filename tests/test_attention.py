@@ -419,15 +419,21 @@ def test_flash_on_a_declared_mesh_runs_in_shard_map(shape, monkeypatch):
     # 1,100 pads to 1,152 = 9 x 128 and runs in blocks the chooser picks
     # among ITS divisors (384 x 384 in float32).
     pytest.param(1100, 64, (None, None), id="s1100-chosen"),
+    # A latent layer's keys of 128 + 64: past the 128 lanes the kernel
+    # takes whole lane tiles (the lowering refuses 192), so 192 pads to 256.
+    pytest.param(128, 192, (None, None), id="s128-d192"),
 ])
 @pytest.mark.parametrize("causal", [False, True])
 def test_flash_padded_off_tile_shapes_match_reference(causal, s, d, blocks):
     """VERDICT r2 weak #7 (remaining half): off-tile shapes — a ViT-like
     sequence (197) and a head_dim that is not a multiple of 64 — run the
     kernel through the zero-padding wrapper with exact-math results."""
-    from ml_trainer_tpu.ops.attention import _flash_padded
+    from ml_trainer_tpu.ops.attention import (_flash_padded, _off_tile,
+                                              _padded_head)
 
     q, k, v = qkv(b=2 if s < 1024 else 1, h=2, s=s, d=d, seed=8)
+    assert _off_tile(q, k, *blocks)
+    assert _padded_head(d) == {48: 64, 64: 64, 192: 256}[d]
     ref = dot_product_attention(q, k, v, causal=causal)
     out = _flash_padded(q, k, v, None, causal, None, *blocks, interpret=True)
     assert out.shape == q.shape

@@ -301,6 +301,35 @@ def test_slot_cache_write_refusals():
                          implementation="scatter")
 
 
+@pytest.mark.parametrize("shape", [(4, 1, 256, 72), (4, 1, 64, 128)],
+                         ids=["lanes", "sublanes"])
+def test_slot_row_write_is_the_same_write_with_one_cache(shape):
+    """A latent cache is ONE leaf under one head (``models/kimi_linear.py``:
+    a row of 512 + 64 values, here 72 and 128): the kernel called with one
+    cache against the scatter, bit for bit, in both layouts, rows at
+    different positions and the clamp past the end."""
+    from ml_trainer_tpu.ops.kernels import slot_row_write
+
+    b, h, L, d = shape
+    assert _position_on_lanes(L, d) == (d == 72)
+    rng = np.random.default_rng(5)
+    cache = jnp.asarray(rng.normal(size=shape), jnp.bfloat16)
+    new = jnp.asarray(rng.normal(size=(b, h, 1, d)), jnp.bfloat16)
+    pos = jnp.asarray([0, 17, L - 1, L + 500], jnp.int32)
+    want = slot_row_write(cache, new, pos, implementation="reference")
+    got = slot_row_write(cache, new, pos, implementation="pallas",
+                         interpret=True)
+    assert np.array_equal(_bits(got), _bits(want))
+    expect = _bits(cache).copy()
+    for row, p in enumerate([0, 17, L - 1, L - 1]):
+        expect[row, :, p, :] = _bits(new)[row, :, 0, :]
+    assert np.array_equal(_bits(got), expect)
+    with pytest.raises(ValueError, match="one position a row"):
+        slot_row_write(cache, new[:, :, 0], pos)
+    with pytest.raises(ValueError, match="Unknown slot_row_write"):
+        slot_row_write(cache, new, pos, implementation="scatter")
+
+
 # ---------------------------------------------------- decode_attention
 # [B, G, L, D] per layout, as for the write above; blocks of 128 positions,
 # so a row meets one, two or all three of its blocks.

@@ -20,10 +20,14 @@ import jax.numpy as jnp
 import pytest
 from jax.sharding import SingleDeviceSharding
 
-from ml_trainer_tpu.ops.kernels.decode_attention import decode_attention
+from ml_trainer_tpu.ops.kernels.decode_attention import (
+    decode_attention,
+    grouped_decode_attention,
+)
 from ml_trainer_tpu.ops.kernels.slot_cache_write import (
     _position_on_lanes,
     slot_cache_write,
+    slot_row_write,
 )
 
 
@@ -112,6 +116,41 @@ def test_decode_step_reads_the_cache_where_the_write_left_it(
     assert " while(" not in text
     cache_bytes = 2 * b * g * L * d
     assert compiled.memory_analysis().temp_size_in_bytes < cache_bytes // 16
+
+
+def test_a_latent_cache_is_written_and_read_where_it_lies(one_chip):
+    """``kimi-linear-48b-ep8``'s latent layer, 128 slots of 4,096 rows of
+    512 + 64 values under ONE head, as the slot engine states a decode step:
+    ``slot_row_write`` (the kernel's call with one cache) then XLA's masked
+    read of the leaf as keys AND values by 32 query heads.  A row of 576 pads
+    less on the sublanes than on the lanes, so the position goes on the
+    lanes; one Mosaic call, the cache donated and never copied, transposed
+    or scattered, and the temporaries are the scores, not a cache."""
+    b, L, d, heads = 128, 4096, 576, 32
+
+    def spec(shape, dtype=jnp.bfloat16):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    def step(cache, q, new, idx):
+        cache = slot_row_write(cache, new, idx, implementation="pallas")
+        valid = jnp.arange(L)[None, :] <= idx[:, None]
+        return cache, grouped_decode_attention(
+            q, cache, cache, valid, scale=192 ** -0.5)
+
+    compiled = jax.jit(step, donate_argnums=(0,)).lower(
+        spec((b, 1, L, d)), spec((b, heads, 1, d)), spec((b, 1, 1, d)),
+        spec((b,), jnp.int32)).compile()
+    text = compiled.as_text()
+    entry = re.search(r"entry_computation_layout=\{\((.*?)\)->", text).group(1)
+    assert entry.startswith(f"bf16[{b},1,{L},{d}]{{2,3,1,0"), entry[:80]
+    assert _position_on_lanes(L, d)
+    assert text.count('custom_call_target="tpu_custom_call"') == 1
+    assert "slot_cache_write" in text
+    cache = rf"bf16\[{b},1,(?:{L},{d}|{d},{L})\]"
+    assert not re.findall(rf"= {cache}\S* (?:copy|transpose|scatter)\(", text)
+    assert " while(" not in text
+    scores = 4 * b * heads * L
+    assert compiled.memory_analysis().temp_size_in_bytes < 1.5 * scores
 
 
 def test_flash_kernels_compile_at_the_training_shape(one_chip):
