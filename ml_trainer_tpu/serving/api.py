@@ -842,7 +842,32 @@ class Server:
                 "server closed" if self.healthy
                 else f"serving engine unhealthy: {self._unhealthy_reason}"
             )
+            # A step still in flight: its tokens reach their requests
+            # before those are failed, unless the engine is what failed
+            # (a wedged device would hold this thread in the fence).
+            if self.healthy:
+                try:
+                    self._land_step()
+                except Exception as e:  # noqa: BLE001 — shutdown must finish
+                    self._log.error(
+                        "serving_engine_error",
+                        error=f"{type(e).__name__}: {e}",
+                    )
+            self.engine.abandon()
             self._fail_all(msg, release_slots=True)
+
+    def _land_step(self) -> int:
+        """Fence and deliver the decode step in flight, if any (loop
+        thread only); returns how many slots that freed.  The loop
+        dispatches each step before it lands the one before
+        (``engine.advance``); whatever is not a decode step (an
+        admission, an adoption, a chunk window, an export, an
+        evacuation, shutdown) lands first and so sees the engine as a
+        synchronous ``engine.step()`` leaves it."""
+        freed = self.engine.land()
+        for slot in freed:
+            self.scheduler.release(slot)
+        return len(freed)
 
     def _drain_adoptions(self) -> bool:
         """Import queued KV adoptions into free slots (loop thread only).
@@ -880,9 +905,10 @@ class Server:
                 # next free slot goes to the oldest adoption.
                 self._adoptions.appendleft((req, export, resolver))
                 break
-            # Tracked like a prefill admission: a crash mid-import is
-            # visible to the watchdog/error handler (the request is not
-            # in engine._active yet) and fails its stream instead of
+            # Tracked like a prefill admission: a crash in the landing
+            # (a device fence) or mid-import is visible to the
+            # watchdog/error handler (the request is not in
+            # engine._active yet) and fails its stream instead of
             # hanging the client.
             from ml_trainer_tpu.serving.transfer import (
                 MigrationCorrupt,
@@ -891,6 +917,7 @@ class Server:
 
             self._admitting_req = req
             try:
+                self._land_step()
                 status = engine.import_slot(req, slot, export)
             except MigrationCorrupt as e:
                 # The payload failed its CRC gate AT import (the router
@@ -922,6 +949,12 @@ class Server:
                     sched.requeue(req)
                 progressed = True
                 continue
+            except Exception as e:  # noqa: BLE001 — the loop's handler ends it
+                # The loop fails the request with the rest; a remote
+                # router hears so now and not at its time-out.
+                if resolver is not None:
+                    resolver("error", f"{type(e).__name__}: {e}")
+                raise
             self._admitting_req = None
             if status == "no_memory":
                 sched.release(slot)
@@ -1023,6 +1056,7 @@ class Server:
         this returns."""
         sink, self._evacuate_sink = self._evacuate_sink, None
         engine, sched = self.engine, self.scheduler
+        self._land_step()
         for slot in sorted(engine._active):
             req = engine._active[slot]
             export = engine.export_slot(slot)
@@ -1098,17 +1132,27 @@ class Server:
                 # another replica — making them wait behind fresh
                 # admissions would waste that work under load.
                 progressed = self._drain_adoptions()
-                while engine.free_capacity() > 0:
+                # Every free slot is filled, but for those that the
+                # landing below frees: the step that frees them is one
+                # the synchronous order runs AFTER this admission, so
+                # they are filled an iteration later, after a decode
+                # step.  Filled at once they would put two prefills into
+                # one gap between tokens where the synchronous order has
+                # one in each of two (the tail of the inter-token times).
+                held_back = 0
+                while engine.free_capacity() > held_back:
                     got = sched.acquire()
                     if got is None:
                         break
                     req, slot = got
-                    self._maybe_slow()
-                    # Tracked so a wedge or crash DURING prefill (request
-                    # popped from the queue, not yet in engine._active)
-                    # is still visible to the watchdog/error handler and
+                    # Tracked so a wedge or crash in the landing (a
+                    # device fence) or DURING prefill (request popped
+                    # from the queue, not yet in engine._active) is
+                    # still visible to the watchdog/error handler and
                     # failed with the rest instead of hanging its stream.
                     self._admitting_req = req
+                    held_back += self._land_step()
+                    self._maybe_slow()
                     status = engine.admit(req, slot)
                     self._admitting_req = None
                     progressed = True
@@ -1129,15 +1173,21 @@ class Server:
                 # AFTER admissions — short requests admit (and decode,
                 # below) between a long prompt's windows instead of
                 # waiting out its whole prefill.
+                if engine.chunking_count():
+                    self._land_step()
                 for slot, req, status in engine.advance_chunks():
                     progressed = True
                     if status == "finished":
                         sched.release(slot)
                     elif status == "active" and req.migration_sink is not None:
                         self._export_for_migration(req, slot)
-                if engine.active_count():
+                if engine.active_count() or engine.in_flight():
+                    # One step of lookahead: dispatch step n+1, THEN read
+                    # and deliver step n's tokens, so the device never
+                    # waits for the delivery or the next dispatch.  The
+                    # last step lands here too, with nothing left active.
                     self._maybe_slow()
-                    for slot in engine.step():
+                    for slot in engine.advance():
                         sched.release(slot)
                     # Preempt-and-requeue victims resume from their
                     # committed tokens (head of their tenant queue).
@@ -1156,6 +1206,8 @@ class Server:
                 err = f"{type(e).__name__}: {e}"
                 self._log.error("serving_engine_error", error=err)
                 self.metrics.record_engine_error()
+                # Their riders fail below: no token follows the error.
+                engine.abandon()
                 admitting, self._admitting_req = self._admitting_req, None
                 if admitting is not None and admitting.state == "active":
                     # Crashed mid-prefill: not in engine._active yet, so

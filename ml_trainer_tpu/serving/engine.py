@@ -27,7 +27,10 @@ ONE compiled per-token decode program:
   ``[max_batch, 1]`` tokens in, one forward, per-row sampling out.
   Requests join (prefill + insert) and leave (EOS / budget / deadline)
   at token boundaries with NO recompilation: shapes are static, inactive
-  slots just compute masked garbage that nobody reads.
+  slots just compute masked garbage that nobody reads.  ``step()`` is
+  dispatch, fence, deliver; the serving loop calls ``advance()``, the
+  same turn with the landing one step behind the dispatch, so the
+  device never waits for the host between two steps (docs/serving.md).
 
 Sampling matches ``generate()`` token-for-token per request: greedy is
 ``argmax``; ``temperature > 0`` draws
@@ -79,6 +82,8 @@ bound covers every decode executable in the process.
 
 from __future__ import annotations
 
+import collections
+import dataclasses
 import inspect
 import time
 from typing import Dict, List, Optional
@@ -133,10 +138,24 @@ def _leaf_name(path) -> Optional[str]:
     return getattr(path[-1], "key", None) if path else None
 
 
+@dataclasses.dataclass
+class _DecodeStep:
+    """A decode step between its dispatch and its landing: who was in
+    which slot when it was dispatched, and where its outputs are."""
+
+    seq: int
+    riders: Dict[int, Request]
+    tok: jax.Array                  # [max_batch, 1]; its host copy started
+    counted: Optional[dict]         # a ``step_counter_args`` model's counts
+    dispatched_at: float
+    secs: float = 0.0               # set at the fence (``_fence``)
+
+
 class SlotDecodeEngine:
     """The slot cache plus its compiled programs.  Single-threaded by
     design: one worker (serving/api.py's loop) calls ``admit`` and
-    ``step``; thread-safe admission lives in the scheduler."""
+    ``advance`` (or the synchronous ``step``); thread-safe admission
+    lives in the scheduler."""
 
     def __init__(self, model, variables: dict, max_batch: int = 8,
                  metrics: Optional[ServingMetrics] = None,
@@ -436,6 +455,10 @@ class SlotDecodeEngine:
             )
         self._active: Dict[int, Request] = {}
         self._step_seq = 0  # decode steps run (the decode_wedge fault clock)
+        # Steps dispatched and not yet landed, oldest first: at most one
+        # between two calls, two inside ``advance()``.
+        self._flying: collections.deque = collections.deque()
+        self._landed_at = 0.0  # perf_counter at the last landing's fence
         # Overload control (serving/overload.py, set via
         # Server.set_degradation): the active degradation-ladder rung
         # (0 = full service), the retry_after a shed client is told,
@@ -563,7 +586,7 @@ class SlotDecodeEngine:
                 nxt = _sample_rows(logits[:, -1], temps, rngs, steps)
                 return mut["cache"], nxt[:, None].astype(jnp.int32)
 
-            return jax.jit(step_quant, donate_argnums=(1, 2))
+            return jax.jit(step_quant, donate_argnums=(1,))
 
         if self._lora_on:
             def step_lora(params, cache, tok, temps, rngs, steps, lora):
@@ -574,7 +597,7 @@ class SlotDecodeEngine:
                 nxt = _sample_rows(logits[:, -1], temps, rngs, steps)
                 return mut["cache"], nxt[:, None].astype(jnp.int32)
 
-            return jax.jit(step_lora, donate_argnums=(1, 2))
+            return jax.jit(step_lora, donate_argnums=(1,))
 
         if self._counter_args is not None:
             def step_counted(params, cache, tok, temps, rngs, steps,
@@ -593,7 +616,7 @@ class SlotDecodeEngine:
                 )
                 return mut["cache"], nxt[:, None].astype(jnp.int32), counted
 
-            return jax.jit(step_counted, donate_argnums=(1, 2))
+            return jax.jit(step_counted, donate_argnums=(1,))
 
         def step(params, cache, tok, temps, rngs, steps):
             logits, mut = dm.apply(
@@ -603,7 +626,7 @@ class SlotDecodeEngine:
             nxt = _sample_rows(logits[:, -1], temps, rngs, steps)
             return mut["cache"], nxt[:, None].astype(jnp.int32)
 
-        return jax.jit(step, donate_argnums=(1, 2))
+        return jax.jit(step, donate_argnums=(1,))
 
     # -- batched LoRA adapters (serving/adapter_pool.py) -----------------
 
@@ -1056,6 +1079,7 @@ class SlotDecodeEngine:
         — the caller releases the slot afterwards if it migrates."""
         from ml_trainer_tpu.serving.transfer import export_kv_slot
 
+        self._must_have_landed("export_slot")
         return export_kv_slot(self, slot)
 
     def import_slot(self, req: Request, slot: int, export) -> str:
@@ -1065,6 +1089,7 @@ class SlotDecodeEngine:
         which resumes via the ordinary preempt-resume prefill)."""
         from ml_trainer_tpu.serving.transfer import import_kv_slot
 
+        self._must_have_landed("import_slot")
         return import_kv_slot(self, req, slot, export)
 
     # -- serving ---------------------------------------------------------
@@ -1082,6 +1107,7 @@ class SlotDecodeEngine:
         """``_admit`` (which see for the statuses returned) under its
         span: bookkeeping, prefill dispatch and the fence on the first
         token are one stall of every decoding slot."""
+        self._must_have_landed("admit")
         with span("serve_admit", request=req.id, prompt_len=len(req.prompt)):
             return self._admit(req, slot)
 
@@ -1427,6 +1453,9 @@ class SlotDecodeEngine:
         window landed, request now decoding), or ``"finished"``
         (completed/cancelled/expired on its first token — the caller
         recycles the slot)."""
+        if not self._chunked:
+            return []
+        self._must_have_landed("advance_chunks")
         out: List[tuple] = []
         now = time.monotonic()
         for slot in sorted(self._chunked):
@@ -1530,9 +1559,10 @@ class SlotDecodeEngine:
             np.int32(slot), np.int32(p),
         )
 
-    def _in_flight(self) -> np.ndarray:
-        """1 for each slot that holds a running request, for the counters
-        of a decode step (a free slot computes garbage nobody counts)."""
+    def _active_rows(self) -> np.ndarray:
+        """1 for each slot that holds a running request: the rows a
+        decode step counts (a free slot computes garbage nobody counts)
+        and whose ``fold_in`` counter its dispatch advances."""
         rows = np.zeros((self.max_batch,), np.int32)
         rows[list(self._active)] = 1
         return rows
@@ -1568,87 +1598,210 @@ class SlotDecodeEngine:
         return freed
 
     def step(self) -> List[int]:
-        """One compiled decode step over all slots; distributes each
-        active slot's token(s) and returns the slots freed this step
-        (finished, expired, cancelled, or preempted).  In spec mode
-        each slot advances 1..spec_k+1 tokens."""
-        if not self._active:
-            return []
-        with span("serve_prepare", engine_step=self._step_seq + 1):
-            cancel_freed = self._sweep_cancelled()
-            if not self._active:
-                return cancel_freed
-            self._step_seq += 1
-            # Flight record BEFORE the dispatch: when this step wedges,
-            # the ring's newest decode_step record names the step — and
-            # the REQUESTS riding it — that the watchdog dump blames.
-            step_requests = [
-                req.id for _, req in sorted(self._active.items())
-            ]
-            self._flight.record(
-                "decode_step", engine_step=self._step_seq,
-                active=len(self._active), spec=bool(self.spec_k),
-                requests=step_requests,
-            )
-            self._profiler.on_step(self._step_seq)
-            # decode_wedge injection hook (resilience/faults.py): block
-            # like a wedged device program would — the serving watchdog's
-            # job is to fail the waiting clients while this thread is
-            # stuck here.
-            from ml_trainer_tpu.resilience.faults import active_plan
+        """One compiled decode step over all slots, synchronous: dispatch,
+        fence, deliver, in that order; each active slot's token(s) are
+        with its request on return.  Returns the slots freed this step
+        (finished, expired, cancelled, or preempted).  In spec mode each
+        slot advances 1..spec_k+1 tokens."""
+        return self._turn(look_ahead=False)
 
-            plan = active_plan()
-            if plan is not None:
-                fault = plan.fire("decode_wedge", step=self._step_seq)
-                if fault is not None:
-                    plan.hold_wedge(fault)
-            spec_now = bool(self.spec_k and self.spec_enabled)
-            preempt_freed: List[int] = cancel_freed
-            if self.paged:
-                preempt_freed = preempt_freed + self._ensure_pages(
-                    self.spec_k + 1 if spec_now else 1
-                )
-                self._sync_table()
-                if not self._active:
-                    return preempt_freed
-        if spec_now:
-            return preempt_freed + self._step_spec()
-        active_before = len(self._active)
-        t0 = time.perf_counter()
+    def advance(self) -> List[int]:
+        """The serving loop's step: the same turn as ``step()`` with the
+        landing one step behind.  While step n runs on the device the
+        host prepares and dispatches step n+1, and only then reads and
+        delivers step n's tokens, so the device goes from one step
+        straight into the next and delivery happens under a busy device.
+        Each call returns the slots freed by the step it LANDED (and by
+        its own preparation); one step stays in flight until the next
+        call or ``land()``."""
+        return self._turn(look_ahead=True)
+
+    def land(self) -> List[int]:
+        """Fence and deliver the step in flight (none: nothing happens).
+        Whatever is not a decode step calls this first, so an admission,
+        a chunk window, an export or an import sees the engine as
+        ``step()`` leaves it."""
+        if not self._flying:
+            return []
+        landing = self._flying.popleft()
+        with self._decode_span(landing.seq, landing.riders):
+            toks = self._fence(landing)
+        return self._deliver(landing, toks)
+
+    def _decode_span(self, seq: int, riders: Dict[int, Request]):
+        return span("serve_decode", engine_step=seq, active=len(riders),
+                    requests=[req.id for _, req in sorted(riders.items())])
+
+    def in_flight(self) -> bool:
+        """A dispatched step has not been landed yet."""
+        return bool(self._flying)
+
+    def _must_have_landed(self, what: str) -> None:
+        """Whatever is not a decode step reads or donates what a step in
+        flight still owns (``tok``, the host mirrors, ``_active``)."""
+        if self._flying:
+            raise RuntimeError(
+                f"{what} with a decode step in flight: land() it first"
+            )
+
+    def abandon(self) -> None:
+        """Forget the steps in flight without delivering them: the
+        caller is failing their requests (an engine error, a wedged
+        device), and a token pushed after that would follow a gap."""
+        self._flying.clear()
+
+    def _turn(self, look_ahead: bool) -> List[int]:
+        """One turn of the decode engine: prepare and dispatch a step,
+        then fence and deliver the OLDEST step in flight, unless that is
+        the step just dispatched and ``look_ahead`` leaves it to the
+        next turn.
+
+        The engine looks ahead only when nothing a step needs comes
+        from the step before it, which it tells from its own state, once
+        a turn: the speculative step drafts from the last tokens on the
+        host, and a paged step may preempt a victim whose ``req.tokens``
+        would be one short of what the device has written.  Both land
+        first: the synchronous order is this code with nothing in
+        flight when the step is prepared."""
+        drafting = bool(self.spec_k and self.spec_enabled)
+        look_ahead = look_ahead and not (self.paged or drafting)
+        freed = [] if look_ahead else self.land()
+        go = False
+        if self._active:
+            with span("serve_prepare", engine_step=self._step_seq + 1):
+                prepared, go = self._prepare(drafting)
+            freed = freed + prepared
+        if not go:
+            # Nothing to dispatch for: the last step lands alone.
+            return freed + self.land()
+        if drafting:
+            return freed + self._step_spec()
+        landing = None
+        with self._decode_span(self._step_seq, self._active):
+            self._dispatch()
+            if len(self._flying) > 1 or not look_ahead:
+                landing = self._flying.popleft()
+                toks = self._fence(landing)
+        if landing is not None:
+            freed = freed + self._deliver(landing, toks)
+        return freed
+
+    def _prepare(self, drafting: bool):
+        """What a step needs before its dispatch: the cancelled swept,
+        the flight record, the profiler's and the fault plan's hooks,
+        and in paged mode the pages of the step's writes.  Returns the
+        slots it freed and whether anything is left to dispatch for."""
+        freed = self._sweep_cancelled()
+        if not self._active:
+            return freed, False
+        self._step_seq += 1
+        # Flight record BEFORE the dispatch: when this step wedges,
+        # the ring's newest decode_step record names the step — and
+        # the REQUESTS riding it — that the watchdog dump blames.
+        self._flight.record(
+            "decode_step", engine_step=self._step_seq,
+            active=len(self._active), spec=bool(self.spec_k),
+            requests=[req.id for _, req in sorted(self._active.items())],
+        )
+        self._profiler.on_step(self._step_seq)
+        # decode_wedge injection hook (resilience/faults.py): block
+        # like a wedged device program would — the serving watchdog's
+        # job is to fail the waiting clients while this thread is
+        # stuck here.
+        from ml_trainer_tpu.resilience.faults import active_plan
+
+        plan = active_plan()
+        if plan is not None:
+            fault = plan.fire("decode_wedge", step=self._step_seq)
+            if fault is not None:
+                plan.hold_wedge(fault)
+        if self.paged:
+            freed = freed + self._ensure_pages(
+                self.spec_k + 1 if drafting else 1
+            )
+            self._sync_table()
+        return freed, bool(self._active)
+
+    def _dispatch(self) -> None:
+        """Enqueue one decode step for the slots active now and put it in
+        flight.  Nothing here waits for the device: the step's input
+        token is the last step's output, still on the device, and the
+        host mirrors (``_steps``, the ``fold_in`` counter, and ``_pos``)
+        advance HERE, so the next dispatch needs nothing this step
+        produces."""
+        rows = self._active_rows()
         extra = (
             (self._lora_vars(self._adapter_rows),) if self._lora_on
             else (self._quant,) if self.quant_int8
-            else (self._in_flight(),) if self._counter_args is not None
+            else (rows,) if self._counter_args is not None
             else ()
         )
-        with span("serve_decode", engine_step=self._step_seq,
-                  active=active_before, requests=step_requests):
-            with span("serve_decode.dispatch"):
-                self.cache, self.tok, *counted = self._decode(
-                    self.params, self.cache, self.tok,
-                    self._temps, self._rngs, self._steps, *extra,
-                )
-            with span("serve_decode.fence") as fence_args:
-                # The step's ONE fence: every later read this iteration
-                # is host data.  # graft-lint: sync-ok
-                toks = np.asarray(self.tok[:, 0])  # blocks: the step landed
-                if counted:
-                    # The counters left the device with the tokens: the
-                    # same fence.  # graft-lint: sync-ok
-                    landed = jax.device_get(counted[0])
-                    fence_args.update(
-                        self._counter_args(landed, active_before))
-        dt = time.perf_counter() - t0
-        with span("serve_deliver", emitted=0, freed=0) as delivered:
-            # Host mirror of the device's idx += 1 (every row advances).
-            self._pos = np.minimum(
-                self._pos + 1, self.max_len
-            ).astype(np.int32)
+        with span("serve_decode.dispatch", engine_step=self._step_seq,
+                  ahead=int(bool(self._flying))):
+            # A step that starts before the last one landed starts its
+            # clock at that landing (``_fence``).
+            t0 = time.perf_counter()
+            self.cache, self.tok, *counted = self._decode(
+                self.params, self.cache, self.tok,
+                self._temps, self._rngs, self._steps, *extra,
+            )
+            # The tokens leave as a plain transfer that waits for THIS
+            # step only; a slice program issued later would queue behind
+            # the step dispatched after it.
+            self.tok.copy_to_host_async()
+            for leaf in jax.tree.leaves(counted):
+                leaf.copy_to_host_async()
+        if self._flying:
+            self.metrics.record_dispatch_ahead()
+        self._flying.append(_DecodeStep(
+            seq=self._step_seq, riders=dict(self._active), tok=self.tok,
+            counted=counted[0] if counted else None, dispatched_at=t0,
+        ))
+        # New arrays, not updates in place: a step in flight may still be
+        # reading the ones it was called with.
+        self._steps = self._steps + rows
+        self._pos = np.minimum(self._pos + 1, self.max_len).astype(np.int32)
+
+    def _fence(self, landing: "_DecodeStep") -> np.ndarray:
+        """Wait for ``landing``'s tokens.  The step's seconds run from
+        the later of its own dispatch and the previous landing: in
+        steady overlap the period from landing to landing.  Consecutive
+        steps' seconds add up to first dispatch to last landing, so
+        their sum is never less than the device's time for them (one
+        sample can be: a landing the host came late to shortens the
+        next), and none holds an admission, which lands everything
+        first."""
+        with span("serve_decode.fence",
+                  engine_step=landing.seq) as fence_args:
+            # The step's ONE fence: every later read of it is host
+            # data.  # graft-lint: sync-ok
+            toks = np.asarray(landing.tok)[:, 0]  # blocks: the step landed
+            if landing.counted is not None:
+                # The counters left the device with the tokens: the
+                # same fence.  They count the rows the step was
+                # dispatched for, a row dropped at delivery among them.
+                # graft-lint: sync-ok
+                fence_args.update(self._counter_args(
+                    jax.device_get(landing.counted), len(landing.riders)))
+        now = time.perf_counter()
+        landing.secs = now - max(landing.dispatched_at, self._landed_at)
+        self._landed_at = now
+        return toks
+
+    def _deliver(self, landing: "_DecodeStep", toks: np.ndarray) -> List[int]:
+        """Hand each row's token to the request the step was dispatched
+        for, if that request still holds the slot.  A row whose request
+        finished, expired or was cancelled while the step was in flight
+        computed a token nobody asked for: dropped, counted."""
+        with span("serve_deliver", engine_step=landing.seq, emitted=0,
+                  freed=0, dropped=0) as delivered:
             freed: List[int] = []
-            emitted = 0
+            emitted = dropped = 0
             now = time.monotonic()
-            for slot in sorted(self._active):
-                req = self._active[slot]
+            for slot, req in sorted(landing.riders.items()):
+                if self._active.get(slot) is not req:
+                    dropped += 1
+                    continue
                 if req.expired(now):
                     req.finish(
                         "expired",
@@ -1660,17 +1813,20 @@ class SlotDecodeEngine:
                     del self._active[slot]
                     freed.append(slot)
                     continue
-                self._steps[slot] += 1
                 token = int(toks[slot])
                 req.push_token(token)
                 emitted += 1
                 if self._finished(req, token):
                     freed.append(slot)
             self.metrics.record_step(
-                dt, active_before, self.max_batch, emitted
+                landing.secs, len(landing.riders) - dropped, self.max_batch,
+                emitted,
             )
-            delivered.update(emitted=emitted, freed=len(freed))
-        return preempt_freed + freed
+            if dropped:
+                self.metrics.record_dropped(dropped)
+            delivered.update(
+                emitted=emitted, freed=len(freed), dropped=dropped)
+        return freed
 
     def _step_spec(self) -> List[int]:
         """One speculative verify step over all slots: draft spec_k

@@ -98,6 +98,11 @@ class ServingMetrics:
         self._hist_pending: dict = {k: [] for k in LATENCY_HISTOGRAMS}
         self.tokens_total = 0
         self.steps_total = 0
+        # Decode lookahead (engine.advance): steps dispatched before the
+        # step ahead of them had landed, and rows of a landed step whose
+        # request had left its slot meanwhile (a token nobody asked for).
+        self.steps_ahead_total = 0
+        self.rows_dropped_total = 0
         self.busy_secs = 0.0
         self.requests_admitted = 0
         self.requests_rejected = 0
@@ -228,6 +233,14 @@ class ServingMetrics:
             if self._first_step_at is None:
                 self._first_step_at = now - seconds
             self._last_step_at = now
+
+    def record_dispatch_ahead(self) -> None:
+        with self._lock:
+            self.steps_ahead_total += 1
+
+    def record_dropped(self, rows: int) -> None:
+        with self._lock:
+            self.rows_dropped_total += int(rows)
 
     def _tenant(self, tenant: str) -> dict:
         # Caller holds the lock.
@@ -424,6 +437,8 @@ class ServingMetrics:
                 "e2e_p99_ms": round(_percentile(e2e, 0.99) * 1e3, 3),
                 "tokens_total": self.tokens_total,
                 "decode_steps_total": self.steps_total,
+                "decode_steps_ahead_total": self.steps_ahead_total,
+                "decode_rows_dropped_total": self.rows_dropped_total,
                 "tokens_per_sec_busy": round(
                     self.tokens_total / self.busy_secs, 1
                 ) if self.busy_secs > 0 else 0.0,

@@ -72,7 +72,7 @@ def test_engine_error_fails_the_serve_phase(tmp_path, capsys, monkeypatch):
     must not: the dead requests and the counter each fail the phase."""
     from ml_trainer_tpu.serving.engine import SlotDecodeEngine
 
-    real_step, calls = SlotDecodeEngine.step, []
+    real_step, calls = SlotDecodeEngine.advance, []
 
     def step_once_refused(self):
         calls.append(1)
@@ -80,7 +80,8 @@ def test_engine_error_fails_the_serve_phase(tmp_path, capsys, monkeypatch):
             raise RuntimeError("Mosaic refused the decode step")
         return real_step(self)
 
-    monkeypatch.setattr(SlotDecodeEngine, "step", step_once_refused)
+    # ``advance`` is the serving loop's call of the decode step.
+    monkeypatch.setattr(SlotDecodeEngine, "advance", step_once_refused)
     one_mode = dataclasses.replace(TINY, serve_modes=("contiguous",))
     assert chip_smoke.child_main("serve", str(tmp_path), one_mode) == 1
     out = capsys.readouterr().out

@@ -370,7 +370,7 @@ def test_engine_thread_death_propagates_to_streams(served_model):
         def die(*a, **kw):
             raise Boom("engine exploded")
 
-        srv.engine.step = die
+        srv.engine.advance = die  # the loop's call of the decode step
         s = srv.submit(_prompt(4, 4), 8)
         with pytest.raises(RuntimeError, match="engine thread died"):
             s.result(timeout=60)
@@ -396,7 +396,7 @@ def test_result_timeout_honored_when_engine_dead(served_model):
             release.wait(60)
             return []
 
-        srv.engine.step = stuck
+        srv.engine.advance = stuck  # the loop's call of the decode step
         s = srv.submit(_prompt(7, 4), 8)
         t0 = time.monotonic()
         with pytest.raises(TimeoutError, match="not finished within"):

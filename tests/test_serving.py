@@ -413,49 +413,124 @@ def test_metrics_snapshot_hammer_under_concurrent_recording():
 
 
 def test_decode_step_and_admission_are_cut_into_phase_spans(model_and_vars):
-    """Per decode step, on the engine's one thread and in this order:
-    ``serve_prepare``, ``serve_decode`` holding ``.dispatch`` then
-    ``.fence``, ``serve_deliver``, no sibling overlapping the next; per
-    admission ``serve_admit`` holding the prefill and its fence."""
+    """The spans of the engine's one thread, under the names the
+    benchmark's readers use.  A turn of the decode engine is
+    ``serve_prepare`` (a dispatch follows), ``serve_decode`` holding
+    ``.dispatch`` and/or ``.fence``, ``serve_deliver`` (a fence came
+    before); no sibling overlaps the next.  Through the serving loop the
+    turn dispatches step n+1 (``ahead``: 1) and fences step n; before an
+    admission, and with nothing left active, it only fences; after one it
+    only dispatches.  Through ``engine.step()`` both are the same step.
+    Per admission ``serve_admit`` holds the prefill and its fence, with
+    every dispatched step fenced before it."""
+    from ml_trainer_tpu.serving.engine import SlotDecodeEngine
+    from ml_trainer_tpu.serving.scheduler import Request
     from ml_trainer_tpu.telemetry.spans import clear_trace, trace_events
 
     model, variables = model_and_vars
+
+    def end(e):
+        return e["ts"] + e["dur"]
+
+    def engine_spans():
+        events = sorted(
+            (e for e in trace_events()
+             if e["ph"] == "X" and e["name"].startswith("serve_")
+             and e["name"] != "serve_wait"),
+            key=lambda e: (e["ts"], -e["dur"]))
+        assert len({e["tid"] for e in events}) == 1
+        tops = [e for e in events if "." not in e["name"]
+                and not e["name"].startswith("serve_prefill")]
+        for a, b in zip(tops, tops[1:]):
+            assert end(a) <= b["ts"]               # siblings never overlap
+        return events, tops
+
+    def inside(events, parent):
+        return [e for e in events if e is not parent
+                and parent["ts"] <= e["ts"] and end(e) <= end(parent)]
+
+    def turns(events, tops):
+        """Each ``serve_decode`` with its dispatch, its fence, and the
+        top-level spans just before and after it."""
+        for i, decode in enumerate(tops):
+            if decode["name"] != "serve_decode":
+                continue
+            kids = inside(events, decode)
+            names = [k["name"] for k in kids]
+            assert names in (
+                ["serve_decode.dispatch", "serve_decode.fence"],
+                ["serve_decode.dispatch"], ["serve_decode.fence"])
+            by = {k["name"].split(".")[1]: k for k in kids}
+            if "dispatch" in by:
+                before = tops[i - 1]
+                assert before["name"] == "serve_prepare"
+                assert (before["args"]["engine_step"]
+                        == decode["args"]["engine_step"]
+                        == by["dispatch"]["args"]["engine_step"])
+            if "fence" in by:
+                after = tops[i + 1]
+                assert after["name"] == "serve_deliver"
+                assert (after["args"]["engine_step"]
+                        == by["fence"]["args"]["engine_step"])
+                if "dispatch" not in by:
+                    assert (decode["args"]["engine_step"]
+                            == by["fence"]["args"]["engine_step"])
+            yield by.get("dispatch"), by.get("fence")
+
+    # The synchronous order: one step a turn, dispatched and fenced in it.
+    clear_trace()
+    engine = SlotDecodeEngine(model, variables, max_batch=2)
+    assert engine.admit(Request(prompt=_prompt(5, 4), max_new_tokens=4),
+                        0) == "active"
+    while engine.active_count():
+        engine.step()
+    events, tops = engine_spans()
+    assert [t["name"] for t in tops[1:]] == [
+        "serve_prepare", "serve_decode", "serve_deliver"] * 3
+    for dispatch, fence in turns(events, tops):
+        assert dispatch["args"]["ahead"] == 0
+        assert dispatch["args"]["engine_step"] == fence["args"]["engine_step"]
+
+    # The serving loop: the fence is one step behind the dispatch.
     clear_trace()
     with Server(model, variables, max_batch=2) as server:
         first = server.submit(_prompt(3, 5), 6)
         second = server.submit(_prompt(4, 9), 4)
         first.result(timeout=120)
         second.result(timeout=120)
-    events = sorted(
-        (e for e in trace_events()
-         if e["ph"] == "X" and e["name"].startswith("serve_")
-         and e["name"] != "serve_wait"),
-        key=lambda e: (e["ts"], -e["dur"]))
-    assert len({e["tid"] for e in events}) == 1
-
-    def end(e):
-        return e["ts"] + e["dur"]
-
-    def inside(parent):
-        return [e["name"] for e in events if e is not parent
-                and parent["ts"] <= e["ts"] and end(e) <= end(parent)]
-
-    tops = [e for e in events if "." not in e["name"]
-            and not e["name"].startswith("serve_prefill")]
-    for a, b in zip(tops, tops[1:]):
-        assert end(a) <= b["ts"]                   # siblings never overlap
-    steps = [e for e in tops if e["name"] != "serve_admit"]
-    assert len(steps) % 3 == 0 and len(steps) >= 3 * 5
-    for i in range(0, len(steps), 3):
-        prepare, decode, deliver = steps[i:i + 3]
-        assert [e["name"] for e in (prepare, decode, deliver)] == [
-            "serve_prepare", "serve_decode", "serve_deliver"]
-        assert prepare["args"]["engine_step"] == decode["args"]["engine_step"]
-        assert inside(decode) == ["serve_decode.dispatch",
-                                  "serve_decode.fence"]
-        assert 1 <= deliver["args"]["emitted"] <= 2
-    assert sum(e["args"]["freed"] for e in steps[2::3]) == 2
+    events, tops = engine_spans()
+    dispatched, fenced, overlapped = [], [], 0
+    for dispatch, fence in turns(events, tops):
+        if dispatch is not None:
+            step = dispatch["args"]["engine_step"]
+            # Ahead exactly when the step before it is still in flight.
+            assert dispatch["args"]["ahead"] == int(
+                bool(dispatched) and dispatched[-1] not in fenced)
+            dispatched.append(step)
+        if fence is not None:
+            fenced.append(fence["args"]["engine_step"])
+            if dispatch is not None:
+                assert fenced[-1] == dispatched[-1] - 1
+                overlapped += 1
+    # Every step dispatched once and fenced once, in order; most of them
+    # with the next one already on its way.
+    assert dispatched == fenced == list(range(
+        dispatched[0], dispatched[0] + len(dispatched)))
+    assert overlapped >= 3
+    delivers = [e for e in tops if e["name"] == "serve_deliver"]
+    assert len(delivers) == len(fenced)
+    assert all(0 <= e["args"]["emitted"] <= 2 for e in delivers)
+    # A request's first token is its prefill's; a request that ends on a
+    # decode step leaves one row in the step dispatched behind it.
+    assert sum(e["args"]["emitted"] for e in delivers) == (6 - 1) + (4 - 1)
+    assert sum(e["args"]["freed"] for e in delivers) == 2
+    assert 1 <= sum(e["args"]["dropped"] for e in delivers) <= 2
     admits = [e for e in tops if e["name"] == "serve_admit"]
     assert [a["args"]["prompt_len"] for a in admits] == [5, 9]
     for a in admits:
-        assert inside(a) == ["serve_prefill", "serve_prefill.fence"]
+        assert [e["name"] for e in inside(events, a)] == [
+            "serve_prefill", "serve_prefill.fence"]
+        # Landed before anything that is not a decode step.
+        earlier = [e for e in events if e["ts"] < a["ts"]]
+        assert (sum(e["name"] == "serve_decode.dispatch" for e in earlier)
+                == sum(e["name"] == "serve_decode.fence" for e in earlier))
