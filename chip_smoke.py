@@ -360,6 +360,35 @@ def kernel_cases(cfg: SmokeConfig):
                f"{(b, g, length, d)}", attend("pallas"), attend("reference"),
                args, BF16_FLOOR)
 
+    # -- retention state step: every decode step of a power retention layer
+    # A slot's key-value heads at the published group (five query heads of
+    # 128 over a state of 128 x 8,320), the padding under phi's last half
+    # row nought as the prompt's form leaves it; the interpreter gets the
+    # same call small.  All float32 on the VPU: held to 1e-5.
+    from ml_trainer_tpu.ops.kernels.retention_state_step import (
+        retention_state_step,
+    )
+    from ml_trainer_tpu.ops.power_retention import phi_padded
+
+    for b, g, r, d in ((2, 2, 5, 128),) if interp else ((4, 8, 5, 128),):
+        p = phi_padded(d)
+        live = jnp.arange(p) < p - d // 2
+        args = (
+            normal((b, g, r, d), jnp.float32), normal((b, g, d), jnp.float32),
+            normal((b, g, d), jnp.float32),
+            jnp.asarray(rng.uniform(size=(b, g)), jnp.float32),
+            normal((b, g, d, p), jnp.float32) * live,
+            jnp.abs(normal((b, g, p), jnp.float32)) * live,
+        )
+
+        def step(impl):
+            return lambda *a: retention_state_step(
+                *a, implementation=impl, interpret=interp)
+
+        yield (f"retention_state_step [float32] {(b, g, r, d)} x "
+               f"{(b, g, d, p)}", step("pallas"), step("reference"), args,
+               1e-5)
+
     # -- int8 decode matmul (opt-in: Server(quant_int8=True)) --------------
     for tag, kk, nn in (("qkv", E, 3 * E), ("proj", E, E),
                         ("fc_in", E, 4 * E), ("fc_out", 4 * E, E)):

@@ -65,6 +65,7 @@ from ml_trainer_tpu.models.moe import (
     GatedMLP,
     HeldExpertsMoE,
     held_expert_counter_args,
+    held_expert_counters_in_flight,
 )
 from ml_trainer_tpu.models.registry import register_model
 from ml_trainer_tpu.ops.attention import attention
@@ -422,6 +423,10 @@ class KimiLinearLM(nn.Module):
             (self.embed_dim, self.vocab_rows), self.dtype)
         return jnp.matmul(x.astype(self.dtype), head.astype(self.dtype),
                           preferred_element_type=jnp.float32)
+
+    def reduce_step_counters(self, counters: dict, in_flight) -> dict:
+        """Inside the decode program: the counters over the rows in flight."""
+        return held_expert_counters_in_flight(counters, in_flight)
 
     def step_counter_args(self, counters: dict, rows_in_flight: int) -> dict:
         """The decode step's counters as arguments of its fence span."""

@@ -168,6 +168,13 @@ def held_expert_counter_args(counters: dict, rows_in_flight: int,
     }
 
 
+def held_expert_counters_in_flight(counters: dict, in_flight) -> dict:
+    """Inside the decode program (a model's ``reduce_step_counters``): the
+    per-row ``HeldExpertsMoE`` counts summed over the rows in flight."""
+    return jax.tree.map(
+        lambda c: jnp.tensordot(in_flight, c, axes=1), counters)
+
+
 class HeldExpertsMoE(nn.Module):
     """Dropless routed feed-forward over the experts THIS chip holds.
 

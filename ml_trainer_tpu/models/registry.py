@@ -28,7 +28,7 @@ DRAFT_PAIRS: Dict[str, str] = {
 
 _FAMILY_MODULES = (
     "mlmodel", "resnet", "vit", "bert", "gpt2", "llama", "exaone_moe",
-    "kimi_linear",
+    "kimi_linear", "brumby",
 )
 
 

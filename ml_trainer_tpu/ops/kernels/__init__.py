@@ -37,6 +37,11 @@ Catalog (see docs/kernels.md for block layouts and measured numbers):
   against the blocks of that row that hold a live position, where XLA's
   masked attention reads all ``L`` positions of every row.  Online
   softmax, so pinned to its reference by tolerance, not bit for bit.
+* ``retention_state_step`` — a power retention layer's pass over its state
+  pool, every decode step and behind no knob: one read and one write in
+  place, ``phi`` of the queries and the key made in VMEM, where XLA reads
+  the pool twice and writes it once.  The sum over ``phi``'s entries runs
+  lane by lane, so pinned by tolerance.
 """
 
 from ml_trainer_tpu.ops.kernels.decode_attention import (  # noqa: F401
@@ -49,6 +54,9 @@ from ml_trainer_tpu.ops.kernels.decode_attention import (  # noqa: F401
 from ml_trainer_tpu.ops.kernels.paged_attention import (  # noqa: F401
     paged_attention,
     paged_attention_reference,
+)
+from ml_trainer_tpu.ops.kernels.retention_state_step import (  # noqa: F401
+    retention_state_step,
 )
 from ml_trainer_tpu.ops.kernels.slot_cache_write import (  # noqa: F401
     slot_cache_write,
@@ -72,6 +80,7 @@ __all__ = [
     "decode_attention_reference",
     "grouped_decode_attention",
     "paged_attention",
+    "retention_state_step",
     "paged_attention_reference",
     "adam_scalars",
     "fused_adam_update",

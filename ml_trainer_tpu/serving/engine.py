@@ -351,7 +351,9 @@ class SlotDecodeEngine:
         self._dm_prefill = self._prefill_model.clone(decode=True)
         # What a model may offer beyond the GPT-2 family's call (the engine
         # knows no architecture by name): per-row counters of the decode
-        # step turned into arguments of its fence span, and a prefill that
+        # step, which its ``reduce_step_counters`` reduces over the rows in
+        # flight inside the program and its ``step_counter_args`` turns
+        # into arguments of the fence span, and a prefill that
         # is told the prompt's true length inside its padded bucket (a
         # cache that keeps the last positions only must not keep padding).
         self._counter_args = getattr(model, "step_counter_args", None)
@@ -604,16 +606,16 @@ class SlotDecodeEngine:
                              in_flight):
                 # A model with ``step_counter_args`` sows per-row counts
                 # (row axis first) in "step_counters"; the step returns
-                # them summed over the rows in flight, beside the tokens.
+                # them as its ``reduce_step_counters`` reduces them over
+                # the rows in flight (a sum; a smallest value), beside the
+                # tokens.
                 logits, mut = dm.apply(
                     {"params": params, "cache": cache}, tok,
                     train=False, mutable=["cache", "step_counters"],
                 )
                 nxt = _sample_rows(logits[:, -1], temps, rngs, steps)
-                counted = jax.tree.map(
-                    lambda c: jnp.tensordot(in_flight, c, axes=1),
-                    mut["step_counters"],
-                )
+                counted = dm.reduce_step_counters(
+                    mut["step_counters"], in_flight)
                 return mut["cache"], nxt[:, None].astype(jnp.int32), counted
 
             return jax.jit(step_counted, donate_argnums=(1,))

@@ -505,6 +505,7 @@ def _kernel_calls():
         "decode_attention": lambda: decode_attention(
             *_decode_case("lanes", jnp.float32, 1)[0],
             jnp.ones((6,), jnp.int32), **kw),
+        "retention_state_step": lambda: _retention_state_step_call(**kw),
         "fused_adam_norm": lambda: unscale_sqsum(g, 2.0, **kw),
         "fused_adam_update": lambda: fused_adam_update(
             g, g, g, jnp.abs(g), bc1=0.1, bc2=0.001, step_size=-1e-3,
@@ -514,7 +515,8 @@ def _kernel_calls():
 
 @pytest.mark.parametrize("name", ["paged_attention_decode", "int8_matmul",
                                   "fused_adam_norm", "fused_adam_update",
-                                  "slot_cache_write", "decode_attention"])
+                                  "slot_cache_write", "decode_attention",
+                                  "retention_state_step"])
 def test_each_kernel_carries_the_name_the_profiler_shows(name):
     """docs/kernels.md: a Pallas call's ``name`` is the instruction's name
     on the device trace, the handle a per-kernel metric finds it by."""
@@ -800,3 +802,76 @@ def test_int8_quality_gate(tmp_path):
     rel_err = float(jnp.max(jnp.abs(lf - lq)) / jnp.max(jnp.abs(lf)))
     assert agreement >= 0.995, f"argmax agreement {agreement}"
     assert rel_err <= 0.02, f"relative logit error {rel_err}"
+
+
+# -- retention_state_step ---------------------------------------------------
+
+def _retention_state_step_call(**kw):
+    from ml_trainer_tpu.ops.kernels.retention_state_step import (
+        retention_state_step,
+    )
+
+    return retention_state_step(
+        *_retention_step_args(0, 1, 1, 5, 16, 8), **kw)
+
+
+def _retention_step_args(seed, b, n, heads, d, d_v):
+    from ml_trainer_tpu.ops.power_retention import phi_padded
+
+    keys = jax.random.split(jax.random.PRNGKey(seed), 6)
+    normal = lambda i, *shape: jax.random.normal(keys[i], shape)  # noqa: E731
+    state = normal(4, b, n, d_v, phi_padded(d))
+    # what lies under phi's padding is nought, and has to stay so
+    state = state.at[..., phi_padded(d) - d // 2:].set(0.0)
+    norm = jnp.abs(normal(5, b, n, phi_padded(d))).at[
+        ..., phi_padded(d) - d // 2:].set(0.0)
+    return (normal(0, b, n, heads, d), normal(1, b, n, d),
+            normal(2, b, n, d_v), jax.random.uniform(keys[3], (b, n)),
+            state, norm)
+
+
+@pytest.mark.parametrize("b,n,heads,d,d_v", [
+    (2, 2, 5, 128, 128),     # the published group: five query heads of 128
+    (1, 3, 8, 128, 64),      # a full vreg of heads, two blocks of value rows
+    (3, 1, 1, 16, 8),        # one head, a tiny state (interpret mode only)
+], ids=["five-of-128", "eight-of-128", "one-of-16"])
+def test_retention_state_step_is_the_two_xla_passes(b, n, heads, d, d_v):
+    """One pass over the pool in place against XLA's read and update: the
+    same four results, the sums over ``D`` in another order (lane by lane,
+    then one reduction: 65 terms a lane of order 1 at heads of 128, read
+    here to 3e-5 on reads of order 100); the padding under ``phi``'s last
+    half row stays nought."""
+    from ml_trainer_tpu.ops.kernels.retention_state_step import (
+        retention_state_step,
+    )
+    from ml_trainer_tpu.ops.power_retention import phi_padded
+
+    args = _retention_step_args(b + heads, b, n, heads, d, d_v)
+    want = retention_state_step(*args, implementation="reference")
+    got = retention_state_step(*args, implementation="pallas", interpret=True)
+    for name, w, g in zip(("read", "z_read", "state", "norm"), want, got):
+        assert g.shape == w.shape and g.dtype == jnp.float32, name
+        scale = float(jnp.abs(w).max())
+        np.testing.assert_allclose(
+            np.asarray(g), np.asarray(w), atol=2e-6 * scale, err_msg=name)
+    pad = slice(phi_padded(d) - d // 2, None)
+    assert not np.asarray(got[2])[..., pad].any()
+    assert not np.asarray(got[3])[..., pad].any()
+
+
+def test_retention_state_step_refusals():
+    from ml_trainer_tpu.ops.kernels.retention_state_step import (
+        retention_state_step,
+    )
+
+    args = _retention_step_args(0, 1, 1, 9, 16, 8)
+    with pytest.raises(ValueError, match="at most 8"):
+        retention_state_step(*args, implementation="pallas", interpret=True)
+    with pytest.raises(ValueError, match="Unknown retention_state_step"):
+        retention_state_step(*args, implementation="mosaic")
+    with pytest.raises(ValueError, match="phi's 144 entries"):
+        retention_state_step(*args[:4], args[4][..., :136], args[5])
+    # off the TPU, or at heads the kernel does not take, 'auto' is XLA's
+    want = retention_state_step(*args, implementation="reference")
+    got = retention_state_step(*args)
+    np.testing.assert_array_equal(np.asarray(got[2]), np.asarray(want[2]))

@@ -193,3 +193,90 @@ def test_flash_kernels_compile_at_the_training_shape(one_chip):
     operand = 2 * b * h * s * d
     # the output cotangent, `out`, and two rows of statistics a position
     assert compiled.memory_analysis().temp_size_in_bytes < 2.1 * operand
+
+
+def test_brumby_decode_and_prefill_fit_the_chip_with_one_state_pool(
+        one_chip, monkeypatch):
+    """``brumby-14b-l8`` at its published widths, 8 layers, as the slot
+    engine states them: the decode program over 16 slots with the cache
+    donated, and the batch-1 prefill of the largest bucket the cell's
+    prompts reach (2,048, one chunk).  The state pool (16 x 8 x 34.35 MB)
+    exists ONCE: all of it is aliased to the outputs; weights, pool and the
+    larger program's temporaries fit 15.75 GB with the prefill's batch-1
+    state beside them; the pool is passed over by ONE Mosaic call a layer
+    (``retention_state_step``), in place, and by no fusion, copy or
+    transpose of XLA's; the prefill computes the head on one position, not
+    on 2,048 x 151,936 logits."""
+    import functools
+
+    from ml_trainer_tpu.models import brumby, get_model
+
+    # 'auto' asks jax.default_backend(), which is the CPU here: the test
+    # steers the model to the path a TPU takes
+    monkeypatch.setattr(brumby, "retention_state_step", functools.partial(
+        brumby.retention_state_step, implementation="pallas"))
+
+    slots, bucket, layers = 16, 2048, 8
+    model = get_model("brumby", num_layers=layers, max_len=4096,
+                      dtype=jnp.bfloat16)
+    dm = model.clone(decode=True)
+
+    def spec(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    def shapes(rows):
+        made = jax.eval_shape(lambda: dm.init(
+            {"params": jax.random.PRNGKey(0)},
+            jnp.zeros((rows, 1), jnp.int32), train=False))
+        return (jax.tree.map(lambda s: spec(s.shape, s.dtype), made["params"]),
+                made["cache"])
+
+    params, cache = shapes(slots)
+    # the slot engine's cache: the scalar index widened to a row a slot
+    cache = jax.tree.map(
+        lambda s: spec(s.shape or (slots,), s.dtype), cache)
+    pool = sum(math.prod(s.shape) * 4 for s in jax.tree.leaves(cache))
+    assert pool == slots * layers * (8 * 8320 * 129 * 4) + layers * slots * 4
+
+    def step(params, cache, tok, in_flight):
+        logits, mut = dm.apply(
+            {"params": params, "cache": cache}, tok, train=False,
+            mutable=["cache", "step_counters"])
+        return (mut["cache"], jnp.argmax(logits[:, -1], -1),
+                model.reduce_step_counters(mut["step_counters"], in_flight))
+
+    decode = jax.jit(step, donate_argnums=(1,)).lower(
+        params, cache, spec((slots, 1), jnp.int32),
+        spec((slots,), jnp.int32)).compile()
+    memory = decode.memory_analysis()
+    assert memory.alias_size_in_bytes >= pool                  # ONE copy
+    text = decode.as_text()
+    state = r"f32\[16,8,128,8320\]"
+    assert not re.findall(
+        rf"= {state}\S* (?:copy|transpose|fusion)\(", text)
+    calls = re.findall(
+        r"%(\S+) = .*? custom-call\(.*custom_call_target=\"tpu_custom_call\"",
+        text)
+    assert len(calls) == layers, calls
+    assert all("retention_state_step" in name for name in calls)
+
+    _, cache1 = shapes(1)
+
+    def prefill(params, ids, true_len):
+        empty = jax.tree.map(lambda s: jnp.zeros(s.shape, s.dtype), cache1)
+        logits, mut = dm.apply(
+            {"params": params, "cache": empty}, ids, train=False,
+            mutable=["cache"], true_len=true_len)
+        return mut["cache"], jnp.argmax(jax.lax.dynamic_index_in_dim(
+            logits, true_len - 1, axis=1, keepdims=False), -1)
+
+    prompt = jax.jit(prefill).lower(
+        params, spec((1, bucket), jnp.int32), spec((), jnp.int32)).compile()
+    temps = prompt.memory_analysis()
+    logits = bucket * 151936 * 4
+    assert temps.temp_size_in_bytes < 0.6 * logits
+    held = memory.argument_size_in_bytes          # weights and the pool
+    assert 12.75e9 < held < 12.85e9
+    assert held + max(
+        memory.temp_size_in_bytes,
+        temps.temp_size_in_bytes + temps.output_size_in_bytes) < 14.5e9
