@@ -210,6 +210,7 @@ def kernel_cases(cfg: SmokeConfig):
     )
     from ml_trainer_tpu.ops.kernels import (
         decode_attention,
+        decode_attention_append,
         fused_adam_update,
         int8_matmul,
         paged_attention,
@@ -359,6 +360,27 @@ def kernel_cases(cfg: SmokeConfig):
         yield (f"decode_attention [bfloat16] {(b, h, 1, d)} x "
                f"{(b, g, length, d)}", attend("pallas"), attend("reference"),
                args, BF16_FLOOR)
+
+        # The step as the engine makes it since PR 35, one call that appends
+        # this step's rows at each row's position while it reads the row:
+        # the output and both caches against the scatter then the masked
+        # attention (the caches move bytes: any difference is past the floor
+        # of a bfloat16's rounding).
+        pos = lens - 1
+        pos[2] = length + 500                       # a free row's: clamped
+        step_args = (
+            args[0], normal((b, g, 1, d), jnp.bfloat16, 0.5),
+            normal((b, g, 1, d), jnp.bfloat16, 0.5), args[1], args[2],
+            jnp.asarray(pos, jnp.int32),
+        )
+
+        def append(impl):
+            return lambda *a: decode_attention_append(
+                *a, implementation=impl, interpret=interp)
+
+        yield (f"decode_attention_append [bfloat16] {(b, h, 1, d)} x "
+               f"{(b, g, length, d)}", append("pallas"), append("reference"),
+               step_args, BF16_FLOOR)
 
     # -- retention state step: every decode step of a power retention layer
     # A slot's key-value heads at the published group (five query heads of

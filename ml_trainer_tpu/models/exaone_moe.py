@@ -47,7 +47,7 @@ from ml_trainer_tpu.models.moe import (
 from ml_trainer_tpu.models.registry import register_model
 from ml_trainer_tpu.ops.attention import attention
 from ml_trainer_tpu.ops.kernels.decode_attention import (
-    decode_attention,
+    decode_attention_append,
     grouped_decode_attention,
 )
 from ml_trainer_tpu.ops.kernels.slot_cache_write import slot_cache_write
@@ -172,21 +172,25 @@ class ExaoneAttention(nn.Module):
             return self._causal(q, k, v, implementation="auto")
         rows = idx if idx.ndim else jnp.full((b,), idx, jnp.int32)
         q, k = self._rotate(q, k, rows[:, None])
+        if idx.ndim and not w:
+            # A full layer of the slot engine, one call with every row in
+            # flight (ops/kernels/decode_attention.py): each row's live
+            # blocks and no others, this step's K and V put into the last
+            # of them and only their tile written back; a free row's
+            # position clamps.
+            out, cached_k.value, cached_v.value = decode_attention_append(
+                q, k, v, cached_k.value, cached_v.value, rows)
+            return out
         at = rows % w if w else rows
         if idx.ndim:
-            # One in-place write a layer with every row in flight
-            # (ops/kernels/slot_cache_write.py); the ring's position is
-            # always inside it, a free row's full-layer position clamps.
+            # A ring is one block, full after ``window`` tokens, so there
+            # is nothing to skip: one in-place write a layer
+            # (ops/kernels/slot_cache_write.py, the position always inside
+            # the ring), then XLA's read of the whole ring.
             cached_k.value, cached_v.value = slot_cache_write(
                 cached_k.value, cached_v.value, k, v, at)
         else:
             put(at[0])
-        if idx.ndim and not w:
-            # A full layer of the slot engine: each row's live blocks and
-            # no others (ops/kernels/decode_attention.py).  A ring is one
-            # block, full after ``window`` tokens: nothing to skip.
-            return decode_attention(
-                q, cached_k.value, cached_v.value, rows + 1)
         slots = jnp.arange(length)[None, :]
         valid = slots <= rows[:, None]
         if w:

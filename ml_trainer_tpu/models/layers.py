@@ -23,9 +23,10 @@ import jax
 import jax.numpy as jnp
 
 from ml_trainer_tpu.ops.attention import attention
-from ml_trainer_tpu.ops.kernels.decode_attention import decode_attention
+from ml_trainer_tpu.ops.kernels.decode_attention import (
+    decode_attention_append,
+)
 from ml_trainer_tpu.ops.kernels.slot_cache_write import (
-    slot_cache_write,
     slot_cache_write_reference,
 )
 
@@ -243,12 +244,15 @@ class MultiHeadAttention(nn.Module):
             # is a PER-ROW [B] vector — each batch row (slot) sits at its
             # own sequence position, so rows write K/V at their own index
             # and attend their own valid prefix.  ``s == 1`` is the
-            # ordinary decode step: one in-place write a layer with every
-            # row in flight (ops/kernels/slot_cache_write.py; XLA runs the
-            # scatter it replaces as a sequential loop over the rows), then
-            # one read of each row's live blocks and no others
-            # (ops/kernels/decode_attention.py; the masked attention it
-            # replaces reads all ``L`` positions of every row).
+            # ordinary decode step, ONE call a layer with every row in
+            # flight (ops/kernels/decode_attention.py): it reads each row's
+            # live blocks and no others (the masked attention it replaces
+            # reads all ``L`` positions of every row), puts this step's K
+            # and V into the row's last block while it holds it, and writes
+            # back the one tile they land in (XLA runs the scatter it
+            # replaces as a sequential loop over the rows; a write kernel of
+            # its own moved that tile both ways, 1.5 GB a step of gpt2-large
+            # for 5.9 MB of new rows).
             # ``s > 1`` is the speculative VERIFY window (speculative.py): a
             # length-``s`` token window lands at each row's own dynamic
             # offset — one dynamic_update_slice per row, shapes static at
@@ -256,20 +260,18 @@ class MultiHeadAttention(nn.Module):
             # query position j attends cached positions <= idx + j (the
             # in-window causal rule).  The window keeps the scatter and the
             # masked attention (the kernels' references): it may cross the
-            # edge of the write's block and has ``s`` lengths a row, which
+            # edge of the write's tile and has ``s`` lengths a row, which
             # would take second kernels, and no measured traffic runs it.
             # Prefill still runs per request at batch 1 with the ordinary
             # scalar index and is inserted into the slot cache afterwards.
-            write = slot_cache_write if s == 1 else slot_cache_write_reference
-            cached_k.value, cached_v.value = write(
-                cached_k.value, cached_v.value,
-                k.astype(self.dtype), v.astype(self.dtype), idx,
-            )
             idx_var.value = idx + s
+            k, v = k.astype(self.dtype), v.astype(self.dtype)
             if s == 1:
-                # The positions before this step's and the one just written.
-                return decode_attention(
-                    q, cached_k.value, cached_v.value, idx + 1)
+                out, cached_k.value, cached_v.value = decode_attention_append(
+                    q, k, v, cached_k.value, cached_v.value, idx)
+                return out
+            cached_k.value, cached_v.value = slot_cache_write_reference(
+                cached_k.value, cached_v.value, k, v, idx)
             valid = (
                 jnp.arange(L)[None, None, :]
                 <= idx[:, None, None] + jnp.arange(s)[None, :, None]

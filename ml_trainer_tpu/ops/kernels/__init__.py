@@ -29,14 +29,17 @@ Catalog (see docs/kernels.md for block layouts and measured numbers):
 * ``int8_matmul`` / ``quantize_per_channel`` — int8 weight-quantized
   matmul with per-output-channel scales, backing the opt-in quantized
   decode path (``Server(quant_int8=True)``).
-* ``slot_cache_write`` — the slot engine's per-row append of this step's
-  K and V, every decode step and behind no knob: one in-place grid over
-  the rows where XLA runs the scatter as a sequential loop over them.
-* ``decode_attention`` — the slot engine's read of the cache it has just
-  written, every decode step and behind no knob: one query position a row
-  against the blocks of that row that hold a live position, where XLA's
-  masked attention reads all ``L`` positions of every row.  Online
-  softmax, so pinned to its reference by tolerance, not bit for bit.
+* ``decode_attention_append`` / ``decode_attention`` — the slot engine's
+  decode step over its cache, every step and behind no knob: one query
+  position a row against the blocks of that row that hold a live position,
+  where XLA's masked attention reads all ``L`` positions of every row, and
+  this step's K and V put into the row's last block on the way, its tile
+  alone written back.  Online softmax, so pinned to its reference by
+  tolerance, not bit for bit; the caches bit for bit.
+* ``slot_cache_write`` / ``slot_row_write`` — the per-row append alone, for
+  a cache that XLA reads whole (a window layer's ring, a latent cache): one
+  in-place grid over the rows where XLA runs the scatter as a sequential
+  loop over them.
 * ``retention_state_step`` — a power retention layer's pass over its state
   pool, every decode step and behind no knob: one read and one write in
   place, ``phi`` of the queries and the key made in VMEM, where XLA reads
@@ -47,6 +50,8 @@ Catalog (see docs/kernels.md for block layouts and measured numbers):
 from ml_trainer_tpu.ops.kernels.decode_attention import (  # noqa: F401
     attended_positions,
     decode_attention,
+    decode_attention_append,
+    decode_attention_append_reference,
     decode_attention_reference,
     grouped_decode_attention,
 )
@@ -77,6 +82,8 @@ from ml_trainer_tpu.ops.kernels.int8_matmul import (  # noqa: F401
 __all__ = [
     "attended_positions",
     "decode_attention",
+    "decode_attention_append",
+    "decode_attention_append_reference",
     "decode_attention_reference",
     "grouped_decode_attention",
     "paged_attention",

@@ -1,14 +1,24 @@
 """Slot-cache write: this step's K and V into each row's own position, in
 place and with every row in flight.
 
-The slot engine's decode step (``models/layers.py::_decode_step``, the
-per-row ``cache_index`` branch) appends one position a row to a
+A decode step of the slot engine appends one position a row to a
 ``[B, H, L, D]`` cache, each row at its own index.  Stated in XLA that is a
 scatter of ``B`` windows ``[H, 1, D]`` (``jax.vmap`` of a
 ``dynamic_update_slice``), and the TPU runs a scatter as a sequential
 ``while`` over its indices: 72 loops of 32 iterations a step of a 36-layer
 model, half of the decode step (PERF.md, PR 25).  This kernel is the same
 write as one grid over the rows whose DMAs the pipeline overlaps.
+
+Who calls it.  A cache that is READ WHOLE after the write: the rings of
+``models/exaone_moe.py``'s window layers (written at ``position mod
+window``, read by XLA), the latent cache of ``models/kimi_linear.py``
+(``slot_row_write``: one leaf, read by XLA), a paged pool's rows (``rows``,
+below).  A cache read by ``decode_attention`` is written BY that kernel
+since PR 35 (``decode_attention_append``: the read already holds the tile
+this kernel would fetch, so only the write-back is left of it):
+``models/layers.py`` and ``exaone_moe``'s full layers.  The reference here
+is also the verify window's scatter (``s > 1``) and the first half of that
+call's reference.
 
 Why it moves a block and not a position.  The chip addresses memory in
 tiles of 8 sublanes of 32 bits by 128 lanes, and a DMA moves whole tiles.
@@ -85,6 +95,13 @@ def slot_cache_write_reference(k_cache, v_cache, k_new, v_new, pos,
             _write_rows(v_cache, v_new, pos, rows))
 
 
+def landing_position(pos, L: int):
+    """Where a write at ``pos`` lands, as ``jax.lax.dynamic_update_slice``
+    reads a start: a negative one counts from the end, and the result is
+    clamped into the array."""
+    return jnp.clip(jnp.where(pos < 0, pos + L, pos), 0, L - 1)
+
+
 def _position_on_lanes(L: int, d: int) -> bool:
     """XLA's layout of a ``[.., L, d]`` array on the TPU: the dimension
     that pads least to a multiple of 128 goes on the lanes, the order as
@@ -152,9 +169,7 @@ def _slot_cache_write_pallas(caches, news, rows, pos, interpret):
     count = len(caches)
     n, h, L, d = caches[0].shape
     b = news[0].shape[0]
-    # As jax.lax.dynamic_update_slice reads a start: a negative one counts
-    # from the end, and the result is clamped into the array.
-    pos = jnp.clip(jnp.where(pos < 0, pos + L, pos), 0, L - 1)
+    pos = landing_position(pos, L)
     on_lanes = _position_on_lanes(L, d)
     if on_lanes:
         tile = min(LANES, L)
@@ -198,22 +213,29 @@ def _slot_cache_write_pallas(caches, news, rows, pos, interpret):
     return tuple(out)
 
 
-def _write(caches, news, pos, rows, implementation, interpret, name):
-    """What both entry points do once their shapes are checked: the
-    reference's scatter, or the kernel over as many caches as they hand."""
-    news = tuple(n.astype(c.dtype) for n, c in zip(news, caches))
+def _use_pallas(implementation: str, name: str) -> bool:
+    """'auto' is the kernel on the TPU and the reference elsewhere."""
     if implementation == "auto":
         implementation = (
             "pallas" if jax.default_backend() == "tpu" else "reference"
         )
     if implementation in ("reference", "xla"):
-        return tuple(_write_rows(c, n, pos, rows)
-                     for c, n in zip(caches, news))
+        return False
     if implementation != "pallas":
         raise ValueError(
             f"Unknown {name} implementation {implementation!r}; "
             "expected 'auto', 'pallas', or 'reference'"
         )
+    return True
+
+
+def _write(caches, news, pos, rows, implementation, interpret, name):
+    """What both entry points do once their shapes are checked: the
+    reference's scatter, or the kernel over as many caches as they hand."""
+    news = tuple(n.astype(c.dtype) for n, c in zip(news, caches))
+    if not _use_pallas(implementation, name):
+        return tuple(_write_rows(c, n, pos, rows)
+                     for c, n in zip(caches, news))
     if rows is None:
         rows = jnp.arange(pos.shape[0], dtype=jnp.int32)
     return _slot_cache_write_pallas(

@@ -10,7 +10,10 @@ from the steady state of the cell's own traffic (a request of prompt ``P``
 and output ``O`` is met with probability ``O`` at a length uniform in
 ``P + 1 .. P + O``), runs the engine's XLA path and the kernel at each block
 under the profiler and reads device time by operation name: the kernel's
-own, and everything the call runs (the work list's small fusions beside it).
+own, and everything the call runs (the work list's small fusions beside it);
+then a layer's whole decode step with the caches donated, as the write
+kernel then the read ("pair") and as the one call that appends while it
+reads ("fused", PR 35).
 Beside each time: the share of the pool the block fetches
 (``attended_positions``), the bytes a second that makes, and the largest
 difference from the reference.  Results go to standard output and
@@ -22,6 +25,7 @@ difference from the reference.  Results go to standard output and
 """
 
 import argparse
+import functools
 import json
 import os
 import sys
@@ -69,6 +73,7 @@ def main():
         attended_positions,
         decode_attention_reference,
     )
+    from ml_trainer_tpu.ops.kernels.slot_cache_write import slot_cache_write
 
     dtype = jnp.dtype(args.dtype)
     records = []
@@ -93,6 +98,27 @@ def main():
         print(json.dumps(row), flush=True)
         rows = [row]
         chosen = _decode_block(g, L, d, dtype)
+        # The decode step of a layer, the caches donated as the engine's
+        # program donates them: the write kernel then the read ("pair", the
+        # step before PR 35), and the one call that appends while it reads
+        # ("fused").  A step writes at the row's length less one, so both
+        # attend the same positions as the read alone above.
+        k_new, v_new = (jnp.asarray(rng.normal(size=(b, g, 1, d)) * 0.5, dtype)
+                        for _ in range(2))
+
+        def pair(q, k_new, v_new, k, v, pos, block):
+            k, v = slot_cache_write(k, v, k_new, v_new, pos,
+                                    implementation="pallas")
+            return _decode_attention_pallas(
+                q, k, v, pos + 1, block, False), k, v
+
+        def fused(q, k_new, v_new, k, v, pos, block):
+            return _decode_attention_pallas(
+                q, k, v, pos + 1, block, False, (k_new, v_new))
+
+        def carry(out, args):          # the caches a call donated
+            return args[:3] + tuple(out[1:]) + args[5:]
+
         for block in [0] + [int(x) for x in args.blocks.split(",")]:
             if block and L % block:
                 continue
@@ -114,6 +140,21 @@ def main():
                         times.items(), key=lambda kv: -kv[1])[:5]})
                 row["GB_per_s"] = round(
                     share * pool_bytes / row["kernel_ms"] / 1e6, 1)
+                fixed, outs = (q, k_new, v_new), {}
+                for name, step in (("pair", pair), ("fused", fused)):
+                    fn = jax.jit(functools.partial(step, block=block),
+                                 donate_argnums=(3, 4))
+                    outs[name] = fn(*fixed, k + 0, v + 0, lens - 1)
+                    times = kernel_ms(
+                        fn, fixed + (k + 0, v + 0, lens - 1), carry=carry)
+                    row[name] = {
+                        "call_ms": round(sum(times.values()), 4),
+                        "ops": {n: round(t, 4) for n, t in sorted(
+                            times.items(), key=lambda kv: -kv[1])[:5]}}
+                row["fused"]["max_abs_diff_from_pair"] = [
+                    float(jnp.abs(x.astype(jnp.float32)
+                                  - y.astype(jnp.float32)).max())
+                    for x, y in zip(outs["fused"], outs["pair"])]
             except Exception as e:  # refused by Mosaic (VMEM and the like)
                 row["error"] = str(e).splitlines()[0][:200]
             rows.append(row)

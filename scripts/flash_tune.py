@@ -121,19 +121,26 @@ def run_paged(args) -> None:
     print(f"-> {out} best={best}")
 
 
-def kernel_ms(fn, args, iters=5):
+def kernel_ms(fn, args, iters=5, carry=None):
     """Device milliseconds a call of ``fn``, by operation name, from a
-    profiler trace of ``iters`` calls (the first, untraced, compiles)."""
+    profiler trace of ``iters`` calls (the first, untraced, compiles).
+    Where ``fn`` donates arguments, ``carry(result, args)`` gives the next
+    call's."""
     import tempfile
 
     from benchmark.trace_reduce import find_xplane, load_xplane
     from ml_trainer_tpu.utils.profiler import force, trace
 
-    force(fn(*args))
+    def call(args):
+        out = fn(*args)
+        force(out)
+        return carry(out, args) if carry else args
+
+    args = call(args)
     with tempfile.TemporaryDirectory() as logdir:
         with trace(logdir):
             for _ in range(iters):
-                force(fn(*args))
+                args = call(args)
         events = next(iter(load_xplane(find_xplane(logdir))["devices"].values()))
     total = {}
     for name, _, dur in events:

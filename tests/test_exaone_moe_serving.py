@@ -231,12 +231,15 @@ def test_a_window_is_the_band_of_the_causal_mask():
 
 
 def test_only_the_full_layers_read_through_decode_attention(monkeypatch):
-    """The slot engine's decode step calls the kernel's entry point in the
-    full layers (a cache of ``positions`` a row) and not in the window
-    layers, whose ring is one block and full after ``window`` tokens; with
-    the parent's expression (PR 29) behind that entry point the streams keep
-    their bytes (off the TPU the kernel's reference IS that expression)."""
+    """The slot engine's decode step calls the kernel's entry point (since
+    PR 35 the one that appends this step's row while it reads) in the full
+    layers (a cache of ``positions`` a row) and not in the window layers,
+    whose ring is one block and full after ``window`` tokens; with the
+    parent's expressions (PR 24's scatter, PR 29's read) behind that entry
+    point the streams keep their bytes (off the TPU the call's reference IS
+    those expressions)."""
     from ml_trainer_tpu.models import exaone_moe
+    from ml_trainer_tpu.ops.kernels import slot_cache_write_reference
     from ml_trainer_tpu.serving import engine
 
     s = sizes((0, 4))
@@ -244,11 +247,13 @@ def test_only_the_full_layers_read_through_decode_attention(monkeypatch):
     model = get_model("exaone_moe_tiny", experts_held=(0, 4))
     calls = []
 
-    def parent(q, k_cache, v_cache, lengths):
+    def parent(q, k_new, v_new, k_cache, v_cache, idx):
         calls.append((q.shape, k_cache.shape))
+        k_cache, v_cache = slot_cache_write_reference(
+            k_cache, v_cache, k_new, v_new, idx)
         slots = jnp.arange(k_cache.shape[2])[None, :]
         return exaone_moe.grouped_decode_attention(
-            q, k_cache, v_cache, slots <= (lengths - 1)[:, None])
+            q, k_cache, v_cache, slots <= idx[:, None]), k_cache, v_cache
 
     def streams():
         with Server(model, variables, max_batch=3) as server:
@@ -257,7 +262,7 @@ def test_only_the_full_layers_read_through_decode_attention(monkeypatch):
 
     plain = streams()
     monkeypatch.setattr(engine, "_COMPILED", {})   # trace anew under the spy
-    monkeypatch.setattr(exaone_moe, "decode_attention", parent)
+    monkeypatch.setattr(exaone_moe, "decode_attention_append", parent)
     for a, b in zip(plain, streams()):
         np.testing.assert_array_equal(a, b)
     full = sum(kind == "full_attention" for kind in s["layer_types"])

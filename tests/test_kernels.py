@@ -34,10 +34,13 @@ from ml_trainer_tpu.ops.kernels.decode_attention import (
     _decode_block,
     attended_positions,
     decode_attention,
+    decode_attention_append,
+    decode_attention_append_reference,
     decode_attention_reference,
 )
 from ml_trainer_tpu.ops.kernels.slot_cache_write import (
     _position_on_lanes,
+    landing_position,
     slot_cache_write,
     slot_cache_write_reference,
 )
@@ -443,6 +446,167 @@ def test_decode_attention_refusals():
         decode_attention(q, k, v, lengths, implementation="flash")
 
 
+# ----------------------------------------------- decode_attention_append
+_APPENDS = {}
+
+
+def _append_case(layout, dtype, rep):
+    """q, this step's K and V and the caches of a layout, dtype and group
+    size, with three jitted steps: the reference (the scatter, then the
+    masked attention), the PAIR of kernels the call joins (interpret mode)
+    and the fused call (interpret mode), all at ``_DECODE_BLOCK``.  The
+    positions are an argument, so every case shares them."""
+    key = (layout, jnp.dtype(dtype).name, rep)
+    if key not in _APPENDS:
+        b, g, L, d = _DECODE_SHAPES[layout]
+        rng = np.random.default_rng(
+            [100, layout == "lanes", jnp.dtype(dtype).itemsize, rep])
+        arrays = tuple(
+            jnp.asarray(rng.normal(size=shape), dtype)
+            for shape in [(b, g * rep, 1, d)] + [(b, g, 1, d)] * 2
+            + [(b, g, L, d)] * 2)
+
+        def pair(q, k_new, v_new, k_cache, v_cache, pos):
+            k_cache, v_cache = slot_cache_write(
+                k_cache, v_cache, k_new, v_new, pos,
+                implementation="pallas", interpret=True)
+            return _decode_attention_pallas(
+                q, k_cache, v_cache, landing_position(pos, L) + 1,
+                _DECODE_BLOCK, True), k_cache, v_cache
+
+        def fused(q, k_new, v_new, k_cache, v_cache, pos):
+            return _decode_attention_pallas(
+                q, k_cache, v_cache, landing_position(pos, L) + 1,
+                _DECODE_BLOCK, True, (k_new, v_new))
+
+        _APPENDS[key] = (arrays, jax.jit(decode_attention_append_reference),
+                         jax.jit(pair), jax.jit(fused))
+    return _APPENDS[key]
+
+
+def _positions(pos, b, L):
+    at = {"L-1": L - 1, "L": L, "L+500": L + 500}
+    pos = pos if isinstance(pos, tuple) else (pos,) * b
+    return jnp.asarray([at.get(p, p) for p in pos], jnp.int32)
+
+
+@pytest.mark.parametrize("layout", sorted(_DECODE_SHAPES))
+@pytest.mark.parametrize("rep", [1, 8], ids=["G==H", "H/G==8"])
+@pytest.mark.parametrize("dtype,pos", [
+    *[(jnp.bfloat16, p) for p in (
+        0, 127, 128, 255, 256, "L-1", "L+500",
+        (0, 15, 16, 127, 256, "L+500"), (129, 1, "L-1", 255, "L", 383))],
+    (jnp.float32, (7, 128, "L", 0, 255, "L+500")),
+], ids=str)
+def test_decode_attention_append_is_the_write_then_the_read(layout, rep,
+                                                            dtype, pos):
+    """The fused call (interpret mode) against the pair it joins: the
+    caches bit for bit ``slot_cache_write_reference``'s (a position either
+    side of every block's and tile's edge, the cache's two ends, a free
+    row's position past ``L - 1``, clamped onto the row's own last
+    position; every other position and row keep their bits), and the output
+    that of ``decode_attention`` run after ``slot_cache_write``, both
+    kernels in interpret mode: the fused body runs the read's operations in
+    the read's order on the bits a read-back would see.  BIT FOR BIT on the
+    lanes (the VPU body attends the merged tile from the tile it writes
+    back); on the sublanes by the tolerance ``decode_attention`` is held to,
+    because there the new row enters the block under a select that feeds
+    the product, and XLA's CPU compiler may then sum that product in
+    another order (seen: one bfloat16 of 9,216 a float32 rounding off, at
+    one query head a key-value head; the chip's MXU takes the same bits
+    either way).  Against the reference (the masked attention over all
+    ``L`` positions) by that tolerance in both."""
+    arrays, reference, pair, fused = _append_case(layout, dtype, rep)
+    b, _, L, _ = arrays[3].shape
+    pos = _positions(pos, b, L)
+    want, two, got = (f(*arrays, pos) for f in (reference, pair, fused))
+    assert got[0].shape == arrays[0].shape and got[0].dtype == arrays[0].dtype
+    for cache, new, w, g in zip(arrays[3:], arrays[1:3], want[1:], got[1:]):
+        assert np.array_equal(_bits(g), _bits(w))
+        expect = _bits(cache).copy()
+        for row, p in enumerate(np.asarray(pos)):
+            expect[row, :, min(p, L - 1), :] = _bits(new)[row, :, 0, :]
+        assert np.array_equal(_bits(g), expect)
+    tol = _DECODE_TOL[jnp.dtype(dtype).name]
+    if layout == "lanes":
+        assert np.array_equal(_bits(got[0]), _bits(two[0]))
+    for other in (two[0], want[0]):
+        np.testing.assert_allclose(
+            np.asarray(got[0], np.float32), np.asarray(other, np.float32),
+            atol=tol, rtol=tol)
+
+
+@pytest.mark.parametrize("layout", sorted(_DECODE_SHAPES))
+@pytest.mark.parametrize("poison", [np.nan, 1e30], ids=["nan", "1e30"])
+def test_decode_attention_append_never_sees_what_it_replaces(layout, poison):
+    """Exact: what a freed slot left AT the position the step writes and
+    past it, in the block the position lies in and in the blocks never
+    fetched, changes no bit of the output, and is still there after the
+    call everywhere but at the position."""
+    arrays, reference, _, fused = _append_case(layout, jnp.bfloat16, 8)
+    q, k_new, v_new, k, v = arrays
+    b, _, L, _ = k.shape
+    pos = _positions((0, 127, 128, 255, "L-1", "L+500"), b, L)
+    dead = (jnp.arange(L)[None, :] >= jnp.minimum(pos, L - 1)[:, None])[
+        :, None, :, None]
+    dirty = [jnp.where(dead, poison, c).astype(c.dtype) for c in (k, v)]
+    clean_out = fused(*arrays, pos)[0]
+    out, k_after, v_after = fused(q, k_new, v_new, *dirty, pos)
+    assert np.isfinite(np.asarray(clean_out, np.float32)).all()
+    assert np.array_equal(_bits(out), _bits(clean_out))
+    want = reference(q, k_new, v_new, *dirty, pos)
+    assert np.array_equal(_bits(k_after), _bits(want[1]))
+    assert np.array_equal(_bits(v_after), _bits(want[2]))
+
+
+def test_decode_attention_append_entry_point_and_refusals():
+    """The public call: off the TPU 'auto' IS the reference, and the
+    reference IS the pair the engine made before (the scatter at ``pos``,
+    the masked attention at ``pos + 1``); with the chooser's block the
+    kernel agrees with it; both parents' shape checks refuse."""
+    (q, k_new, v_new, k, v), reference, _, fused = _append_case(
+        "lanes", jnp.float32, 8)
+    b, _, L, _ = k.shape
+    pos = _positions((0, 127, 128, 255, "L-1", "L+500"), b, L)
+    want = reference(q, k_new, v_new, k, v, pos)
+    written = jax.jit(slot_cache_write_reference)(k, v, k_new, v_new, pos)
+    assert _bits_equal(want[1:], written)
+    assert np.array_equal(_bits(want[0]), _bits(jax.jit(
+        decode_attention_reference)(q, *written, pos + 1)))
+    assert _bits_equal(
+        _jrun(decode_attention_append, q, k_new, v_new, k, v, pos), want)
+    chosen = _jrun(decode_attention_append, q, k_new, v_new, k, v, pos,
+                   implementation="pallas", interpret=True)
+    assert _bits_equal(chosen[1:], want[1:])
+    np.testing.assert_allclose(chosen[0], want[0], atol=1e-5)
+    # a float32 row into a bfloat16 cache is rounded BEFORE it is attended
+    half = [c.astype(jnp.bfloat16) for c in (k, v)]
+    rounded = fused(q, k_new.astype(jnp.bfloat16),
+                    v_new.astype(jnp.bfloat16), *half, pos)
+    got = _jrun(decode_attention_append, q, k_new, v_new, *half, pos,
+                implementation="pallas", interpret=True)
+    assert _bits_equal(got[1:], rounded[1:])
+    np.testing.assert_allclose(got[0], rounded[0], atol=1e-5)
+
+    with pytest.raises(ValueError, match="differ"):
+        decode_attention_append(q, k_new, v_new, k, v[:, :1], pos)
+    with pytest.raises(ValueError, match="differ"):
+        decode_attention_append(q, k_new, v_new, k, half[1], pos)
+    with pytest.raises(ValueError, match="one position a row of the cache"):
+        decode_attention_append(q[:, :, 0], k_new, v_new, k, v, pos)
+    with pytest.raises(ValueError, match="query heads over"):
+        decode_attention_append(q[:, :15], k_new, v_new, k, v, pos)
+    with pytest.raises(ValueError, match="one position a row"):
+        decode_attention_append(q, k_new[:, :, 0], v_new, k, v, pos)
+    with pytest.raises(ValueError, match="one position a row"):
+        decode_attention_append(q, k_new, v_new[:, :1], k, v, pos)
+    with pytest.raises(ValueError, match="one a cache row"):
+        decode_attention_append(q, k_new, v_new, k, v, pos[:-1])
+    with pytest.raises(ValueError, match="Unknown decode_attention_append"):
+        decode_attention_append(q, k_new, v_new, k, v, pos,
+                                implementation="scatter")
+
+
 def test_decode_block_is_chosen_from_shape_and_dtype():
     """Bytes of K a grid step, not positions: the two serving cells' caches
     get the same block, float32 half of bfloat16, and a length 128 does not
@@ -505,6 +669,9 @@ def _kernel_calls():
         "decode_attention": lambda: decode_attention(
             *_decode_case("lanes", jnp.float32, 1)[0],
             jnp.ones((6,), jnp.int32), **kw),
+        "decode_attention_append": lambda: decode_attention_append(
+            *_append_case("lanes", jnp.float32, 1)[0],
+            jnp.ones((6,), jnp.int32), **kw),
         "retention_state_step": lambda: _retention_state_step_call(**kw),
         "fused_adam_norm": lambda: unscale_sqsum(g, 2.0, **kw),
         "fused_adam_update": lambda: fused_adam_update(
@@ -516,6 +683,7 @@ def _kernel_calls():
 @pytest.mark.parametrize("name", ["paged_attention_decode", "int8_matmul",
                                   "fused_adam_norm", "fused_adam_update",
                                   "slot_cache_write", "decode_attention",
+                                  "decode_attention_append",
                                   "retention_state_step"])
 def test_each_kernel_carries_the_name_the_profiler_shows(name):
     """docs/kernels.md: a Pallas call's ``name`` is the instruction's name
@@ -582,21 +750,23 @@ def _run_requests(model, variables, **server_kw):
     return outs
 
 
-def _parent_decode_attention(calls):
-    """``layers.py``'s read of the slot cache as it stood before the kernel
-    (PR 29), written out, behind the kernel's signature; keeps what it was
-    called with."""
+def _parent_decode_step(calls):
+    """``layers.py``'s decode step at a per-row index as it stood before
+    the kernels (PR 24's write, PR 29's read), written out, behind the fused
+    call's signature; keeps what it was called with."""
     from ml_trainer_tpu.ops.attention import attention
 
-    def spy(q, k_cache, v_cache, lengths):
-        calls.append((q.shape, k_cache.shape, lengths.shape))
-        idx, s, L = lengths - 1, q.shape[2], k_cache.shape[2]
+    def spy(q, k_new, v_new, k_cache, v_cache, idx):
+        calls.append((q.shape, k_cache.shape, idx.shape))
+        k_cache, v_cache = slot_cache_write_reference(
+            k_cache, v_cache, k_new, v_new, idx)
+        s, L = q.shape[2], k_cache.shape[2]
         valid = (
             jnp.arange(L)[None, None, :]
             <= idx[:, None, None] + jnp.arange(s)[None, :, None]
         )[:, None, :, :]
         return attention(q, k_cache, v_cache, causal=False, mask=valid,
-                         implementation="xla")
+                         implementation="xla"), k_cache, v_cache
 
     return spy
 
@@ -613,10 +783,11 @@ def _slot_streams(model, variables, **server_kw):
 def test_slot_engine_reads_through_decode_attention_and_streams_the_same(
         model_and_vars, monkeypatch):
     """The slot engine's streams are byte for byte what the parent's
-    expression gives (off the TPU the kernel's reference IS that
-    expression), and ``_decode_step`` takes ``decode_attention`` only at one
-    position a row with a per-row index: the speculative verify window and
-    ``generate()``'s scalar index keep the masked XLA attention."""
+    expression gives (off the TPU the fused call's reference IS that
+    expression), and ``_decode_step`` takes ``decode_attention_append`` only
+    at one position a row with a per-row index: the speculative verify
+    window and ``generate()``'s scalar index keep the scatter and the masked
+    XLA attention."""
     from ml_trainer_tpu.generate import generate
     from ml_trainer_tpu.models import layers
     from ml_trainer_tpu.serving import engine
@@ -627,8 +798,8 @@ def test_slot_engine_reads_through_decode_attention_and_streams_the_same(
     # the engines' programs are kept by model: trace them anew under the spy
     monkeypatch.setattr(engine, "_COMPILED", {})
     decode_step = layers.MultiHeadAttention._decode_step
-    monkeypatch.setattr(layers, "decode_attention",
-                        _parent_decode_attention(calls))
+    monkeypatch.setattr(layers, "decode_attention_append",
+                        _parent_decode_step(calls))
     monkeypatch.setattr(
         layers.MultiHeadAttention, "_decode_step",
         lambda self, q, k, v: (steps.append(q.shape[2]),
@@ -651,6 +822,37 @@ def test_slot_engine_reads_through_decode_attention_and_streams_the_same(
     for a, b in zip(plain, _slot_streams(model, variables, spec_k=3)):
         np.testing.assert_array_equal(a, b)
     assert 4 in steps and not calls
+
+
+def test_slot_engine_streams_the_same_through_the_fused_call_as_the_pair(
+        model_and_vars, monkeypatch):
+    """The slot engine with the KERNELS behind its decode step (interpret
+    mode; on the CPU 'auto' is the reference): the one call that appends
+    while it reads streams token for token what the write kernel then the
+    read kernel stream, and what the reference streams, four requests over
+    three slots, so one is admitted into a freed slot whose index ran on
+    while it was free."""
+    import functools
+
+    from ml_trainer_tpu.models import layers
+    from ml_trainer_tpu.serving import engine
+
+    model, variables = model_and_vars
+    plain = _slot_streams(model, variables)
+    kw = dict(implementation="pallas", interpret=True)
+
+    def pair(q, k_new, v_new, k_cache, v_cache, idx):
+        k_cache, v_cache = slot_cache_write(
+            k_cache, v_cache, k_new, v_new, idx, **kw)
+        return (decode_attention(q, k_cache, v_cache, idx + 1, **kw),
+                k_cache, v_cache)
+
+    for step in (pair, functools.partial(decode_attention_append, **kw)):
+        # the engines' programs are kept by model: trace them anew
+        monkeypatch.setattr(engine, "_COMPILED", {})
+        monkeypatch.setattr(layers, "decode_attention_append", step)
+        for a, b in zip(plain, _slot_streams(model, variables)):
+            np.testing.assert_array_equal(a, b)
 
 
 def test_server_paged_kernel_byte_identity(model_and_vars):

@@ -83,18 +83,22 @@ def test_freed_slot_whose_index_ran_past_the_cache_is_served_again(
     runs past the cache's last position and its write must land, clamped,
     in its own row.  The long request, and two admitted afterwards into
     both slots, serve what ``generate()`` gives.  ``pallas`` steers the
-    write's call site to the kernel in interpret mode (at 32 positions the
-    position lies on the sublanes of its tile, at 64 on the lanes); ``auto``
-    is the call site as every other test on the CPU runs it."""
+    decode step's call site to the kernel in interpret mode (at 32 positions
+    the position lies on the sublanes of its tile, at 64 on the lanes);
+    ``auto`` is the call site as every other test on the CPU runs it."""
     import functools
 
     from ml_trainer_tpu.models import layers
+    from ml_trainer_tpu.ops.kernels.decode_attention import (
+        decode_attention_append)
     from ml_trainer_tpu.ops.kernels.slot_cache_write import (
-        _position_on_lanes, slot_cache_write)
+        _position_on_lanes)
 
     if write == "pallas":
-        monkeypatch.setattr(layers, "slot_cache_write", functools.partial(
-            slot_cache_write, implementation="pallas", interpret=True))
+        monkeypatch.setattr(
+            layers, "decode_attention_append", functools.partial(
+                decode_attention_append, implementation="pallas",
+                interpret=True))
     # A width of its own, so that no other test's compiled decode program
     # is reused here, nor this one's there.
     model = get_model("gpt2_tiny", max_len=max_len,

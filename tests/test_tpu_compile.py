@@ -22,6 +22,7 @@ from jax.sharding import SingleDeviceSharding
 
 from ml_trainer_tpu.ops.kernels.decode_attention import (
     decode_attention,
+    decode_attention_append,
     grouped_decode_attention,
 )
 from ml_trainer_tpu.ops.kernels.slot_cache_write import (
@@ -116,6 +117,49 @@ def test_decode_step_reads_the_cache_where_the_write_left_it(
     assert " while(" not in text
     cache_bytes = 2 * b * g * L * d
     assert compiled.memory_analysis().temp_size_in_bytes < cache_bytes // 16
+
+
+@pytest.mark.parametrize("heads,shape,layout", [
+    (20, (32, 20, 1024, 64), "{2,3,1,0"),   # gpt2-large.batch-decode
+    (64, (64, 8, 2048, 128), "{3,2,1,0"),   # k-exaone's full layers: 8 a group
+], ids=["position_on_lanes", "position_on_sublanes"])
+def test_decode_step_appends_where_it_reads(one_chip, heads, shape, layout):
+    """A layer's decode step as the slot engine states it since PR 35, the
+    ONE call that puts this step's rows into the block it holds and writes
+    their tile back, compiled with the cache donated: one Mosaic call whose
+    name starts with ``decode_attention`` (the handle of
+    ``decode_attention_share_pct``) and none of the write kernel's, the
+    cache in the layout ``_position_on_lanes`` foresees, and no copy,
+    transpose, scatter or loop of a cache-sized operand round it."""
+    b, g, L, d = shape
+
+    def spec(shape, dtype=jnp.bfloat16):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    def step(kc, vc, q, kn, vn, idx):
+        out, kc, vc = decode_attention_append(
+            q, kn, vn, kc, vc, idx, implementation="pallas")
+        return kc, vc, out
+
+    compiled = jax.jit(step, donate_argnums=(0, 1)).lower(
+        spec(shape), spec(shape), spec((b, heads, 1, d)),
+        spec((b, g, 1, d)), spec((b, g, 1, d)), spec((b,), jnp.int32),
+    ).compile()
+    text = compiled.as_text()
+    entry = re.search(r"entry_computation_layout=\{\((.*?)\)->", text).group(1)
+    assert entry.startswith(f"bf16[{b},{g},{L},{d}]{layout}"), entry[:80]
+    assert _position_on_lanes(L, d) == (layout == "{2,3,1,0")
+    calls = re.findall(
+        r"%(\S+) = .*? custom-call\(.*custom_call_target=\"tpu_custom_call\"",
+        text)
+    assert len(calls) == 1 and calls[0].startswith("decode_attention"), calls
+    cache = rf"bf16\[{b},{g},(?:{L},{d}|{d},{L})\]"
+    assert not re.findall(rf"= {cache}\S* (?:copy|transpose|scatter)\(", text)
+    assert " while(" not in text
+    memory = compiled.memory_analysis()
+    cache_bytes = 2 * b * g * L * d
+    assert memory.alias_size_in_bytes >= 2 * cache_bytes    # both in place
+    assert memory.temp_size_in_bytes < cache_bytes // 16
 
 
 def test_a_latent_cache_is_written_and_read_where_it_lies(one_chip):
