@@ -863,10 +863,16 @@ class Server:
         (``engine.advance``); whatever is not a decode step (an
         admission, an adoption, a chunk window, an export, an
         evacuation, shutdown) lands first and so sees the engine as a
-        synchronous ``engine.step()`` leaves it."""
-        freed = self.engine.land()
-        for slot in freed:
-            self.scheduler.release(slot)
+        synchronous ``engine.step()`` leaves it: ``serve_land`` holds
+        that landing's ``serve_decode`` (a fence, no dispatch) and its
+        ``serve_deliver``."""
+        if not self.engine.in_flight():
+            return 0
+        with spans.span("serve_land", freed=0) as landed:
+            freed = self.engine.land()
+            for slot in freed:
+                self.scheduler.release(slot)
+            landed["freed"] = len(freed)
         return len(freed)
 
     def _drain_adoptions(self) -> bool:

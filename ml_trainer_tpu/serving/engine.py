@@ -83,6 +83,7 @@ bound covers every decode executable in the process.
 from __future__ import annotations
 
 import collections
+import contextlib
 import dataclasses
 import inspect
 import time
@@ -105,6 +106,13 @@ from ml_trainer_tpu.speculative import (
     build_draft_scan,
     build_verify,
 )
+
+
+# The phases of an admission turn, as the serving loop's spans name them:
+# the landing it forces (``Server._land_step``), each admission
+# (``admit``), and the first dispatch after it with nothing ahead
+# (``_turn``).  The benchmark's readers take the names from here.
+ADMISSION_SPANS = ("serve_land", "serve_admit", "serve_restart")
 
 
 def _as_key(rng) -> np.ndarray:
@@ -461,6 +469,7 @@ class SlotDecodeEngine:
         # between two calls, two inside ``advance()``.
         self._flying: collections.deque = collections.deque()
         self._landed_at = 0.0  # perf_counter at the last landing's fence
+        self._admitted = 0  # admissions since the last ``serve_restart``
         # Overload control (serving/overload.py, set via
         # Server.set_degradation): the active degradation-ladder rung
         # (0 = full service), the retry_after a shed client is told,
@@ -1110,6 +1119,7 @@ class SlotDecodeEngine:
         span: bookkeeping, prefill dispatch and the fence on the first
         token are one stall of every decoding slot."""
         self._must_have_landed("admit")
+        self._admitted += 1
         with span("serve_admit", request=req.id, prompt_len=len(req.prompt)):
             return self._admit(req, slot)
 
@@ -1257,46 +1267,49 @@ class SlotDecodeEngine:
             )
             if self._draft is not None:
                 self._admit_draft(prompt, slot, key, req.temperature)
-        self._pos[slot] = p
         with span("serve_prefill.fence"):
             tok0 = np.asarray(tok0)  # blocks until prefill + insert land
         prefill_dt = time.perf_counter() - t0
-        req.prefill_secs += prefill_dt
-        req.mark("prefill_done", ms=round(prefill_dt * 1e3, 3))
-        self.metrics.record_prefill(prefill_dt)
-        self._temps[slot] = req.temperature
-        self._rngs[slot] = key
-        self._steps[slot] = done_tokens + 1
-        if self.paged:
-            if self._prefix is not None:
-                # Register the prompt's full blocks NOW (the prefill
-                # that fills them is already dispatched, and the device
-                # stream serializes) so the next same-prefix request —
-                # even one admitted this very batch — hits.
-                self._prefix.insert(
-                    prompt,
-                    self.pool.slot_pages[slot][: p // self.kv_page_size],
-                    namespace=self._prefix_ns(req),
+        # The host mirrors, the prefix insert, the first token to its
+        # stream (which wakes its thread) and the admission's samples.
+        with span("serve_admit.emit"):
+            self._pos[slot] = p
+            req.prefill_secs += prefill_dt
+            req.mark("prefill_done", ms=round(prefill_dt * 1e3, 3))
+            self.metrics.record_prefill(prefill_dt)
+            self._temps[slot] = req.temperature
+            self._rngs[slot] = key
+            self._steps[slot] = done_tokens + 1
+            if self.paged:
+                if self._prefix is not None:
+                    # Register the prompt's full blocks NOW (the prefill
+                    # that fills them is already dispatched, and the device
+                    # stream serializes) so the next same-prefix request —
+                    # even one admitted this very batch — hits.
+                    self._prefix.insert(
+                        prompt,
+                        self.pool.slot_pages[slot][: p // self.kv_page_size],
+                        namespace=self._prefix_ns(req),
+                    )
+                self._push_kv_metrics()
+            token = int(tok0.reshape(-1)[0])
+            req.push_token(token)
+            if done_tokens == 0:
+                self.metrics.record_ttft(
+                    time.monotonic() - req.submitted_at, tenant=req.tenant
                 )
-            self._push_kv_metrics()
-        token = int(tok0.reshape(-1)[0])
-        req.push_token(token)
-        if done_tokens == 0:
-            self.metrics.record_ttft(
-                time.monotonic() - req.submitted_at, tenant=req.tenant
-            )
-            if req.first_admitted_at is not None:
-                # The queueing half of TTFT (the prefill-compute half is
-                # record_prefill above), per-request, so a saturated
-                # queue and a slow prefill are attributable apart.
-                self.metrics.record_queue_wait(
-                    req.first_admitted_at - req.submitted_at,
-                    tenant=req.tenant,
-                )
-        self._active[slot] = req
-        if self._finished(req, token):
-            return "finished"
-        return "active"
+                if req.first_admitted_at is not None:
+                    # The queueing half of TTFT (the prefill-compute half is
+                    # record_prefill above), per-request, so a saturated
+                    # queue and a slow prefill are attributable apart.
+                    self.metrics.record_queue_wait(
+                        req.first_admitted_at - req.submitted_at,
+                        tenant=req.tenant,
+                    )
+            self._active[slot] = req
+            if self._finished(req, token):
+                return "finished"
+            return "active"
 
     def _admit_full_prefill(self, req, slot, prompt, key, done_tokens):
         """The contiguous batch-1 prefill + slot insert (paged mode
@@ -1667,26 +1680,38 @@ class SlotDecodeEngine:
         flight when the step is prepared."""
         drafting = bool(self.spec_k and self.spec_enabled)
         look_ahead = look_ahead and not (self.paged or drafting)
-        freed = [] if look_ahead else self.land()
-        go = False
-        if self._active:
-            with span("serve_prepare", engine_step=self._step_seq + 1):
-                prepared, go = self._prepare(drafting)
-            freed = freed + prepared
-        if not go:
-            # Nothing to dispatch for: the last step lands alone.
-            return freed + self.land()
-        if drafting:
-            return freed + self._step_spec()
-        landing = None
-        with self._decode_span(self._step_seq, self._active):
-            self._dispatch()
-            if len(self._flying) > 1 or not look_ahead:
-                landing = self._flying.popleft()
-                toks = self._fence(landing)
-        if landing is not None:
-            freed = freed + self._deliver(landing, toks)
-        return freed
+        restart = look_ahead and bool(self._active) and not self._flying
+        if restart:
+            # The first dispatch after a landing (an admission's, as a
+            # rule) has nothing ahead of it: ``serve_restart`` holds its
+            # prepare and its dispatch, and counts the admissions since
+            # the last one (those that shared the landing and this gap).
+            admitted, self._admitted = self._admitted, 0
+            around = span("serve_restart", engine_step=self._step_seq + 1,
+                          admitted=admitted)
+        else:
+            around = contextlib.nullcontext()
+        with around:
+            freed = [] if look_ahead else self.land()
+            go = False
+            if self._active:
+                with span("serve_prepare", engine_step=self._step_seq + 1):
+                    prepared, go = self._prepare(drafting)
+                freed = freed + prepared
+            if not go:
+                # Nothing to dispatch for: the last step lands alone.
+                return freed + self.land()
+            if drafting:
+                return freed + self._step_spec()
+            landing = None
+            with self._decode_span(self._step_seq, self._active):
+                self._dispatch()
+                if len(self._flying) > 1 or not look_ahead:
+                    landing = self._flying.popleft()
+                    toks = self._fence(landing)
+            if landing is not None:
+                freed = freed + self._deliver(landing, toks)
+            return freed
 
     def _prepare(self, drafting: bool):
         """What a step needs before its dispatch: the cancelled swept,

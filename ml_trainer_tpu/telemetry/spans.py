@@ -107,18 +107,6 @@ def complete_event(name: str, start_mono: float, end_mono: float,
         _events.append(ev)
 
 
-def instant(name: str, category: str = "event", **args) -> None:
-    """A zero-duration marker on the trace timeline (``ph: "i"``)."""
-    ev = {
-        "name": name, "cat": category, "ph": "i", "s": "t",
-        "ts": _now_us(), "pid": os.getpid(), "tid": threading.get_ident(),
-    }
-    if args:
-        ev["args"] = args
-    with _events_lock:
-        _events.append(ev)
-
-
 def trace_events() -> list:
     """Point-in-time copy of the buffered events (oldest first)."""
     with _events_lock:
@@ -288,7 +276,6 @@ class StepProfiler:
             )
             try:
                 jax.profiler.start_trace(logdir)
-                instant("profile_window_start", step=step, logdir=logdir)
                 logger.info(
                     "profile_window_start", step=step, logdir=logdir
                 )
@@ -301,7 +288,6 @@ class StepProfiler:
 
             try:
                 jax.profiler.stop_trace()
-                instant("profile_window_stop", step=step)
                 logger.info("profile_window_stop", step=step)
             except Exception as e:
                 logger.warning(f"profile window failed to stop: {e}")

@@ -425,9 +425,20 @@ def test_decode_step_and_admission_are_cut_into_phase_spans(model_and_vars):
     turn dispatches step n+1 (``ahead``: 1) and fences step n; before an
     admission, and with nothing left active, it only fences; after one it
     only dispatches.  Through ``engine.step()`` both are the same step.
-    Per admission ``serve_admit`` holds the prefill and its fence, with
-    every dispatched step fenced before it."""
-    from ml_trainer_tpu.serving.engine import SlotDecodeEngine
+    Per admission ``serve_admit`` holds the prefill, its fence and the
+    first token's emission, with every dispatched step fenced before it.
+
+    An admission turn is wrapped (``engine.ADMISSION_SPANS``): the
+    landing it forces is ``serve_land`` round that fence-only
+    ``serve_decode`` and its ``serve_deliver``; the first dispatch after
+    it, with nothing ahead, is ``serve_restart`` round its
+    ``serve_prepare`` and ``serve_decode``, counting the admissions
+    since the last one.  A steady turn has neither wrapper, and
+    ``engine.step()`` records neither."""
+    from ml_trainer_tpu.serving.engine import (
+        ADMISSION_SPANS,
+        SlotDecodeEngine,
+    )
     from ml_trainer_tpu.serving.scheduler import Request
     from ml_trainer_tpu.telemetry.spans import clear_trace, trace_events
 
@@ -444,7 +455,8 @@ def test_decode_step_and_admission_are_cut_into_phase_spans(model_and_vars):
             key=lambda e: (e["ts"], -e["dur"]))
         assert len({e["tid"] for e in events}) == 1
         tops = [e for e in events if "." not in e["name"]
-                and not e["name"].startswith("serve_prefill")]
+                and not e["name"].startswith("serve_prefill")
+                and e["name"] not in wrappers]
         for a, b in zip(tops, tops[1:]):
             assert end(a) <= b["ts"]               # siblings never overlap
         return events, tops
@@ -452,6 +464,52 @@ def test_decode_step_and_admission_are_cut_into_phase_spans(model_and_vars):
     def inside(events, parent):
         return [e for e in events if e is not parent
                 and parent["ts"] <= e["ts"] and end(e) <= end(parent)]
+
+    land, admit, restart = ADMISSION_SPANS
+    wrappers = (land, restart)
+
+    def admission_turns(events, tops):
+        """The loop's top level with the wrappers in it: each wrapper
+        holds what the grammar says, the admissions between two
+        restarts are the next restart's ``admitted``, and every dispatch
+        with nothing ahead is a restart's.  Returns the top level."""
+        wraps = [e for e in events if e["name"] in wrappers]
+        level = sorted(wraps + [t for t in tops if not any(
+            t in inside(events, w) for w in wraps)],
+            key=lambda e: e["ts"])
+        for a, b in zip(level, level[1:]):
+            assert end(a) <= b["ts"]
+        since = 0
+        for i, e in enumerate(level):
+            kids = inside(events, e)
+            names = [k["name"] for k in kids]
+            if e["name"] == land:
+                assert names == ["serve_decode", "serve_decode.fence",
+                                 "serve_deliver"]
+                assert e["args"]["freed"] == kids[2]["args"]["freed"]
+                # an admission follows, or the loop is shutting down
+                assert i == len(level) - 1 or level[i + 1]["name"] == admit
+            elif e["name"] == admit:
+                assert names == ["serve_prefill", "serve_prefill.fence",
+                                 "serve_admit.emit"]
+                since += 1
+            elif e["name"] == restart:
+                assert names == ["serve_prepare", "serve_decode",
+                                 "serve_decode.dispatch"]
+                assert kids[2]["args"]["ahead"] == 0
+                assert (e["args"]["engine_step"]
+                        == kids[2]["args"]["engine_step"])
+                assert e["args"]["admitted"] == since
+                since = 0
+        dispatches = [e for e in events
+                      if e["name"] == "serve_decode.dispatch"]
+        in_restart = [d for d in dispatches if any(
+            d in inside(events, w) for w in wraps
+            if w["name"] == restart)]
+        # a steady turn (its step dispatched ahead) has neither wrapper
+        assert [d["args"]["ahead"] for d in dispatches if d not in
+                in_restart] == [1] * (len(dispatches) - len(in_restart))
+        return level
 
     def turns(events, tops):
         """Each ``serve_decode`` with its dispatch, its fence, and the
@@ -491,6 +549,7 @@ def test_decode_step_and_admission_are_cut_into_phase_spans(model_and_vars):
     events, tops = engine_spans()
     assert [t["name"] for t in tops[1:]] == [
         "serve_prepare", "serve_decode", "serve_deliver"] * 3
+    assert not any(e["name"] in wrappers for e in events)
     for dispatch, fence in turns(events, tops):
         assert dispatch["args"]["ahead"] == 0
         assert dispatch["args"]["engine_step"] == fence["args"]["engine_step"]
@@ -533,8 +592,27 @@ def test_decode_step_and_admission_are_cut_into_phase_spans(model_and_vars):
     assert [a["args"]["prompt_len"] for a in admits] == [5, 9]
     for a in admits:
         assert [e["name"] for e in inside(events, a)] == [
-            "serve_prefill", "serve_prefill.fence"]
+            "serve_prefill", "serve_prefill.fence", "serve_admit.emit"]
         # Landed before anything that is not a decode step.
         earlier = [e for e in events if e["ts"] < a["ts"]]
         assert (sum(e["name"] == "serve_decode.dispatch" for e in earlier)
                 == sum(e["name"] == "serve_decode.fence" for e in earlier))
+    level = admission_turns(events, tops)
+    assert level[0]["name"] == admit               # nothing to land yet
+    assert sum(e["name"] == restart for e in level) in (1, 2)
+
+    # One slot, three requests: each later one is admitted by a turn
+    # that lands the step in flight, and shares it with no other.
+    clear_trace()
+    with Server(model, variables, max_batch=1) as server:
+        streams = [server.submit(_prompt(6 + i, 4), 3) for i in range(3)]
+        for stream in streams:
+            stream.result(timeout=120)
+    events, tops = engine_spans()
+    level = admission_turns(events, tops)
+    grammar = [e["name"] for e in level
+               if e["name"] in ADMISSION_SPANS][:8]
+    assert grammar == [admit, restart, land, admit, restart,
+                       land, admit, restart]
+    assert [e["args"]["admitted"] for e in level
+            if e["name"] == restart] == [1, 1, 1]

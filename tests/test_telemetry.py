@@ -164,7 +164,8 @@ def test_events_between_takes_a_monotonic_window_and_tells_a_wrapped_ring(
     base = time.monotonic()
     for i, name in enumerate(["a", "b", "a"]):
         spans.complete_event(name, base + i, base + i + 0.25)
-    spans.instant("marker")                        # not a complete event
+    spans._events.append({"name": "marker", "ph": "i",   # not a complete
+                          "ts": (base + 1 - spans._MONO_EPOCH) * 1e6})
     events, wrapped = spans.events_between(base + 0.5, base + 2.0)
     assert [e["name"] for e in events] == ["b", "a"] and not wrapped
     # On the trace clock, as complete_event puts them there.
