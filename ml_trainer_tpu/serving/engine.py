@@ -96,10 +96,12 @@ import numpy as np
 from ml_trainer_tpu.generate import _COMPILED, _cache_shapes, _empty_cache
 from ml_trainer_tpu.serving.kv_pool import KVPagePool
 from ml_trainer_tpu.serving.metrics import ServingMetrics
+from ml_trainer_tpu.serving.param_cast import cast_at_use
 from ml_trainer_tpu.serving.prefix_cache import PrefixCache
 from ml_trainer_tpu.serving.scheduler import Request
 from ml_trainer_tpu.telemetry.flight import get_recorder
 from ml_trainer_tpu.telemetry.spans import StepProfiler, span
+from ml_trainer_tpu.utils.logging import get_logger
 from ml_trainer_tpu.speculative import (
     DraftModelDrafter,
     NgramDrafter,
@@ -570,6 +572,8 @@ class SlotDecodeEngine:
                 self._draft_insert = self._program(
                     ("serve_insert", d_model, max_batch), self._build_insert
                 )
+        self._draft_params = None
+        self._serve_params()
 
     # -- compiled programs ----------------------------------------------
 
@@ -579,6 +583,73 @@ class SlotDecodeEngine:
             run = build()
             _COMPILED[key] = run
         return run
+
+    def _prefill_program(self, bucket: int):
+        return self._program(
+            ("serve_prefill", self._prefill_model, bucket),
+            lambda: self._build_prefill(bucket, lora=self._lora_on),
+        )
+
+    def _draft_prefill_program(self, bucket: int):
+        return self._program(
+            ("serve_prefill", self._draft.model, bucket),
+            lambda: self._build_prefill(
+                bucket, self._draft_dm, self._draft_shapes_b1
+            ),
+        )
+
+    def _decode_extra(self, rows) -> tuple:
+        """The decode program's inputs after the sampling state."""
+        return (
+            (self._lora_vars(self._adapter_rows),) if self._lora_on
+            else (self._quant,) if self.quant_int8
+            else (rows,) if self._counter_args is not None
+            else ()
+        )
+
+    def _serve_params(self) -> None:
+        """Hold the trees the programs are handed as the programs use them
+        (serving/param_cast.py): a leaf that the decode step and a prompt's
+        prefill only ever cast to one narrower dtype is cast to it once,
+        here, and the programs read half the bytes of a float32 weight that
+        they round to bfloat16.  What identifies the weights
+        (``weights_fp``) and the int8 collection were computed from the
+        trees as handed."""
+        t0 = time.perf_counter()
+        bucket = min(2, self.max_len)     # the multi-token prefill path
+        prompt_args = (
+            np.zeros((1, bucket), np.int32), np.int32(bucket),
+            np.float32(0.0), np.zeros((2,), np.uint32), np.int32(0),
+        )
+        self.params, cast = cast_at_use(self.params, [
+            (self._decode, (self.cache, self.tok, self._temps, self._rngs,
+                            self._steps,
+                            *self._decode_extra(self._active_rows()))),
+            (self._prefill_program(bucket), prompt_args + (
+                (self._lora_vars(self._adapter_rows[:1]),)
+                if self._lora_on else ())),
+        ])
+        self.cast_param_bytes = cast
+        if self._draft is not None:
+            self._draft_params, cast = cast_at_use(self._draft.params, [
+                (self._draft_scan, (self._draft_cache, self.tok,
+                                    jnp.asarray(self._pos))),
+                (self._draft_prefill_program(bucket), prompt_args),
+            ])
+            self.cast_param_bytes += cast
+        self.served_param_bytes = sum(
+            int(leaf.nbytes) for leaf in jax.tree.leaves(
+                [self.params, self._draft_params])
+        )
+        self.metrics.record_params(
+            cast=self.cast_param_bytes, served=self.served_param_bytes)
+        get_logger("ml_trainer_tpu.serving").info(
+            "serving_engine", model=type(self.model).__name__,
+            max_batch=self.max_batch, max_len=self.max_len,
+            cast_param_bytes=self.cast_param_bytes,
+            served_param_bytes=self.served_param_bytes,
+            seconds=round(time.perf_counter() - t0, 3),
+        )
 
     def _build_decode(self):
         dm = self.dm
@@ -1319,10 +1390,7 @@ class SlotDecodeEngine:
         bucket = min(1 << (p - 1).bit_length(), self.max_len)
         padded = np.zeros((1, bucket), np.int32)
         padded[0, :p] = prompt
-        run = self._program(
-            ("serve_prefill", self._prefill_model, bucket),
-            lambda: self._build_prefill(bucket, lora=self._lora_on),
-        )
+        run = self._prefill_program(bucket)
         extra = (
             (self._lora_vars(self._adapter_rows[slot: slot + 1]),)
             if self._lora_on else ()
@@ -1559,14 +1627,8 @@ class SlotDecodeEngine:
         bucket = min(1 << (p - 1).bit_length(), self.max_len)
         padded = np.zeros((1, bucket), np.int32)
         padded[0, :p] = prompt
-        d_run = self._program(
-            ("serve_prefill", self._draft.model, bucket),
-            lambda: self._build_prefill(
-                bucket, self._draft_dm, self._draft_shapes_b1
-            ),
-        )
-        d_cache1, d_tok0 = d_run(
-            self._draft.params, padded, np.int32(p),
+        d_cache1, d_tok0 = self._draft_prefill_program(bucket)(
+            self._draft_params, padded, np.int32(p),
             jnp.asarray(temperature, jnp.float32), key, np.int32(0),
         )
         self._draft_cache, self._draft_tok = self._draft_insert(
@@ -1757,12 +1819,7 @@ class SlotDecodeEngine:
         advance HERE, so the next dispatch needs nothing this step
         produces."""
         rows = self._active_rows()
-        extra = (
-            (self._lora_vars(self._adapter_rows),) if self._lora_on
-            else (self._quant,) if self.quant_int8
-            else (rows,) if self._counter_args is not None
-            else ()
-        )
+        extra = self._decode_extra(rows)
         with span("serve_decode.dispatch", engine_step=self._step_seq,
                   ahead=int(bool(self._flying))):
             # A step that starts before the last one landed starts its
@@ -1871,7 +1928,7 @@ class SlotDecodeEngine:
                   active=active_before, k=k, requests=step_requests):
             if self._draft is not None:
                 self._draft_cache, drafts_dev = self._draft_scan(
-                    self._draft.params, self._draft_cache, self.tok,
+                    self._draft_params, self._draft_cache, self.tok,
                     jnp.asarray(self._pos),
                 )
                 # Draft fence: the verify window needs the drafted ids

@@ -155,6 +155,11 @@ class ServingMetrics:
         self.adapter_loads = 0
         self.adapter_evictions = 0
         self.adapter_slot_bytes = 0
+        # The parameters the engine's programs are handed (engine
+        # ``_serve_params``): bytes of the leaves it cast once at load, as
+        # handed, and bytes of the trees it serves.
+        self.cast_param_bytes = 0
+        self.served_param_bytes = 0
         self._tenants: dict = {}
         # Speculative decoding (engine spec mode): acceptance accounting.
         # One histogram entry per (verify step, active slot); keys are
@@ -364,6 +369,12 @@ class ServingMetrics:
             if bytes_per_slot is not None:
                 self.adapter_slot_bytes = int(bytes_per_slot)
 
+    def record_params(self, cast: int, served: int) -> None:
+        """What the engine did to the parameters it was handed."""
+        with self._lock:
+            self.cast_param_bytes = int(cast)
+            self.served_param_bytes = int(served)
+
     def record_prefix_stats(self, hits: int, misses: int,
                             hit_tokens: int, lookup_tokens: int) -> None:
         """Cumulative prefix-cache counters (token-weighted hit rate:
@@ -495,6 +506,8 @@ class ServingMetrics:
                     "total": self.adapter_slots_total
                     * self.adapter_slot_bytes,
                 },
+                "cast_param_bytes": self.cast_param_bytes,
+                "served_param_bytes": self.served_param_bytes,
                 "tenants": {
                     name: dict(stats)
                     for name, stats in sorted(self._tenants.items())

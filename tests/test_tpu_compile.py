@@ -324,3 +324,70 @@ def test_brumby_decode_and_prefill_fit_the_chip_with_one_state_pool(
     assert held + max(
         memory.temp_size_in_bytes,
         temps.temp_size_in_bytes + temps.output_size_in_bytes) < 14.5e9
+
+
+@pytest.mark.parametrize("served", [False, True], ids=["as_handed", "served"])
+def test_the_decode_step_reads_the_block_weights_at_two_bytes(
+        one_chip, monkeypatch, served):
+    """``gpt2-large.batch-decode``'s decode step at its widths, 2 layers,
+    32 slots of 1,024 positions, as the slot engine states it, over float32
+    parameters as ``init`` makes them.  Handed the tree the engine serves
+    (``serving/param_cast.py``'s rule, read off this very step), the block
+    weights are arguments of 2 bytes a value and no convert in the optimized
+    program makes a bfloat16 array of a weight's or a bias's shape; handed
+    the tree as made (the parent's way), every step converts each kernel."""
+    import functools
+
+    from ml_trainer_tpu.generate import _cache_shapes
+    from ml_trainer_tpu.models import get_model, layers
+    from ml_trainer_tpu.serving.param_cast import cast_targets
+
+    monkeypatch.setattr(layers, "decode_attention_append", functools.partial(
+        layers.decode_attention_append, implementation="pallas"))
+    slots, width, depth = 32, 1280, 2
+    model = get_model("gpt2_large", depth=depth, dtype=jnp.bfloat16)
+    dm = model.clone(decode=True)
+
+    def spec(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    params = jax.eval_shape(lambda: model.init(
+        {"params": jax.random.PRNGKey(0)}, jnp.zeros((1, 8), jnp.int32),
+        train=False))["params"]
+    cache = jax.tree.map(lambda s: spec(s.shape or (slots,), s.dtype),
+                         _cache_shapes(dm, slots, jnp.int32))
+    tok = spec((slots, 1), jnp.int32)
+
+    def step(params, cache, tok):
+        logits, mut = dm.apply({"params": params, "cache": cache}, tok,
+                               train=False, mutable=["cache"])
+        return mut["cache"], jnp.argmax(logits[:, -1], -1)
+
+    held = cast_targets(params, [(step, (cache, tok))])
+    leaves, treedef = jax.tree.flatten(params)
+    args = jax.tree.unflatten(treedef, [
+        spec(s.shape, t if served and t is not None else s.dtype)
+        for s, t in zip(leaves, held)])
+    weights = {(width, 3 * width), (width, width), (width, 4 * width),
+               (4 * width, width)}
+    block = depth * sum(a * b + b for a, b in weights)
+    assert sum(math.prod(s.shape) for s, t in zip(leaves, held)
+               if t is not None) == block               # kernels and biases
+    assert {jnp.dtype(t) for t in held if t is not None} == {
+        jnp.dtype(jnp.bfloat16)}
+    compiled = jax.jit(step, donate_argnums=(1,)).lower(
+        args, cache, tok).compile()
+    other = sum(math.prod(s.shape) for s in leaves) - block
+    state = sum(math.prod(s.shape) * s.dtype.itemsize
+                for s in jax.tree.leaves((cache, tok)))
+    # within the tiles' padding (82 KB as handed), far under the 79 MB
+    # between two and four bytes a value of the blocks
+    laid = compiled.memory_analysis().argument_size_in_bytes
+    assert abs(laid - ((2 if served else 4) * block + 4 * other + state)) < (
+        block // 100)
+    # what a convert makes, by shape: the activations' converts stay
+    made = {tuple(int(n) for n in dims.split(","))
+            for dims in re.findall(r"= bf16\[([\d,]+)\]\S* convert\(",
+                                   compiled.as_text())}
+    biases = {(b,) for _, b in weights}
+    assert not made & (weights | biases) if served else weights <= made
