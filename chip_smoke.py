@@ -382,6 +382,30 @@ def kernel_cases(cfg: SmokeConfig):
                f"{(b, g, length, d)}", append("pallas"), append("reference"),
                step_args, BF16_FLOOR)
 
+    # -- latent decode attention: every decode step of a latent layer ------
+    # kimi-linear-48b-ep8's pool (128 slots, one key-value head of 4,096
+    # rows of 512 + 64, 32 query heads, the first 512 the values; the
+    # position on the lanes), ragged rows; the interpreter gets the same
+    # layout small.  Against the masked read of the pool as keys and values.
+    from ml_trainer_tpu.ops.kernels import latent_decode_attention
+
+    for b, h, length, d, c in ((4, 8, 256, 72, 64),) if interp else (
+            (128, 32, 4096, 576, 512),):
+        lens = rng.integers(1, length + 1, size=b)
+        lens[:4] = (1, length, length + 500, 257)   # the ends, clamp, an edge
+        args = (normal((b, h, 1, d), jnp.bfloat16, 0.5),
+                normal((b, 1, length, d), jnp.bfloat16, 0.5),
+                jnp.asarray(lens, jnp.int32))
+
+        def latent(impl, c=c):
+            return lambda q, pool, n: latent_decode_attention(
+                q, pool, n, scale=192 ** -0.5, value_dim=c,
+                implementation=impl, interpret=interp)
+
+        yield (f"latent_decode_attention [bfloat16] {(b, h, 1, d)} x "
+               f"{(b, 1, length, d)}", latent("pallas"), latent("reference"),
+               args, BF16_FLOOR)
+
     # -- retention state step: every decode step of a power retention layer
     # A slot's key-value heads at the published group (five query heads of
     # 128 over a state of 128 x 8,320), the padding under phi's last half
@@ -524,7 +548,34 @@ def phase_kernels(cfg: SmokeConfig, workdir: str) -> dict:
         _check(calls >= 3 * depth,
                f"lowered GPT-2 train step holds {calls} Mosaic custom "
                f"calls (flash fwd + dq + dkv in each of {depth} layers)")
+    # The same guard for the latent layer's decode step, whose read has no
+    # option at all: 'auto' is the kernel on the chip, XLA elsewhere.
+    calls = mosaic_call_sites(_lower_latent_step().as_text())
+    facts["latent_step_custom_calls"] = calls
+    if cfg.platform == "tpu":
+        _check(calls == 2,
+               f"lowered latent decode step holds {calls} Mosaic custom "
+               "calls (the row's write and latent_decode_attention)")
     return facts
+
+
+def _lower_latent_step():
+    """One latent layer's decode step at a per-row index, as the slot
+    engine runs it, lowered for the platform JAX is on."""
+    import jax
+    import jax.numpy as jnp
+
+    from ml_trainer_tpu.models.kimi_linear import LatentAttention
+
+    layer = LatentAttention(4, 16, 8, 16, 32, decode=True,
+                            decode_max_len=256)
+    x = jnp.zeros((2, 1, 64), jnp.float32)
+    variables = jax.eval_shape(layer.init, jax.random.PRNGKey(0), x)
+    cache = dict(variables["cache"],
+                 cache_index=jax.ShapeDtypeStruct((2,), jnp.int32))
+    return jax.jit(lambda params, cache, x: layer.apply(
+        {"params": params, "cache": cache}, x, mutable=["cache"])).lower(
+        variables["params"], cache, x)
 
 
 # ---------------------------------------------------------------- serve

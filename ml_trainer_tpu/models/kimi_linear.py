@@ -73,10 +73,13 @@ from ml_trainer_tpu.ops.delta_rule import (
     gated_delta_chunked,
     gated_delta_step,
 )
-from ml_trainer_tpu.ops.kernels.decode_attention import (
-    grouped_decode_attention,
+from ml_trainer_tpu.ops.kernels.latent_decode_attention import (
+    latent_decode_attention,
 )
-from ml_trainer_tpu.ops.kernels.slot_cache_write import slot_row_write
+from ml_trainer_tpu.ops.kernels.slot_cache_write import (
+    landing_position,
+    slot_row_write,
+)
 
 KDA_LAYERS = (1, 2, 3, 5, 6, 7, 9, 10, 11, 13, 14, 15, 17, 18, 19, 21, 22,
               23, 25, 26)
@@ -268,12 +271,14 @@ class LatentAttention(nn.Module):
             "bhn,chn->bhc", q[:, :, 0, :n], up[:, :, :n],
             preferred_element_type=jnp.float32).astype(self.dtype)
         query = jnp.concatenate([q_latent, q[:, :, 0, n:]], axis=-1)
-        valid = jnp.arange(self.decode_max_len)[None, :] <= rows[:, None]
         # The latent cache is the keys AND the values: one key-value head
-        # that every query head reads; of the sum the latent part is kept.
-        mixed = grouped_decode_attention(
-            query[:, :, None, :], latent.value, latent.value, valid,
-            scale=(n + self.rope_dim) ** -0.5)[:, :, 0, :c]
+        # that every query head reads, each row's live blocks fetched once
+        # (ops/kernels/latent_decode_attention.py); of the sum the latent
+        # part is kept.
+        mixed = latent_decode_attention(
+            query[:, :, None, :], latent.value,
+            landing_position(rows, self.decode_max_len) + 1,
+            scale=(n + self.rope_dim) ** -0.5, value_dim=c)[:, :, 0, :]
         return jnp.einsum(
             "bhc,chd->bhd", mixed, up[:, :, n:],
             preferred_element_type=jnp.float32)[:, :, None, :]

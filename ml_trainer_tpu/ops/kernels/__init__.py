@@ -36,8 +36,14 @@ Catalog (see docs/kernels.md for block layouts and measured numbers):
   this step's K and V put into the row's last block on the way, its tile
   alone written back.  Online softmax, so pinned to its reference by
   tolerance, not bit for bit; the caches bit for bit.
+* ``latent_decode_attention`` — a latent attention layer's decode step
+  over its latent cache, every step and behind no knob: each row's live
+  blocks fetched once and used as the keys and the values of one
+  key-value head that every query head reads, where XLA reads the whole
+  pool twice.  Online softmax, so pinned by tolerance.
 * ``slot_cache_write`` / ``slot_row_write`` — the per-row append alone, for
-  a cache that XLA reads whole (a window layer's ring, a latent cache): one
+  a cache that XLA reads whole (a window layer's ring) or that a read
+  kernel does not write (a latent cache): one
   in-place grid over the rows where XLA runs the scatter as a sequential
   loop over them.
 * ``retention_state_step`` — a power retention layer's pass over its state
@@ -56,6 +62,10 @@ from ml_trainer_tpu.ops.kernels.decode_attention import (  # noqa: F401
     grouped_decode_attention,
 )
 
+from ml_trainer_tpu.ops.kernels.latent_decode_attention import (  # noqa: F401
+    latent_decode_attention,
+    latent_decode_attention_reference,
+)
 from ml_trainer_tpu.ops.kernels.paged_attention import (  # noqa: F401
     paged_attention,
     paged_attention_reference,
@@ -86,6 +96,8 @@ __all__ = [
     "decode_attention_append_reference",
     "decode_attention_reference",
     "grouped_decode_attention",
+    "latent_decode_attention",
+    "latent_decode_attention_reference",
     "paged_attention",
     "retention_state_step",
     "paged_attention_reference",

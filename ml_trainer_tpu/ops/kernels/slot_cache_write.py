@@ -9,11 +9,11 @@ scatter of ``B`` windows ``[H, 1, D]`` (``jax.vmap`` of a
 model, half of the decode step (PERF.md, PR 25).  This kernel is the same
 write as one grid over the rows whose DMAs the pipeline overlaps.
 
-Who calls it.  A cache that is READ WHOLE after the write: the rings of
+Who calls it.  A cache whose read does not write it: the rings of
 ``models/exaone_moe.py``'s window layers (written at ``position mod
-window``, read by XLA), the latent cache of ``models/kimi_linear.py``
-(``slot_row_write``: one leaf, read by XLA), a paged pool's rows (``rows``,
-below).  A cache read by ``decode_attention`` is written BY that kernel
+window``, read whole by XLA), the latent cache of ``models/kimi_linear.py``
+(``slot_row_write``: one leaf, read by ``latent_decode_attention``), a paged
+pool's rows (``rows``, below).  A cache read by ``decode_attention`` is written BY that kernel
 since PR 35 (``decode_attention_append``: the read already holds the tile
 this kernel would fetch, so only the write-back is left of it):
 ``models/layers.py`` and ``exaone_moe``'s full layers.  The reference here

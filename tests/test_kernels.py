@@ -32,11 +32,18 @@ from ml_trainer_tpu.ops.attention import dot_product_attention
 from ml_trainer_tpu.ops.kernels.decode_attention import (
     _decode_attention_pallas,
     _decode_block,
+    _work_list,
     attended_positions,
     decode_attention,
     decode_attention_append,
     decode_attention_append_reference,
     decode_attention_reference,
+    grouped_decode_attention,
+)
+from ml_trainer_tpu.ops.kernels.latent_decode_attention import (
+    _latent_decode_attention_pallas,
+    latent_decode_attention,
+    latent_decode_attention_reference,
 )
 from ml_trainer_tpu.ops.kernels.slot_cache_write import (
     _position_on_lanes,
@@ -652,6 +659,134 @@ def test_attended_positions_on_the_benchmark_traffic():
     assert traced.tolist() == [256, 512, 1024]
 
 
+# --------------------------------------------- latent_decode_attention
+# [B, 1, L, c + r] per layout (a row of 72 pads less on the sublanes, so the
+# position goes on the lanes; a row of 128 keeps the order as written), 8
+# query heads on the one key-value head, the first c of a row the values;
+# blocks of 128 positions, so a row meets one, two or all three of them.
+_LATENT_SHAPES = {"lanes": (6, 8, 384, 72, 64), "sublanes": (6, 8, 384, 128, 96)}
+_LATENT_SCALE = 24 ** -0.5
+_LATENTS = {}
+
+
+def _latent_case(layout, dtype):
+    """q and the pool of a layout and dtype, with the two jitted paths: the
+    lengths are an argument, so every case shares them."""
+    key = (layout, jnp.dtype(dtype).name)
+    if key not in _LATENTS:
+        b, h, L, d, c = _LATENT_SHAPES[layout]
+        assert _position_on_lanes(L, d) == (layout == "lanes")
+        rng = np.random.default_rng(len(_LATENTS) + 40)
+        arrays = tuple(jnp.asarray(rng.normal(size=shape), dtype)
+                       for shape in ((b, h, 1, d), (b, 1, L, d)))
+        _LATENTS[key] = arrays, jax.jit(
+            lambda q, pool, n: latent_decode_attention_reference(
+                q, pool, n, _LATENT_SCALE, c)), jax.jit(
+            lambda q, pool, n: _latent_decode_attention_pallas(
+                q, pool, n, _LATENT_SCALE, c, _DECODE_BLOCK, True))
+    return _LATENTS[key]
+
+
+@pytest.mark.parametrize("layout", sorted(_LATENT_SHAPES))
+@pytest.mark.parametrize("dtype,positions", [
+    (jnp.bfloat16, (0, 126, 127, 128, "L-1", "L+500")),
+    (jnp.bfloat16, (255, 256, 1, "L-2", 383, "L")),
+    (jnp.float32, (128, 0, "L+500", 254, "L-1", 127)),
+], ids=str)
+def test_latent_decode_attention_is_the_grouped_read_of_the_pool(
+        layout, dtype, positions):
+    """The kernel (interpret mode) against ``grouped_decode_attention`` over
+    the pool as keys AND values, the first ``c`` of each head kept, both
+    under jit, at the lengths the layer hands (``landing_position`` of the
+    row's index, plus one): 1, either side of a block edge and on it, ``L``,
+    and a free row's index past ``L - 1``, which lands on its last
+    position and attends ``L``.  By tolerance: the online softmax sums in
+    another order."""
+    (q, pool), reference, kernel = _latent_case(layout, dtype)
+    b, h, L, d, c = _LATENT_SHAPES[layout]
+    at = {"L-2": L - 2, "L-1": L - 1, "L": L, "L+500": L + 500}
+    pos = jnp.asarray([at.get(n, n) for n in positions], jnp.int32)
+    lengths = landing_position(pos, L) + 1
+    valid = jnp.arange(L)[None, :] <= pos[:, None]
+    grouped = jax.jit(lambda q, pool: grouped_decode_attention(
+        q, pool, pool, valid, scale=_LATENT_SCALE)[..., :c])(q, pool)
+    want, got = reference(q, pool, lengths), kernel(q, pool, lengths)
+    assert np.array_equal(_bits(want), _bits(grouped))
+    assert got.shape == (b, h, 1, c) and got.dtype == q.dtype
+    tol = _DECODE_TOL[jnp.dtype(dtype).name]
+    np.testing.assert_allclose(
+        np.asarray(got, np.float32), np.asarray(want, np.float32),
+        atol=tol, rtol=tol)
+
+
+@pytest.mark.parametrize("layout", sorted(_LATENT_SHAPES))
+@pytest.mark.parametrize("poison", [np.nan, 1e30], ids=["nan", "1e30"])
+def test_latent_decode_attention_never_sees_past_a_rows_length(
+        layout, poison):
+    """Exact: what lies past a row's length, in the block the length
+    crosses (as a key AND as a value) and in the blocks never fetched,
+    changes no bit of the output (a freed slot leaves its old rows)."""
+    (q, pool), _, kernel = _latent_case(layout, jnp.bfloat16)
+    L = pool.shape[2]
+    lengths = jnp.asarray([1, 127, 128, 129, L - 1, L], jnp.int32)
+    dead = (jnp.arange(L)[None, :] >= lengths[:, None])[:, None, :, None]
+    clean = kernel(q, pool, lengths)
+    dirty = kernel(q, jnp.where(dead, poison, pool).astype(pool.dtype),
+                   lengths)
+    assert np.isfinite(np.asarray(clean, np.float32)).all()
+    assert np.array_equal(_bits(dirty), _bits(clean))
+
+
+def test_latent_decode_attention_when_every_row_is_short():
+    """Every row inside its first block: the work list is one step a row,
+    far shorter than the grid of ``B x L / block`` a dense read would be,
+    and the steps past its end are never run (their rows' outputs are the
+    reference's, not what a repeated step would leave)."""
+    (q, pool), reference, kernel = _latent_case("lanes", jnp.float32)
+    b, _, L, _, _ = _LATENT_SHAPES["lanes"]
+    lengths = jnp.asarray([1, 5, 64, 127, 128, 3], jnp.int32)
+    row_of, block_of, steps = _work_list(lengths, L, _DECODE_BLOCK)
+    assert int(steps) == b < b * L // _DECODE_BLOCK
+    assert row_of[:b].tolist() == list(range(b))
+    assert not block_of[:b].any()
+    np.testing.assert_allclose(
+        np.asarray(kernel(q, pool, lengths)),
+        np.asarray(reference(q, pool, lengths)), atol=1e-5, rtol=1e-5)
+
+
+def test_latent_decode_attention_entry_point_and_refusals():
+    (q, pool), reference, kernel = _latent_case("lanes", jnp.float32)
+    b, h, L, d, c = _LATENT_SHAPES["lanes"]
+    lengths = jnp.asarray([1, 2, 200, L, 300, 129], jnp.int32)
+    kw = dict(scale=_LATENT_SCALE, value_dim=c)
+    chosen = _jrun(latent_decode_attention, q, pool, lengths,
+                   implementation="pallas", interpret=True, **kw)
+    np.testing.assert_allclose(chosen, reference(q, pool, lengths),
+                               atol=1e-5)
+    # off the TPU 'auto' IS the reference
+    assert np.array_equal(
+        _bits(_jrun(latent_decode_attention, q, pool, lengths, **kw)),
+        _bits(reference(q, pool, lengths)))
+    with pytest.raises(ValueError, match="one position a row"):
+        latent_decode_attention(q[..., :-8], pool, lengths, **kw)
+    with pytest.raises(ValueError, match="one position a row"):
+        latent_decode_attention(q[:, :, 0], pool, lengths, **kw)
+    with pytest.raises(ValueError, match="one position a row"):
+        latent_decode_attention(q[:-1], pool, lengths, **kw)
+    with pytest.raises(ValueError, match="value_dim 73 must lie"):
+        latent_decode_attention(q, pool, lengths, scale=1.0, value_dim=d + 1)
+    with pytest.raises(ValueError, match="value_dim 0 must lie"):
+        latent_decode_attention(q, pool, lengths, scale=1.0, value_dim=0)
+    with pytest.raises(ValueError, match="one key-value head"):
+        latent_decode_attention(
+            q, jnp.concatenate([pool, pool], axis=1), lengths, **kw)
+    with pytest.raises(ValueError, match="one a pool row"):
+        latent_decode_attention(q, pool, lengths[:-1], **kw)
+    with pytest.raises(ValueError, match="Unknown latent_decode_attention"):
+        latent_decode_attention(q, pool, lengths, implementation="mosaic",
+                                **kw)
+
+
 def _kernel_calls():
     rng = np.random.default_rng(9)
     g = jnp.asarray(rng.normal(size=(64, 16)), jnp.float32)
@@ -673,6 +808,9 @@ def _kernel_calls():
             *_append_case("lanes", jnp.float32, 1)[0],
             jnp.ones((6,), jnp.int32), **kw),
         "retention_state_step": lambda: _retention_state_step_call(**kw),
+        "latent_decode_attention": lambda: latent_decode_attention(
+            *_latent_case("lanes", jnp.float32)[0], jnp.ones((6,), jnp.int32),
+            scale=1.0, value_dim=64, **kw),
         "fused_adam_norm": lambda: unscale_sqsum(g, 2.0, **kw),
         "fused_adam_update": lambda: fused_adam_update(
             g, g, g, jnp.abs(g), bc1=0.1, bc2=0.001, step_size=-1e-3,
@@ -684,7 +822,8 @@ def _kernel_calls():
                                   "fused_adam_norm", "fused_adam_update",
                                   "slot_cache_write", "decode_attention",
                                   "decode_attention_append",
-                                  "retention_state_step"])
+                                  "retention_state_step",
+                                  "latent_decode_attention"])
 def test_each_kernel_carries_the_name_the_profiler_shows(name):
     """docs/kernels.md: a Pallas call's ``name`` is the instruction's name
     on the device trace, the handle a per-kernel metric finds it by."""

@@ -182,6 +182,51 @@ def test_the_absorbed_step_is_the_expanded_form_over_the_same_rows():
     np.testing.assert_allclose(np.asarray(out[:, 0]), want, atol=2e-6)
 
 
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16],
+                         ids=["float32", "bfloat16"])
+def test_the_decode_step_through_the_kernel_streams_the_same_tokens(
+        monkeypatch, dtype):
+    """The slot engine with the latent layers' read through
+    ``latent_decode_attention`` (interpret mode: the body the chip runs)
+    against the same engine through its reference, the masked
+    ``grouped_decode_attention`` of the pool as keys and values: the same
+    tokens, request for request.  One row fills the whole context (its
+    last steps attend all 64 rows), one row is freed and taken again, one
+    row is never taken (a free row stepped every step)."""
+    import functools
+
+    from ml_trainer_tpu.models import kimi_linear
+    from ml_trainer_tpu.serving import engine as engine_module
+
+    s = sizes((0, 4))
+    weights = reference.make_weights(seed_key(17), **s)
+    model = get_model("kimi_linear_tiny", experts_held=(0, 4), dtype=dtype)
+
+    def serve(read):
+        monkeypatch.setattr(engine_module, "_COMPILED", {})   # trace anew
+        monkeypatch.setattr(kimi_linear, "latent_decode_attention", read)
+        engine = SlotDecodeEngine(model, {"params": weights}, max_batch=4)
+        full, short, again = (request(prompt(9, 21), 64 - 9),
+                              request(prompt(13, 22), 7),
+                              request(prompt(11, 23), 20))
+        assert engine.admit(full, 0) == "active"
+        assert engine.admit(short, 2) == "active"
+        for step in range(64):
+            if step == 10:
+                assert short.state == "done"
+                assert engine.admit(again, 2) == "active"
+            engine.step()
+        assert full.state == again.state == "done"
+        return [r.tokens for r in (full, short, again)]
+
+    want = serve(kimi_linear.latent_decode_attention)
+    got = serve(functools.partial(
+        kimi_linear.latent_decode_attention, implementation="pallas",
+        interpret=True))
+    assert [len(t) for t in want] == [55, 7, 20]
+    assert got == want
+
+
 def moe_params(weights, block, first, count):
     p = weights[block]["moe"]
     return {"router": p["router"], "router_bias": p["router_bias"],

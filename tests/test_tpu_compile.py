@@ -197,6 +197,68 @@ def test_a_latent_cache_is_written_and_read_where_it_lies(one_chip):
     assert compiled.memory_analysis().temp_size_in_bytes < 1.5 * scores
 
 
+def test_the_latent_layers_decode_step_reads_each_pool_in_one_call(
+        one_chip, monkeypatch):
+    """``kimi-linear-48b-ep8``'s latent layers at their widths (32 heads,
+    a row of 512 + 64, 128 slots of 4,096 rows), two of them over dense
+    feed-forwards, as the slot engine states a decode step with the cache
+    donated: a layer is the row's write and ONE ``latent_decode_attention``
+    call, the pool stays in the layout ``_position_on_lanes`` foresees
+    (position on the lanes), and no copy, transpose or fusion of XLA's
+    makes an array of the pool's shape (a relayout round the call would
+    cost more than the read it replaces)."""
+    import functools
+
+    from ml_trainer_tpu.models import get_model, kimi_linear
+
+    # 'auto' asks jax.default_backend(), which is the CPU here: the test
+    # steers the model to the path a TPU takes
+    for name in ("latent_decode_attention", "slot_row_write"):
+        monkeypatch.setattr(kimi_linear, name, functools.partial(
+            getattr(kimi_linear, name), implementation="pallas"))
+    slots, L, d, layers = 128, 4096, 576, 2
+    model = get_model("kimi_linear", num_layers=layers, kda_layers=(),
+                      full_attn_layers=(1, 2), first_k_dense_replace=layers,
+                      vocab_rows=1024, max_len=L, dtype=jnp.bfloat16)
+    dm = model.clone(decode=True)
+
+    def spec(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    made = jax.eval_shape(lambda: dm.init(
+        {"params": jax.random.PRNGKey(0)}, jnp.zeros((slots, 1), jnp.int32),
+        train=False))
+    params = jax.tree.map(lambda s: spec(s.shape, s.dtype), made["params"])
+    # the slot engine's cache: the scalar index widened to a row a slot
+    cache = jax.tree.map(lambda s: spec(s.shape or (slots,), s.dtype),
+                         made["cache"])
+    assert cache["block0"]["attn"]["latent"].shape == (slots, 1, L, d)
+
+    def step(params, cache, tok):
+        logits, mut = dm.apply({"params": params, "cache": cache}, tok,
+                               train=False, mutable=["cache"])
+        return mut["cache"], jnp.argmax(logits[:, -1], -1)
+
+    compiled = jax.jit(step, donate_argnums=(1,)).lower(
+        params, cache, spec((slots, 1), jnp.int32)).compile()
+    text = compiled.as_text()
+    assert re.findall(rf"bf16\[{slots},1,{L},{d}\]\{{2,3,1,0", text)
+    calls = re.findall(
+        r"%(\S+) = .*? custom-call\(.*custom_call_target=\"tpu_custom_call\"",
+        text)
+    assert len(calls) == 2 * layers, calls
+    for kernel in ("slot_cache_write", "latent_decode_attention"):
+        assert sum(name.startswith(kernel) for name in calls) == layers, (
+            kernel, calls)
+    pool = rf"bf16\[{slots},1,(?:{L},{d}|{d},{L})\]"
+    assert not re.findall(
+        rf"= {pool}\S* (?:copy|transpose|fusion|scatter)\(", text)
+    memory = compiled.memory_analysis()
+    assert memory.alias_size_in_bytes >= layers * slots * L * d * 2
+    # the scores of XLA's read (4 B x heads x L a slot) are no longer made
+    assert memory.temp_size_in_bytes < 4 * slots * 32 * L
+
+
 def test_flash_kernels_compile_at_the_training_shape(one_chip):
     """Forward and backward at ``gpt2-124m.pretrain-1k``'s attention, 32 x 12
     heads of 64 over 1,024 positions in bfloat16 under the causal mask, with
